@@ -106,7 +106,8 @@ def cmd_run(args: argparse.Namespace) -> int:
         return 2
     # persistent XLA compile cache, process-global: initialize before any
     # jit so the first trial's trace can hit a prior run's executables
-    # (KATIB_COMPILE_CACHE env wins over the spec's compileCache field)
+    # (JAX_COMPILATION_CACHE_DIR, then KATIB_COMPILE_CACHE, then the spec's
+    # compileCache field, then <checkout>/.jax_cache)
     from katib_tpu.runner.trial_runner import init_compile_cache
 
     init_compile_cache(spec.compile_cache)
@@ -211,8 +212,8 @@ def cmd_prewarm(args: argparse.Namespace) -> int:
     cache = init_compile_cache(spec.compile_cache)
     if not cache:
         print(
-            "note: no persistent compile cache wired (compileCache / "
-            "KATIB_COMPILE_CACHE) — prewarming helps only this process",
+            "note: the persistent compile cache directory could not be "
+            "created — prewarming helps only this process",
             file=sys.stderr,
         )
     artifact_dir = ARTIFACTS.configure(

@@ -191,13 +191,15 @@ def pack_envelope(
     out_tree: Any,
     *,
     avals: list | None = None,
+    devices: list[int] | None = None,
     cost: Mapping[str, Any] | None = None,
     parent: str | None = None,
 ) -> bytes:
     """``MAGIC + header-json + \\n + body``: the body is the pickled
     (serialized executable, in/out treedefs) and the header carries the
     signature identity, the environment fingerprint, the program's input
-    avals, the optional cost record, and the body's length + SHA-256."""
+    avals, the ids of the devices it was compiled for (assignment order),
+    the optional cost record, and the body's length + SHA-256."""
     body = pickle.dumps(
         {"payload": payload, "in_tree": in_tree, "out_tree": out_tree},
         protocol=pickle.HIGHEST_PROTOCOL,
@@ -212,6 +214,7 @@ def pack_envelope(
         "donation": sig.donation,
         "fingerprint": dict(fp),
         "avals": avals or [],
+        "devices": list(devices or []),
         "cost": dict(cost) if cost else None,
         # the request-level signature this program was compiled under —
         # a prewarm twin observes several step programs, each published
@@ -530,6 +533,11 @@ class ArtifactCache:
                 in_tree,
                 out_tree,
                 avals=avals,
+                # the executable's own device assignment, in order: the
+                # loader must hand deserialize_and_load exactly these
+                devices=[
+                    d.id for d in compiled.runtime_executable().local_devices()
+                ],
                 cost=cost,
                 parent=parent,
             )
@@ -661,11 +669,22 @@ class ArtifactCache:
             # the content address should make this unreachable; a file
             # renamed/copied across envs is exactly what it catches
             raise ArtifactMismatch("environment fingerprint mismatch")
+        import jax
         from jax.experimental import serialize_executable as se
 
+        # load onto the devices the program was compiled for: without them
+        # jax loads for EVERY local device, and a one-device step then
+        # refuses its operands on a four-chip host
+        by_id = {d.id: d for d in jax.devices()}
+        ids = header.get("devices") or []
+        if not ids or any(i not in by_id for i in ids):
+            raise ArtifactMismatch(f"execution devices {ids} not present here")
         try:
             compiled = se.deserialize_and_load(
-                body["payload"], body["in_tree"], body["out_tree"]
+                body["payload"],
+                body["in_tree"],
+                body["out_tree"],
+                execution_devices=[by_id[i] for i in ids],
             )
         except Exception as e:
             raise ArtifactCorrupt(f"executable deserialize failed: {e}") from e

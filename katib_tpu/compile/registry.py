@@ -147,16 +147,12 @@ def trial_signature(train_fn: Callable | None, trial: Any, mesh: Any = None) -> 
 
 
 def _cache_dir() -> str | None:
-    """The wired persistent-compile-cache dir, or None — read from the live
-    jax config (set by ``init_compile_cache``) so a prewarm subprocess with
-    the same env shares the registry file without an import cycle."""
-    try:
-        import jax
+    """The persistent-compile-cache dir in force, or None — whatever
+    ``init_compile_cache`` resolved (imported lazily: the runner imports
+    this package)."""
+    from katib_tpu.runner.trial_runner import compile_cache_dir
 
-        d = getattr(jax.config, "jax_compilation_cache_dir", None)
-        return str(d) if d else None
-    except Exception:
-        return None
+    return compile_cache_dir()
 
 
 class ShapeRegistry:

@@ -692,8 +692,9 @@ class ExperimentSpec:
     # No-op for train_fns without a prewarm twin; never fails a trial.
     prewarm: bool = True
     # Persistent XLA compilation-cache directory wired at run() start
-    # (jax_compilation_cache_dir); None falls back to the
-    # KATIB_COMPILE_CACHE env var, empty/unset disables.
+    # (runner.trial_runner.init_compile_cache): JAX_COMPILATION_CACHE_DIR
+    # outranks everything, then KATIB_COMPILE_CACHE, then this field;
+    # None/empty resolves to the fixed <checkout>/.jax_cache.
     compile_cache: str | None = None
     # Shared artifact tier: a fleet-shared directory of serialized AOT
     # executables (compile/artifacts.py).  With it wired, the prewarm

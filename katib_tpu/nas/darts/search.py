@@ -59,20 +59,17 @@ _DEFAULT_SCAN_UNROLL = int(os.environ.get("KATIB_SCAN_UNROLL", "1"))
 
 
 def _persistent_cache_dir() -> str:
-    """The wired XLA persistent-cache dir ("" when disabled) — stamped on
-    first-step spans so a cache hit is visible as compile-time collapse."""
-    try:
-        import jax
+    """The persistent-cache dir in force ("" before it is wired) — stamped
+    on first-step spans so a cache hit is visible as compile-time collapse."""
+    from katib_tpu.runner.trial_runner import compile_cache_dir
 
-        return str(getattr(jax.config, "jax_compilation_cache_dir", None) or "")
-    except Exception:
-        return ""
+    return compile_cache_dir() or ""
 
 
 def _record_first_step(compile_s: float, execute_s: float, workload: str) -> None:
     """First-step latency split: under async dispatch the first jitted call
     blocks on trace+compile, fetching its result blocks on execution.  With
-    the persistent compilation cache wired (KATIB_COMPILE_CACHE), a cache
+    the persistent compilation cache wired (init_compile_cache), a cache
     hit shows up here as the compile phase collapsing to deserialize time.
 
     Warm/cold labeling goes through the shape registry with a coarse

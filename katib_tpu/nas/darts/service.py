@@ -66,11 +66,13 @@ _POSITIVE_FLOAT = {
 
 def search_space_from_nas_config(nas_config) -> list[str]:
     """Operations -> primitive names (reference ``get_search_space`` :102:
-    ``<operation_type>_<k>x<k>`` per filter size; skip_connection bare)."""
+    ``<operation_type>_<k>x<k>`` per filter size; skip_connection bare).
+    ``none`` is bare too: the reference's trial appends it to every search
+    space itself, here a CR lists it to reach ``DEFAULT_PRIMITIVES``."""
     primitives: list[str] = []
     for op in nas_config.operations:
-        if op.operation_type == "skip_connection":
-            primitives.append("skip_connection")
+        if op.operation_type in ("skip_connection", "none"):
+            primitives.append(op.operation_type)
             continue
         sizes = []
         for p in op.parameters:

@@ -216,8 +216,8 @@ class Orchestrator:
         if self.config is not None:
             spec = self.config.apply_to(spec)
         validate_experiment(spec)
-        # persistent XLA compilation cache (KATIB_COMPILE_CACHE env wins,
-        # spec field second); process-global, first writer wins
+        # persistent XLA compilation cache (init_compile_cache resolves the
+        # directory); process-global, first writer wins
         init_compile_cache(spec.compile_cache)
         # shared serialized-executable tier (KATIB_ARTIFACT_DIR env wins,
         # spec field second); same first-caller-wins contract

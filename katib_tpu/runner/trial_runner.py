@@ -53,23 +53,49 @@ from katib_tpu.utils.faults import (
 # first caller to wire a directory wins for the life of the process
 _COMPILE_CACHE_DIR: str | None = None
 
+#: last resort of ``init_compile_cache``: a fixed path inside the checkout
+#: (the directory is part of the cache key's context — one that moves
+#: between runs never hits), listed in ``.gitignore``
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def compile_cache_dir() -> str | None:
+    """The directory ``init_compile_cache`` put in force — the one place
+    the shape registry, the local artifact tier and the first-step spans
+    read it from.  None until the cache is wired."""
+    return _COMPILE_CACHE_DIR
+
 
 def init_compile_cache(cache_dir: str | None = None) -> str | None:
-    """Wire JAX's persistent compilation cache, once per process.
+    """Wire JAX's persistent compilation cache, once per process, and
+    return the directory in force.
 
-    Resolution: ``KATIB_COMPILE_CACHE`` env var, then the ``cache_dir``
-    argument (``ExperimentSpec.compile_cache``), else disabled.  With the
-    cache wired, identical programs compile once per *cache* instead of
-    once per process — restarts, ``--resume``, and repeated sweeps of the
-    same shapes skip straight to executable deserialization, which shows
-    up as the compile phase of ``katib_trial_first_step_seconds``
-    collapsing.  Returns the effective directory (None = disabled);
-    best-effort — an unwritable dir or an old jax never fails the run.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache has been placed
+    from outside: jax took the directory from the environment itself, so
+    it is used as is and neither ``KATIB_COMPILE_CACHE`` nor ``cache_dir``
+    can move it.  Otherwise: ``KATIB_COMPILE_CACHE``, then ``cache_dir``
+    (``ExperimentSpec.compile_cache``), then the fixed
+    ``<checkout>/.jax_cache``.  Identical programs then compile once per
+    *cache* instead of once per process — restarts, ``--resume`` and
+    repeated sweeps of the same shapes go straight to executable
+    deserialization.  An unwritable directory leaves the cache off (None)
+    and never fails the run.
     """
     global _COMPILE_CACHE_DIR
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    requested = (
+        placed
+        or os.environ.get("KATIB_COMPILE_CACHE")
+        or cache_dir
+        or DEFAULT_COMPILE_CACHE_DIR
+    )
+    requested = os.path.abspath(requested)
     if _COMPILE_CACHE_DIR is not None:
-        requested = os.environ.get("KATIB_COMPILE_CACHE") or cache_dir
-        if requested and os.path.abspath(requested) != _COMPILE_CACHE_DIR:
+        explicit = placed or os.environ.get("KATIB_COMPILE_CACHE") or cache_dir
+        if explicit and requested != _COMPILE_CACHE_DIR:
             # first caller wins (the jax config is process-global), but a
             # second experiment asking for a DIFFERENT directory deserves to
             # know its setting is inert — its executables land in (and hit
@@ -79,37 +105,26 @@ def init_compile_cache(cache_dir: str | None = None) -> str | None:
             warnings.warn(
                 "persistent compilation cache already wired to "
                 f"{_COMPILE_CACHE_DIR!r}; ignoring the requested "
-                f"{os.path.abspath(requested)!r} (the jax cache config is "
+                f"{requested!r} (the jax cache config is "
                 "process-global — first caller wins)",
                 RuntimeWarning,
                 stacklevel=2,
             )
         return _COMPILE_CACHE_DIR
-    resolved = os.environ.get("KATIB_COMPILE_CACHE") or cache_dir
-    if not resolved:
-        return None
-    resolved = os.path.abspath(resolved)
     try:
-        os.makedirs(resolved, exist_ok=True)
+        os.makedirs(requested, exist_ok=True)
     except OSError:
         return None
     import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", resolved)
-    except Exception:
-        return None
-    try:
-        # default jax threshold skips sub-second compiles — exactly the
-        # small-model sweep programs this repo batches; cache everything
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        pass
-    _COMPILE_CACHE_DIR = resolved
-    from katib_tpu.utils import observability as obs
-
+    if not placed:
+        jax.config.update("jax_compilation_cache_dir", requested)
+    # default jax threshold skips sub-second compiles — exactly the
+    # small-model sweep programs this repo batches; cache everything
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _COMPILE_CACHE_DIR = requested
     obs.compile_cache_enabled.set(1.0)
-    return resolved
+    return requested
 
 
 class TrialResult:

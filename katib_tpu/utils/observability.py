@@ -532,7 +532,7 @@ pbt_onchip = REGISTRY.gauge(
 compile_cache_enabled = REGISTRY.gauge(
     "katib_compile_cache_enabled",
     "1 when the persistent XLA compilation cache is wired "
-    "(KATIB_COMPILE_CACHE / ExperimentSpec.compile_cache)",
+    "(runner.trial_runner.init_compile_cache)",
 )
 
 # -- compile amortization (katib_tpu/compile/) --------------------------------

@@ -1,0 +1,138 @@
+"""Compile the main path's device programs for a TPU v5e that is described,
+not attached: the chip's own compiler refuses here what it would refuse
+there (misaligned kernel tiles, too much fast memory, a program that does
+not fit 16 GiB), at no chip time.
+
+Everything that touches the TPU library happens inside the module-scoped
+``topo`` fixture or a test body — never at import, never in a child
+process: only one process may load libtpu, and under pytest-xdist every
+worker imports this file (guide: on-chip-measurement, section 2).  The
+persistent compile cache is off around these compiles; a deviceless
+executable written to it could not be read back without a chip.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+V5E_HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (n_ops, batch, h, w, c): the normal cells of the flagship supernet
+# (8 primitives, batch 64, 16 channels doubling at each reduction)
+MIXED_OP_SHAPES = [(8, 64, 32, 32, 16), (8, 64, 16, 16, 32), (8, 64, 8, 8, 64)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", MIXED_OP_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_mixed_op_kernel_compiles(one_chip, shape, dtype):
+    from katib_tpu.ops.mixed_op import _pallas_mixed_op
+
+    w = jax.ShapeDtypeStruct(shape[:1], jnp.float32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(lambda w, x: _pallas_mixed_op(w, x, False)).lower(w, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+def test_flash_attention_compiles(one_chip, grad):
+    from katib_tpu.ops.flash_attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    qkv = [
+        jax.ShapeDtypeStruct((4, 8, 4096, 64), jnp.bfloat16, sharding=one_chip)
+    ] * 3
+    compiled = jax.jit(fn).lower(*qkv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _mnist_cohort_step_avals(k, member_sharding, shared_sharding, mesh=None):
+    """The jitted cohort train step of ``mnist_cohort_trial`` (MLP, units
+    64, batch 256) and its operands as shapes placed on the described
+    devices: stacked ``[K, ...]`` member states, one shared batch."""
+    from katib_tpu.models import mnist
+    from katib_tpu.parallel.train import TrainState, stack_pytrees
+
+    model = mnist.MLP(units=64, num_layers=2)
+    tx, step, _evaluate = mnist._build_cohort_steps(model, "momentum", mesh)
+
+    def stacked_state():
+        params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 28, 28, 1), jnp.float32))
+        return stack_pytrees([TrainState.create(params, tx)] * k)
+
+    def place(sharding):
+        return lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    states = jax.tree.map(place(member_sharding), jax.eval_shape(stacked_state))
+    batch = (
+        jax.ShapeDtypeStruct((256, 28, 28, 1), jnp.float32, sharding=shared_sharding),
+        jax.ShapeDtypeStruct((256,), jnp.int32, sharding=shared_sharding),
+    )
+    return step, states, batch
+
+
+def _device_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (
+        ma.temp_size_in_bytes
+        + ma.argument_size_in_bytes
+        + ma.output_size_in_bytes
+        + ma.generated_code_size_in_bytes
+    )
+
+
+def test_mnist_cohort_step_one_chip(one_chip):
+    step, states, batch = _mnist_cohort_step_avals(4, one_chip, one_chip)
+    compiled = step.lower(states, batch).compile()
+    assert 0 < _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_mnist_cohort_step_trial_sharded_four_chips(topo):
+    """K=8 with the member dimension split over a {trial: 4} mesh of the
+    described devices: each device steps K/4 members of one SPMD program."""
+    from katib_tpu.parallel.mesh import TRIAL_AXIS
+
+    mesh = Mesh(np.asarray(topo.devices[:4]), (TRIAL_AXIS,))
+    members = NamedSharding(mesh, PartitionSpec(TRIAL_AXIS))
+    shared = NamedSharding(mesh, PartitionSpec())
+    step, states, batch = _mnist_cohort_step_avals(8, members, shared, mesh)
+    compiled = step.lower(states, batch).compile()
+    assert 0 < _device_bytes(compiled) < V5E_HBM_BYTES
+    state_out, _metrics = compiled.output_shardings
+    for sharding in jax.tree.leaves(state_out):
+        assert sharding.spec[0] == TRIAL_AXIS, sharding
