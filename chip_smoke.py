@@ -9,6 +9,8 @@ time, so nothing here starts a child that needs it.
 
     python chip_smoke.py            # sweep phase: MNIST cohort sweep, one chip
     python chip_smoke.py --darts    # DARTS phase only: the flagship supernet
+                                    # (cold compile: ~20 min and > 40 GiB of host
+                                    # memory; see CHANGES.md, PR 22)
     python chip_smoke.py --chips 4  # trial-sharded cohort vs one device only
     python chip_smoke.py --allow-cpu [--darts | --chips 4]   # tiny rehearsal
 
@@ -86,8 +88,23 @@ class CompileCounts(logging.Handler):
         }
 
 
-def _total(metric) -> float:
-    return float(sum(v for _labels, v in metric.samples()))
+def _obs_totals() -> dict:
+    """The program's own counters this script reads, summed over labels."""
+    from katib_tpu.utils import observability as obs
+
+    counters = {
+        "cohorts": obs.cohorts_executed,
+        "fallbacks": obs.cohort_fallbacks,
+        "registry_warm": obs.compile_cache_hits,
+        "registry_cold": obs.compile_cache_misses,
+        "artifact_hits": obs.artifact_hits,
+        "artifact_misses": obs.artifact_misses,
+        "artifact_publishes": obs.artifact_publishes,
+    }
+    return {
+        name: float(sum(v for _labels, v in metric.samples()))
+        for name, metric in counters.items()
+    }
 
 
 def fresh_workdir(name: str) -> str:
@@ -278,15 +295,7 @@ class MemberPlacement:
 def run_sweep(phase, name, workdir, tiny, width, trials, counts, placement, mesh_axes=None):
     from katib_tpu.utils import observability as obs
 
-    before = {
-        "cohorts": _total(obs.cohorts_executed),
-        "fallbacks": _total(obs.cohort_fallbacks),
-        "registry_warm": _total(obs.compile_cache_hits),
-        "registry_cold": _total(obs.compile_cache_misses),
-        "artifact_hits": _total(obs.artifact_hits),
-        "artifact_misses": _total(obs.artifact_misses),
-        "artifact_publishes": _total(obs.artifact_publishes),
-    }
+    before = _obs_totals()
     c0 = counts.snapshot()
     n_placed = len(placement.cohorts)
     t0 = time.perf_counter()
@@ -294,15 +303,7 @@ def run_sweep(phase, name, workdir, tiny, width, trials, counts, placement, mesh
     wall = time.perf_counter() - t0
     series = settled_ok(phase, orch, exp, workdir, trials)
 
-    after = {
-        "cohorts": _total(obs.cohorts_executed),
-        "fallbacks": _total(obs.cohort_fallbacks),
-        "registry_warm": _total(obs.compile_cache_hits),
-        "registry_cold": _total(obs.compile_cache_misses),
-        "artifact_hits": _total(obs.artifact_hits),
-        "artifact_misses": _total(obs.artifact_misses),
-        "artifact_publishes": _total(obs.artifact_publishes),
-    }
+    after = _obs_totals()
     delta = {k: after[k] - before[k] for k in after}
     say(
         phase,

@@ -1,8 +1,8 @@
 """Compile amortization: shape bucketing, a compile-signature registry, and
 a background prewarm worker.
 
-BENCH_r05 puts a live XLA compile at 470s against a 0.54s step — at fleet
-trial volumes compilation, not training, is the bill.  Three coordinated
+An earlier round's record puts a live XLA compile at 470s against a 0.54s
+step — at fleet trial volumes compilation, not training, is the bill.  Three coordinated
 pieces keep cohort dispatches on a warm cache:
 
 - :mod:`katib_tpu.compile.buckets` quantizes cohort width K onto a few

@@ -559,7 +559,7 @@ class TrialSpec:
     progress_deadline_seconds: float | None = None
     # compile watchdog: budget for jit compile + FIRST dispatch (trace to
     # first ctx.report()).  The progress watchdog only arms per-step cadence;
-    # a 470s live compile (BENCH_r05) is indistinguishable from a wedge
+    # a 470s live compile (an earlier round's record) is indistinguishable from a wedge
     # without a separate budget.  Overruns classify as the retryable
     # FailureKind.COMPILE_HANG.  None = disabled.
     compile_deadline_seconds: float | None = None
@@ -659,7 +659,7 @@ class ExperimentSpec:
     max_trial_runtime_seconds: float | None = None
     metrics_retries: int = 0
     # Transient-failure retry budget + backoff base, propagated into every
-    # TrialSpec (see TrialSpec / utils.faults for the taxonomy).
+    # TrialSpec (see TrialSpec / utils.faults for the failure kinds).
     max_retries: int = 0
     retry_backoff_seconds: float = 1.0
     # Suggester circuit breaker: this many CONSECUTIVE get_suggestions

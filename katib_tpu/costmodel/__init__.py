@@ -6,9 +6,9 @@ benchmark: ordinary trials, cohorts, and the dashboard ran blind to
 utilization.  This package makes the roofline a first-class observability
 layer:
 
-- :mod:`peaks` — per-device-kind peak flops / HBM bandwidth tables (the
-  MFU denominator), with ``KATIB_PEAK_FLOPS`` / ``KATIB_PEAK_BW`` env
-  overrides for hardware the table doesn't know.
+- :mod:`peaks` — per-device-kind peak flops / HBM bandwidth table (the
+  MFU denominator); a device the table does not hold raises, and the
+  live gauges then publish nothing.
 - :mod:`record` — :class:`CostRecord`: flops, bytes accessed, peak HBM,
   arithmetic intensity, roofline floors and memory/compute-bound
   classification for one compiled program; extraction helpers for
@@ -20,8 +20,8 @@ layer:
   ``katib_arithmetic_intensity`` / ``katib_roofline_headroom`` against
   measured step time (:func:`live.publish_dispatch`).
 - :mod:`aot` — the deviceless TPU-topology AOT compile path shared with
-  ``bench.py`` (cost analysis without a device grant — works on CPU
-  hosts and wedged pools).
+  ``bench.py`` (cost analysis for a described chip — works on CPU
+  hosts).
 - :mod:`profiler` — on-demand ``jax.profiler`` capture with an
   in-process registry of trace directories (``/api/status`` and the
   ``katib-tpu profile --list`` verb read it).
@@ -46,6 +46,7 @@ from katib_tpu.costmodel.live import (
 )
 from katib_tpu.costmodel.peaks import (
     DevicePeaks,
+    UnknownDeviceKind,
     detect_device_kind,
     normalize_device_kind,
     peaks_for,
@@ -60,6 +61,7 @@ from katib_tpu.costmodel.record import (
 __all__ = [
     "CostRecord",
     "DevicePeaks",
+    "UnknownDeviceKind",
     "active_cost",
     "clear_active",
     "cost_of_compiled",

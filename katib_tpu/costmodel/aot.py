@@ -1,9 +1,9 @@
 """Deviceless TPU-topology AOT compile — cost analysis without a device.
 
-The pip ``libtpu`` can compile a program *client-side* against a TPU
-topology description (``jax.experimental.topologies``): no device grant,
-no runtime — which means the cost/HBM analysis works from a CPU host and
-even while the pool is wedged.  ``bench.py``'s AOT child pioneered the
+The pip ``libtpu`` can compile a program against a TPU topology
+description (``jax.experimental.topologies``): no device, no runtime —
+which means the cost/HBM analysis works from a CPU host.
+``bench.py``'s AOT child pioneered the
 path; it lives here so the bench and the ``katib-tpu cost`` verb share
 one implementation.
 """

@@ -58,14 +58,6 @@ class StepLoopUnavailable(RuntimeError):
 _DEFAULT_SCAN_UNROLL = int(os.environ.get("KATIB_SCAN_UNROLL", "1"))
 
 
-def _persistent_cache_dir() -> str:
-    """The persistent-cache dir in force ("" before it is wired) — stamped
-    on first-step spans so a cache hit is visible as compile-time collapse."""
-    from katib_tpu.runner.trial_runner import compile_cache_dir
-
-    return compile_cache_dir() or ""
-
-
 def _record_first_step(compile_s: float, execute_s: float, workload: str) -> None:
     """First-step latency split: under async dispatch the first jitted call
     blocks on trace+compile, fetching its result blocks on execution.  With
@@ -78,6 +70,7 @@ def _record_first_step(compile_s: float, execute_s: float, workload: str) -> Non
     first-step seam, and a double bump would overstate the hit rate."""
     from katib_tpu import costmodel
     from katib_tpu.compile.registry import REGISTRY, CompileSignature
+    from katib_tpu.runner.trial_runner import compile_cache_dir
 
     cache = "unknown"
     try:
@@ -105,7 +98,7 @@ def _record_first_step(compile_s: float, execute_s: float, workload: str) -> Non
         compile_s=round(compile_s, 4),
         execute_s=round(execute_s, 4),
         cache=cache,
-        persistent_cache=_persistent_cache_dir(),
+        persistent_cache=compile_cache_dir() or "",
     )
 
 

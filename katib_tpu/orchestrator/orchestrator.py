@@ -877,7 +877,11 @@ class Orchestrator:
             from katib_tpu.compile.buckets import bucketed_cohort_size
             from katib_tpu.compile.prewarm import PrewarmRequest
             from katib_tpu.compile.registry import shared_structural
-            from katib_tpu.parallel.mesh import padded_cohort_size, trial_axis_size
+            from katib_tpu.parallel.mesh import (
+                padded_cohort_size,
+                serial_mesh,
+                trial_axis_size,
+            )
 
             sig_mesh = mesh
             if len(trials) > 1:
@@ -896,6 +900,9 @@ class Orchestrator:
             else:
                 k = 1
                 program_fn = None
+                # a singleton trains on serial_mesh (run_trial): a
+                # trial-axis-only mesh has no data axis to compile for
+                sig_mesh = serial_mesh(mesh)
             worker.submit(
                 PrewarmRequest(
                     train_fn=spec.train_fn,

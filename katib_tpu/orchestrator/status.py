@@ -39,7 +39,7 @@ def trial_to_dict(trial: Trial) -> dict:
         "completion_time": trial.completion_time,
         "checkpoint_dir": trial.checkpoint_dir,
         # fault-tolerance state: journaled so a resumed process continues
-        # the retry budget instead of resetting it (utils/faults.py taxonomy)
+        # the retry budget instead of resetting it (utils/faults.py failure kinds)
         "retry_count": trial.retry_count,
         "failure_kind": trial.failure_kind,
     }

@@ -41,24 +41,6 @@ from katib_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
 InnerAttention = Callable[..., tuple[jax.Array, jax.Array]]
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` where available (jax >= 0.6), else the
-    ``jax.experimental`` spelling older runtimes ship (the ``check_vma``
-    replication check is ``check_rep`` there; disabled either way — the
-    ring's ppermute carry confuses it)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 def default_inner(block_q: int = 128, block_k: int = 128) -> InnerAttention:
     """Per-chunk attention kernel: Pallas flash on TPU, dense jnp elsewhere
     (interpret-mode Pallas inside shard_map is correct but far too slow for
@@ -202,9 +184,12 @@ def make_sequence_parallel_attention(
     spec = P(batch_axis, None, axis_name, None)
 
     def attn(q, k, v):
-        return _shard_map(
+        # check_vma off: the ring's ppermute carry confuses the
+        # replication check
+        return jax.shard_map(
             lambda a, b, c: local(a, b, c),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False,
         )(q, k, v)
 
     return attn

@@ -86,15 +86,9 @@ def init_compile_cache(cache_dir: str | None = None) -> str | None:
     """
     global _COMPILE_CACHE_DIR
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    requested = (
-        placed
-        or os.environ.get("KATIB_COMPILE_CACHE")
-        or cache_dir
-        or DEFAULT_COMPILE_CACHE_DIR
-    )
-    requested = os.path.abspath(requested)
+    explicit = placed or os.environ.get("KATIB_COMPILE_CACHE") or cache_dir
+    requested = os.path.abspath(explicit or DEFAULT_COMPILE_CACHE_DIR)
     if _COMPILE_CACHE_DIR is not None:
-        explicit = placed or os.environ.get("KATIB_COMPILE_CACHE") or cache_dir
         if explicit and requested != _COMPILE_CACHE_DIR:
             # first caller wins (the jax config is process-global), but a
             # second experiment asking for a DIFFERENT directory deserves to
@@ -136,7 +130,7 @@ class TrialResult:
     ):
         self.condition = condition
         self.message = message
-        # why the attempt failed (``utils.faults`` taxonomy) — the
+        # why the attempt failed (``utils.faults`` failure kinds) — the
         # orchestrator's retry loop re-runs TRANSIENT failures only
         self.failure_kind = failure_kind
 

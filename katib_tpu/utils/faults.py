@@ -119,7 +119,7 @@ _TRANSIENT_TYPES = (
     ConnectionError,
     TimeoutError,
     InterruptedError,
-    OSError,  # the taxonomy's catch-all for host/IO flakiness
+    OSError,  # the classification's catch-all for host/IO flakiness
 )
 _PERMANENT_TYPES = (
     ValueError,  # shape errors, bad hyperparameters, failed casts

@@ -432,7 +432,8 @@ dispatch_mfu = REGISTRY.gauge(
     "katib_dispatch_mfu",
     "Model-flops utilization of the live dispatch path: XLA-counted flops "
     "per measured step second over the device kind's peak "
-    "(costmodel.peaks; KATIB_PEAK_FLOPS overrides the denominator)",
+    "(costmodel.peaks; nothing is published for a device the table "
+    "does not hold)",
 )
 arithmetic_intensity = REGISTRY.gauge(
     "katib_arithmetic_intensity",

@@ -3,15 +3,13 @@
 The honest batch-64 measurement (artifacts/flagship/bench_tpu.json,
 ~535 ms/step, 0.56% MFU) is small-op/tile-padding-bound, so throughput
 should scale sub-linearly-in-time with batch — this harness measures how
-far.  Each configuration runs through ``bench.py`` itself (same child
-isolation, same fetch-forced timing), so a scaling point is produced by
-exactly the code the driver benches with.
+far.  Each configuration runs through ``bench.py`` itself, one process per
+point (same fetch-forced timing), so a scaling point is produced by exactly
+the code the bench runs.
 
-Safety: a batch-512 terminal-side compile crashed the pool terminal and
-wedged the grant (docs/performance.md), so every configuration must carry
-a committed deviceless-AOT block proving ``hbm_fits_v5e`` before this
-script will submit it to the chip.  Missing AOT memo => the config is
-SKIPPED with a note, never attempted.
+Safety: every configuration must carry a committed deviceless-AOT block
+proving ``hbm_fits_v5e`` before this script will submit it to the chip.
+Missing AOT memo => the config is SKIPPED with a note, never attempted.
 
 Artifacts: ``artifacts/flagship/batch_scaling.json``.
 Env knobs: SCALING_CONFIGS (comma list like ``64:none,128:dots``; extra
@@ -30,12 +28,7 @@ import subprocess
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from _common import (  # noqa: E402
-    REPO,
-    _local_compile_probe,
-    artifacts_root,
-    write_artifact,
-)
+from _common import REPO, artifacts_root, write_artifact  # noqa: E402
 
 RESULT_PREFIX = '{"metric"'
 
@@ -85,9 +78,8 @@ def aot_block_for(batch: int, policy: str | None, pairhess: bool = False) -> dic
 
 def _flush(points: list[dict]) -> dict:
     """Rewrite batch_scaling.json with the points measured SO FAR.  Called
-    after every point: the outer window driver (scripts/tpu_window5c.sh)
-    hard-kills this script's process group at its step timeout, and an
-    end-only write would lose every already-measured chip point with it."""
+    after every point: a caller that kills this script at a time limit
+    would otherwise lose every already-measured chip point with it."""
     result = {
         "what": (
             "flagship second-order bilevel step throughput vs batch size; "
@@ -103,11 +95,6 @@ def _flush(points: list[dict]) -> dict:
 def main() -> int:
     configs = parse_configs(os.environ.get("SCALING_CONFIGS", "64:none,128:dots"))
     steps = os.environ.get("BENCH_STEPS", "5")
-    # probe once, outside the loop: the verdict cannot change between the
-    # points of one invocation, and an inconclusive (None) probe on a
-    # wedged pool would otherwise charge every point its full timeout
-    # before the bench child even starts
-    remote_compile = _local_compile_probe() is False
     points: list[dict] = []
     for batch, policy, pairhess, window in configs:
         # the scan window chunks dispatches of the SAME per-step program —
@@ -122,9 +109,8 @@ def main() -> int:
                     "paired_hessian": pairhess,
                     "skipped": True,
                     "reason": (
-                        "no committed AOT fit-proof — oversized terminal "
-                        "compiles crash the pool (docs/performance.md); "
-                        "run the deviceless AOT first"
+                        "no committed AOT fit-proof — run the deviceless "
+                        "AOT first"
                         if aot is None
                         else f"AOT says {aot['hbm_gib']} GiB > v5e HBM"
                     ),
@@ -133,22 +119,7 @@ def main() -> int:
             _flush(points)
             continue
         env = dict(os.environ)
-        env.update(
-            BENCH_BATCH=str(batch),
-            BENCH_SKIP_AOT="1",
-            BENCH_NO_FALLBACK="1",
-            # 2, not 1: bench's libtpu-mismatch auto-flip to terminal-side
-            # compile happens on the attempt AFTER the mismatch is seen —
-            # a single attempt fails before the flip can ever fire (this
-            # exact footgun burned the first on-chip scaling run)
-            BENCH_RETRIES="2",
-            BENCH_STEPS=steps,
-        )
-        # consult the cached compile-locality verdict up front so attempt 1
-        # already compiles on the correct side instead of burning an
-        # attempt rediscovering the mismatch per point
-        if remote_compile:
-            env["KATIB_REMOTE_COMPILE"] = "1"
+        env.update(BENCH_BATCH=str(batch), BENCH_STEPS=steps)
         if policy is not None:
             env.update(BENCH_REMAT="1", BENCH_REMAT_POLICY=policy)
         else:
@@ -176,7 +147,7 @@ def main() -> int:
                 timeout=float(os.environ.get("SCALING_POINT_TIMEOUT", "3000")),
             )
         except subprocess.TimeoutExpired:
-            # one wedged point must not lose the points already measured
+            # one hung point must not lose the points already measured
             points.append(
                 {
                     "batch": batch,

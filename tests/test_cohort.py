@@ -11,6 +11,7 @@ Covers the four acceptance properties:
 
 from __future__ import annotations
 
+import os
 import threading
 
 import jax
@@ -550,3 +551,45 @@ class TestSpecPlumbing:
         cache = tmp_path / "env-xla"
         monkeypatch.setenv("KATIB_COMPILE_CACHE", str(cache))
         assert tr.init_compile_cache(None) == str(cache)
+        assert tr.compile_cache_dir() == str(cache)
+
+    @pytest.mark.parametrize("rival", ["KATIB_COMPILE_CACHE", "compileCache", None])
+    def test_cache_placed_from_outside_cannot_be_moved(
+        self, tmp_path, monkeypatch, rival
+    ):
+        """JAX_COMPILATION_CACHE_DIR places the cache: jax read the variable
+        itself, so the directory is used as is, the jax config is never
+        updated, and neither the env knob nor the spec field moves it."""
+        import katib_tpu.runner.trial_runner as tr
+
+        monkeypatch.setattr(tr, "_COMPILE_CACHE_DIR", None)
+        placed, other = tmp_path / "placed", tmp_path / "other"
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(placed))
+        monkeypatch.delenv("KATIB_COMPILE_CACHE", raising=False)
+        if rival == "KATIB_COMPILE_CACHE":
+            monkeypatch.setenv("KATIB_COMPILE_CACHE", str(other))
+        updates = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda name, value: (updates.append(name), real_update(name, value)),
+        )
+        arg = str(other) if rival == "compileCache" else None
+        assert tr.init_compile_cache(arg) == str(placed)
+        assert tr.compile_cache_dir() == str(placed)
+        assert "jax_compilation_cache_dir" not in updates
+        assert placed.is_dir() and not other.exists()
+
+    def test_compile_cache_last_resort_is_the_checkout(self, tmp_path, monkeypatch):
+        """Nothing asked for: a fixed <checkout>/.jax_cache, not "off"."""
+        import katib_tpu.runner.trial_runner as tr
+
+        monkeypatch.setattr(tr, "_COMPILE_CACHE_DIR", None)
+        monkeypatch.delenv("KATIB_COMPILE_CACHE", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert tr.DEFAULT_COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        # resolved, but not wired here: this test must not point the
+        # worker's jax at the checkout
+        monkeypatch.setattr(tr, "DEFAULT_COMPILE_CACHE_DIR", str(tmp_path / "last"))
+        assert tr.init_compile_cache(None) == str(tmp_path / "last")

@@ -69,14 +69,26 @@ class TestExtraction:
 
 
 class TestPeaks:
-    def test_normalize_device_kind(self):
-        assert cm_peaks.normalize_device_kind("TPU v5 lite") == "v5e"
-        assert cm_peaks.normalize_device_kind("TPU v5p") == "v5p"
-        assert cm_peaks.normalize_device_kind("TPU v4") == "v4"
-        assert cm_peaks.normalize_device_kind("cpu") == "cpu"
-        # unknown hardware falls back to the default generation
-        assert cm_peaks.normalize_device_kind("TPU v9000") == "v5e"
-        assert cm_peaks.normalize_device_kind(None) == "v5e"
+    @pytest.mark.parametrize(
+        "raw, key",
+        [("TPU v5 lite", "v5e"), ("v5e", "v5e"), ("TPU v5p", "v5p"),
+         ("TPU v4", "v4"), ("TPU v3", "v3")],
+    )
+    def test_normalize_device_kind(self, raw, key):
+        assert cm_peaks.normalize_device_kind(raw) == key
+
+    @pytest.mark.parametrize("raw", ["cpu", "TPU v9000", "", None])
+    def test_unknown_device_kind_raises(self, raw):
+        # a device that is not in the table is an error, not a default
+        with pytest.raises(cm_peaks.UnknownDeviceKind):
+            cm_peaks.normalize_device_kind(raw)
+        with pytest.raises(cm_peaks.UnknownDeviceKind):
+            cm_peaks.peaks_for(raw if raw is not None else "None")
+
+    def test_live_device_on_the_cpu_has_no_peaks(self):
+        # the tests run on the CPU backend: no row, so no MFU denominator
+        with pytest.raises(cm_peaks.UnknownDeviceKind):
+            cm_peaks.peaks_for()
 
     def test_peak_flops_dtype_fallback(self):
         pk = cm_peaks.PEAKS["v5e"]
@@ -84,16 +96,12 @@ class TestPeaks:
         assert pk.peak_flops("f32") == 98.5e12
         assert pk.peak_flops("no-such-dtype") == 197e12  # bf16 fallback
 
-    def test_env_overrides(self, monkeypatch):
+    def test_environment_cannot_move_the_peaks(self, monkeypatch):
         monkeypatch.setenv("KATIB_PEAK_FLOPS", "1e12")
         monkeypatch.setenv("KATIB_PEAK_BW", "2e11")
         pk = cm_peaks.peaks_for("v5e")
-        assert pk.peak_flops("bf16") == 1e12
-        assert pk.peak_flops("f32") == 1e12  # override applies to every dtype
-        assert pk.hbm_bandwidth == 2e11
-        monkeypatch.delenv("KATIB_PEAK_FLOPS")
-        monkeypatch.delenv("KATIB_PEAK_BW")
-        assert cm_peaks.peaks_for("v5e").peak_flops("bf16") == 197e12
+        assert pk.peak_flops("bf16") == 197e12
+        assert pk.hbm_bandwidth == 819e9
 
     def test_roofline_classification(self):
         pk = cm_peaks.DevicePeaks(
@@ -171,6 +179,17 @@ class TestLiveSlot:
             workload="wl-publish", bound="compute-bound"
         ) == pytest.approx(2.0)  # 1.0s measured vs 0.5s compute floor
 
+    def test_publish_dispatch_publishes_nothing_on_the_cpu(self):
+        # no peaks passed: the live device is the CPU backend, which the
+        # table does not hold — no gauge, no attrs, no exception
+        rec = CostRecord(program="p", flops=50.0, bytes_accessed=1.0)
+        assert cm_live.publish_dispatch(rec, 1.0, workload="wl-cpu") == {}
+        assert not [
+            labels
+            for labels, _v in obs.dispatch_mfu.samples()
+            if labels.get("workload") == "wl-cpu"
+        ]
+
     def test_publish_dispatch_rejects_zero_time(self):
         assert cm_live.publish_dispatch(
             CostRecord(flops=1.0), 0.0, workload="x"
@@ -209,7 +228,7 @@ class TestRegistryCost:
 
 
 class TestHeartbeatPublication:
-    def test_run_trial_publishes_mfu_and_persists_cost(self):
+    def test_run_trial_persists_cost_and_publishes_no_cpu_mfu(self):
         from katib_tpu.compile.registry import REGISTRY
         from katib_tpu.core.types import (
             ObjectiveSpec,
@@ -244,14 +263,15 @@ class TestHeartbeatPublication:
         try:
             res = run_trial(trial, MemoryObservationStore(), objective)
             assert res.condition == TrialCondition.SUCCEEDED
-            # 2nd+ beats publish against the measured report interval
-            # the workload label is the train_fn's qualname
+            # the heartbeats publish MFU against the live device's peak;
+            # this run is on the CPU, which has none — nothing is published
+            # (the workload label is the train_fn's qualname)
             mine = [
                 v
                 for labels, v in obs.dispatch_mfu.samples()
                 if labels.get("workload", "").endswith("costed_trainer")
             ]
-            assert mine and mine[0] > 0
+            assert mine == []
             # the cost landed next to the trial's compile signature
             rows = [
                 r

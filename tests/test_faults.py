@@ -309,7 +309,7 @@ class TestTransientRetry:
             with open(marker, "w") as f:
                 f.write(str(prev + 1))
             if len(progress_seen) <= 2:
-                raise OSError("preempted")  # transient by taxonomy
+                raise OSError("preempted")  # transient by classification
             ctx.report(step=0, accuracy=0.9)
 
         spec = make_spec("chaos-retry", trainer, max_retries=3)

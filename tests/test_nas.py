@@ -370,6 +370,41 @@ class TestDartsService:
         with pytest.raises(SearchExhausted):
             s.get_suggestions(exp, 1)
 
+    def test_cr_can_spell_out_the_default_primitives(self):
+        """``none`` is bare like ``skip_connection`` (the reference's trial
+        appends it itself), so a CR reaches the flagship's eight
+        DEFAULT_PRIMITIVES — what chip_smoke.py --darts sends."""
+        from katib_tpu.core.types import (
+            FeasibleSpace,
+            NasConfig,
+            NasOperation,
+            ParameterSpec,
+            ParameterType,
+        )
+        from katib_tpu.nas.darts.ops import DEFAULT_PRIMITIVES
+        from katib_tpu.nas.darts.service import search_space_from_nas_config
+
+        def op(kind, sizes=None):
+            params = (
+                [ParameterSpec("filter_size", ParameterType.CATEGORICAL,
+                               FeasibleSpace(list=tuple(sizes)))]
+                if sizes
+                else []
+            )
+            return NasOperation(operation_type=kind, parameters=params)
+
+        spec = nas_spec("darts")
+        cfg = NasConfig(
+            graph_config=spec.nas_config.graph_config,
+            operations=[
+                op("none"), op("max_pooling", ["3"]), op("avg_pooling", ["3"]),
+                op("skip_connection"),
+                op("separable_convolution", ["3", "5"]),
+                op("dilated_convolution", ["3", "5"]),
+            ],
+        )
+        assert tuple(search_space_from_nas_config(cfg)) == DEFAULT_PRIMITIVES
+
     def test_settings_validation(self):
         with pytest.raises(SuggesterError, match="num_epochs"):
             make_suggester(nas_spec("darts", settings={"num_epochs": "-3"}))
