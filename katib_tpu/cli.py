@@ -1517,6 +1517,7 @@ def cmd_trace_summary(args: argparse.Namespace) -> int:
             s["name"],
             s["count"],
             f"{s['total_s']:.3f}",
+            f"{s['self_s']:.3f}",
             f"{s['mean_s']:.4f}",
             f"{s['p50_s']:.4f}",
             f"{s['p95_s']:.4f}",
@@ -1524,7 +1525,7 @@ def cmd_trace_summary(args: argparse.Namespace) -> int:
         ]
         for s in summary
     ]
-    print(_table(rows, ["SPAN", "COUNT", "TOTAL_S", "MEAN_S", "P50_S", "P95_S", "MAX_S"]))
+    print(_table(rows, ["SPAN", "COUNT", "TOTAL_S", "SELF_S", "MEAN_S", "P50_S", "P95_S", "MAX_S"]))
     if slowest:
         rows = [
             [

@@ -22,6 +22,7 @@ meaningfully.
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from functools import partial
 from typing import Callable
 
@@ -34,6 +35,7 @@ import optax
 from katib_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, shard_batch
 from katib_tpu.parallel.ring_attention import make_sequence_parallel_attention
 from katib_tpu.parallel.train import TrainState, clip_by_global_norm
+from katib_tpu.utils import tracing
 
 
 class Block(nn.Module):
@@ -165,72 +167,85 @@ def train_lm(
 ) -> float:
     """Train on ``data`` [N, S]; returns final eval loss on a held-out tail.
     Calls ``report(step, loss, eval_loss)`` every ``report_every`` steps."""
-    rng = np.random.default_rng(seed)
-    n_eval = max(batch_size, len(data) // 10)
-    train, heldout = data[:-n_eval], data[-n_eval:]
+    # everything up to the loop: model.init op by op, schedule and optimizer,
+    # TrainState.create, replicate, placing the eval tokens
+    with tracing.span("trial.init"):
+        rng = np.random.default_rng(seed)
+        n_eval = max(batch_size, len(data) // 10)
+        train, heldout = data[:-n_eval], data[-n_eval:]
 
-    # init batch must divide the mesh's data axis (the attention shard_map
-    # shards the batch dimension even while tracing init)
-    init_batch = 1
-    if mesh is not None and DATA_AXIS in mesh.shape:
-        init_batch = mesh.shape[DATA_AXIS]
-    params = model.init(
-        jax.random.PRNGKey(seed), jnp.zeros((init_batch, data.shape[1]), jnp.int32)
-    )
-    sched = optax.warmup_cosine_decay_schedule(
-        0.0, lr, max(1, int(steps * warmup_frac)), steps
-    )
-    tx = optax.adamw(sched, weight_decay=0.01)
+        # init batch must divide the mesh's data axis (the attention shard_map
+        # shards the batch dimension even while tracing init)
+        init_batch = 1
+        if mesh is not None and DATA_AXIS in mesh.shape:
+            init_batch = mesh.shape[DATA_AXIS]
+        params = model.init(
+            jax.random.PRNGKey(seed), jnp.zeros((init_batch, data.shape[1]), jnp.int32)
+        )
+        sched = optax.warmup_cosine_decay_schedule(
+            0.0, lr, max(1, int(steps * warmup_frac)), steps
+        )
+        tx = optax.adamw(sched, weight_decay=0.01)
 
-    use_dropout = model.dropout > 0.0
+        use_dropout = model.dropout > 0.0
 
-    def loss_fn(params, tokens, dropout_key):
-        if use_dropout:
-            logits = model.apply(
-                params, tokens, deterministic=False, rngs={"dropout": dropout_key}
-            )
-        else:
-            logits = model.apply(params, tokens)
-        return lm_loss(logits, tokens)
+        def loss_fn(params, tokens, dropout_key):
+            if use_dropout:
+                logits = model.apply(
+                    params, tokens, deterministic=False, rngs={"dropout": dropout_key}
+                )
+            else:
+                logits = model.apply(params, tokens)
+            return lm_loss(logits, tokens)
 
-    # donate the state: params + optimizer buffers are dead after the step,
-    # so XLA updates them in place instead of copying each iteration
-    @partial(jax.jit, donate_argnums=(0,))
-    def step_fn(state: TrainState, tokens, dropout_key):
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens, dropout_key)
-        grads, _ = clip_by_global_norm(grads, grad_clip)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        return TrainState(state.step + 1, params, opt_state), loss
+        # donate the state: params + optimizer buffers are dead after the step,
+        # so XLA updates them in place instead of copying each iteration
+        @partial(jax.jit, donate_argnums=(0,))
+        def step_fn(state: TrainState, tokens, dropout_key):
+            loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens, dropout_key)
+            grads, _ = clip_by_global_norm(grads, grad_clip)
+            updates, opt_state = tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
+            return TrainState(state.step + 1, params, opt_state), loss
 
-    @jax.jit
-    def eval_fn(params, tokens):
-        return lm_loss(model.apply(params, tokens), tokens)
+        @jax.jit
+        def eval_fn(params, tokens):
+            return lm_loss(model.apply(params, tokens), tokens)
 
-    state = TrainState.create(params, tx)
-    if mesh is not None:
-        from katib_tpu.parallel.mesh import replicate
+        state = TrainState.create(params, tx)
+        if mesh is not None:
+            from katib_tpu.parallel.mesh import replicate
 
-        state = replicate(state, mesh)
+            state = replicate(state, mesh)
 
-    def place(tokens):
-        tokens = jnp.asarray(tokens)
-        return tokens if mesh is None else shard_batch(tokens, mesh)
+        def place(tokens):
+            tokens = jnp.asarray(tokens)
+            return tokens if mesh is None else shard_batch(tokens, mesh)
 
-    eval_tokens = place(heldout[:batch_size])
-    eval_loss: float | None = None
-    dkey = jax.random.PRNGKey(seed + 1)
+        eval_tokens = place(heldout[:batch_size])
+        eval_loss: float | None = None
+        dkey = jax.random.PRNGKey(seed + 1)
+
+    def evaluate(step: int, first: bool) -> float:
+        # the first call also traces, lowers and loads the eval program
+        with tracing.span("trial.eval", step=step, first=first):
+            return float(eval_fn(state.params, eval_tokens))
+
     for s in range(steps):
         idx = rng.integers(0, len(train), size=batch_size)
         dkey, sub = jax.random.split(dkey)
-        state, loss = step_fn(state, place(train[idx]), sub)
+        tokens = place(train[idx])
+        # the first call alone, call to return (it is asynchronous): trace,
+        # lower, cache lookup, executable load and dispatch
+        with tracing.span("trial.first_step") if s == 0 else nullcontext():
+            state, loss = step_fn(state, tokens, sub)
         eval_loss = None  # stale after this step's update
         if report is not None and (s % report_every == 0 or s == steps - 1):
-            eval_loss = float(eval_fn(state.params, eval_tokens))
+            eval_loss = evaluate(s, first=s == 0)
             if report(step=s, loss=float(loss), eval_loss=eval_loss) is False:
                 break
     if eval_loss is None:
-        eval_loss = float(eval_fn(state.params, eval_tokens))
+        eval_loss = evaluate(steps - 1, first=True)  # nothing was reported
     return eval_loss
 
 
@@ -245,18 +260,19 @@ def transformer_trial(ctx) -> None:
     mesh = ctx.mesh
     strategy = str(p.get("attn", "ring"))
 
-    model = TransformerLM(
-        vocab_size=vocab,
-        d_model=int(p.get("d_model", 128)),
-        n_heads=int(p.get("n_heads", 4)),
-        n_layers=int(p.get("n_layers", 2)),
-        max_seq_len=seq_len,
-        dropout=float(p.get("dropout", 0.0)),
-        attn_fn=make_attention_fn(mesh, strategy=strategy),
-    )
-    data = markov_dataset(
-        vocab, int(p.get("n_seq", 512)), seq_len, seed=int(p.get("data_seed", 0))
-    )
+    with tracing.span("trial.data"):
+        model = TransformerLM(
+            vocab_size=vocab,
+            d_model=int(p.get("d_model", 128)),
+            n_heads=int(p.get("n_heads", 4)),
+            n_layers=int(p.get("n_layers", 2)),
+            max_seq_len=seq_len,
+            dropout=float(p.get("dropout", 0.0)),
+            attn_fn=make_attention_fn(mesh, strategy=strategy),
+        )
+        data = markov_dataset(
+            vocab, int(p.get("n_seq", 512)), seq_len, seed=int(p.get("data_seed", 0))
+        )
 
     def report(step, loss, eval_loss):
         return ctx.report(step=step, loss=loss, eval_loss=eval_loss)

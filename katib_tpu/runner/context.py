@@ -24,6 +24,7 @@ from typing import Any, Mapping
 from katib_tpu.core.types import MetricLog
 from katib_tpu.earlystop.rules import RuleEvaluator
 from katib_tpu.store.base import ObservationStore
+from katib_tpu.utils import tracing
 
 
 class TrialEarlyStopped(Exception):
@@ -83,23 +84,27 @@ class TrialContext:
         ``ctx.report(accuracy=0.91, loss=0.3, step=epoch)`` replaces the
         reference's ``print("accuracy=0.91")`` + sidecar regex scrape.
         """
-        if self._heartbeat is not None:
-            self._heartbeat()
-        if step is None:
-            step = self._step
-            self._step += 1
-        else:
-            self._step = step + 1
-        now = time.time()
-        logs = [
-            MetricLog(metric_name=k, value=float(v), timestamp=now, step=step)
-            for k, v in metrics.items()
-        ]
-        self._store.report(self.trial_name, logs)
-        if self._evaluator is not None:
-            for log in logs:
-                self._evaluator.observe(log.metric_name, log.value)
-        return not self.should_stop()
+        # the runner / observation store boundary: heartbeat, store write,
+        # rule evaluation
+        with tracing.span("report") as sp:
+            if self._heartbeat is not None:
+                self._heartbeat()
+            if step is None:
+                step = self._step
+                self._step += 1
+            else:
+                self._step = step + 1
+            sp.set(step=step)
+            now = time.time()
+            logs = [
+                MetricLog(metric_name=k, value=float(v), timestamp=now, step=step)
+                for k, v in metrics.items()
+            ]
+            self._store.report(self.trial_name, logs)
+            if self._evaluator is not None:
+                for log in logs:
+                    self._evaluator.observe(log.metric_name, log.value)
+            return not self.should_stop()
 
     # -- early stopping ------------------------------------------------------
 

@@ -378,6 +378,9 @@ def _run_whitebox(
         started_holder[0] = get_clock().perf_counter()  # first-step clock starts here
         last_beat[0] = started_holder[0]
         with tracing.span("train_fn", trial=trial.name) as sp:
+            # the trial's compile totals, 0 where it built nothing
+            for counter in tracing.JIT_COUNTERS:
+                sp.add(counter, 0)
             trial.spec.train_fn(ctx)
             if cost_attrs:
                 sp.set(**cost_attrs)
