@@ -22,9 +22,11 @@ meaningfully.
 
 from __future__ import annotations
 
+import threading
+from collections import OrderedDict
 from contextlib import nullcontext
-from functools import partial
-from typing import Callable
+from functools import lru_cache, partial
+from typing import Callable, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -32,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from katib_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, shard_batch
+from katib_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, replicated, shard_batch
 from katib_tpu.parallel.ring_attention import make_sequence_parallel_attention
 from katib_tpu.parallel.train import TrainState, clip_by_global_norm
 from katib_tpu.utils import tracing
@@ -104,16 +106,27 @@ def _dense_causal_attention(q, k, v):
     return reference_attention(q, k, v, causal=True)
 
 
+def _flash_causal_attention(q, k, v):
+    from katib_tpu.ops.flash_attention import flash_attention
+
+    return flash_attention(q, k, v, causal=True)
+
+
+@lru_cache(maxsize=8)
+def _sequence_parallel_attention(mesh, strategy: str):
+    return make_sequence_parallel_attention(mesh, strategy=strategy, causal=True)
+
+
 def make_attention_fn(mesh=None, strategy: str = "ring"):
     """Attention for a trial's mesh: sequence-parallel when the mesh has a
-    ``seq`` axis > 1, single-device flash/dense otherwise."""
+    ``seq`` axis > 1, single-device flash/dense otherwise.  The same
+    arguments give the same callable, so that models built from equal fields
+    compare equal and share their programs (``_programs_for``)."""
     if mesh is None:
-        from katib_tpu.ops.flash_attention import flash_attention
-
         if jax.default_backend() == "tpu":
-            return lambda q, k, v: flash_attention(q, k, v, causal=True)
+            return _flash_causal_attention
         return _dense_causal_attention
-    return make_sequence_parallel_attention(mesh, strategy=strategy, causal=True)
+    return _sequence_parallel_attention(mesh, strategy)
 
 
 # ---------------------------------------------------------------------------
@@ -151,6 +164,109 @@ def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
     return jnp.mean(nll)
 
 
+#: AdamW's decoupled weight decay, on every parameter
+WEIGHT_DECAY = 0.01
+
+
+def warmup_cosine(count, peak, warmup_steps, steps):
+    """``optax.warmup_cosine_decay_schedule(0.0, peak, warmup_steps, steps)``
+    at ``count``, in its float32 arithmetic, with every argument an operand of
+    the program: linear from 0 to ``peak`` over ``warmup_steps`` updates, then
+    a cosine to 0 at ``steps``."""
+    warm = peak - peak * (1 - jnp.clip(count, 0, warmup_steps) / warmup_steps)
+    span = jnp.maximum(steps - warmup_steps, 1).astype(jnp.float32)
+    t = jnp.minimum(count - warmup_steps, span)
+    cosine = peak * (0.5 * (1 + jnp.cos(jnp.pi * t / span)))
+    return jnp.where(count < warmup_steps, warm, cosine)
+
+
+class TrialPrograms(NamedTuple):
+    """The jitted programs of one trial structure; each compiles on its first
+    call and for each new shape, as any jitted function does."""
+
+    init: Callable  # (key, seq_len) -> TrainState
+    step_fn: Callable  # (state, tokens, dropout_key, lr, warmup_steps, steps) -> (state, loss)
+    eval_fn: Callable  # (params, tokens) -> loss
+
+
+def _build_programs(model: TransformerLM, grad_clip: float, weight_decay: float, mesh) -> TrialPrograms:
+    # AdamW without its rate: ``step_fn`` scales the update by the schedule's
+    # value, so lr, steps and warmup_frac are operands and not constants
+    tx = optax.chain(optax.scale_by_adam(), optax.add_decayed_weights(weight_decay))
+    use_dropout = model.dropout > 0.0
+    # init batch must divide the mesh's data axis (the attention shard_map
+    # shards the batch dimension even while tracing init)
+    init_batch = 1
+    if mesh is not None and DATA_AXIS in mesh.shape:
+        init_batch = mesh.shape[DATA_AXIS]
+
+    def loss_fn(params, tokens, dropout_key):
+        if use_dropout:
+            logits = model.apply(
+                params, tokens, deterministic=False, rngs={"dropout": dropout_key}
+            )
+        else:
+            logits = model.apply(params, tokens)
+        return lm_loss(logits, tokens)
+
+    # parameters and optimizer state in one program (the forward pass that
+    # ``model.init`` traces is dead code in it), replicated over the mesh
+    @partial(
+        jax.jit, static_argnums=1, out_shardings=None if mesh is None else replicated(mesh)
+    )
+    def init(key, seq_len):
+        params = model.init(key, jnp.zeros((init_batch, seq_len), jnp.int32))
+        return TrainState.create(params, tx)
+
+    # donate the state: params + optimizer buffers are dead after the step,
+    # so XLA updates them in place instead of copying each iteration
+    @partial(jax.jit, donate_argnums=(0,))
+    def step_fn(state: TrainState, tokens, dropout_key, lr, warmup_steps, steps):
+        loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens, dropout_key)
+        grads, _ = clip_by_global_norm(grads, grad_clip)
+        updates, opt_state = tx.update(grads, state.opt_state, state.params)
+        rate = warmup_cosine(state.step, lr, warmup_steps, steps)
+        updates = jax.tree_util.tree_map(lambda u: -rate * u, updates)
+        params = optax.apply_updates(state.params, updates)
+        return TrainState(state.step + 1, params, opt_state), loss
+
+    @jax.jit
+    def eval_fn(params, tokens):
+        return lm_loss(model.apply(params, tokens), tokens)
+
+    return TrialPrograms(init, step_fn, eval_fn)
+
+
+# (model, grad_clip, weight decay, mesh) -> TrialPrograms: every trial of a
+# process with the same structure calls the same jitted functions, so they are
+# traced, lowered and loaded once.  flax Modules hash by field values.
+# LRU-bounded: a search over d_model or n_layers must not pin executables for
+# the life of the process.
+_PROGRAMS: OrderedDict = OrderedDict()
+_PROGRAMS_MAX = 8
+_PROGRAMS_LOCK = threading.Lock()
+
+
+def _programs_for(model: TransformerLM, grad_clip: float, mesh) -> tuple[TrialPrograms, bool]:
+    """The structure's programs, and whether the process had them already."""
+    key = (model, float(grad_clip), WEIGHT_DECAY, mesh)
+    try:
+        hash(key)
+    except TypeError:  # an unhashable attn_fn: nothing to share
+        return _build_programs(*key), False
+    # building only wraps closures in jax.jit (tracing waits for the first
+    # call), so it fits under the lock and no thread sees a half-built entry
+    with _PROGRAMS_LOCK:
+        programs = _PROGRAMS.get(key)
+        reused = programs is not None
+        if not reused:
+            programs = _PROGRAMS[key] = _build_programs(*key)
+        _PROGRAMS.move_to_end(key)
+        while len(_PROGRAMS) > _PROGRAMS_MAX:
+            _PROGRAMS.popitem(last=False)
+    return programs, reused
+
+
 def train_lm(
     model: TransformerLM,
     data: np.ndarray,
@@ -167,56 +283,21 @@ def train_lm(
 ) -> float:
     """Train on ``data`` [N, S]; returns final eval loss on a held-out tail.
     Calls ``report(step, loss, eval_loss)`` every ``report_every`` steps."""
-    # everything up to the loop: model.init op by op, schedule and optimizer,
-    # TrainState.create, replicate, placing the eval tokens
-    with tracing.span("trial.init"):
+    # everything up to the loop: the structure's programs, parameters and
+    # optimizer state, the schedule's operands, placing the eval tokens
+    with tracing.span("trial.init") as sp:
         rng = np.random.default_rng(seed)
         n_eval = max(batch_size, len(data) // 10)
         train, heldout = data[:-n_eval], data[-n_eval:]
 
-        # init batch must divide the mesh's data axis (the attention shard_map
-        # shards the batch dimension even while tracing init)
-        init_batch = 1
-        if mesh is not None and DATA_AXIS in mesh.shape:
-            init_batch = mesh.shape[DATA_AXIS]
-        params = model.init(
-            jax.random.PRNGKey(seed), jnp.zeros((init_batch, data.shape[1]), jnp.int32)
+        programs, reused = _programs_for(model, grad_clip, mesh)
+        sp.set(programs="reused" if reused else "built")
+        state = programs.init(jax.random.PRNGKey(seed), data.shape[1])
+        schedule = (
+            jnp.float32(lr),
+            jnp.int32(max(1, int(steps * warmup_frac))),
+            jnp.int32(steps),
         )
-        sched = optax.warmup_cosine_decay_schedule(
-            0.0, lr, max(1, int(steps * warmup_frac)), steps
-        )
-        tx = optax.adamw(sched, weight_decay=0.01)
-
-        use_dropout = model.dropout > 0.0
-
-        def loss_fn(params, tokens, dropout_key):
-            if use_dropout:
-                logits = model.apply(
-                    params, tokens, deterministic=False, rngs={"dropout": dropout_key}
-                )
-            else:
-                logits = model.apply(params, tokens)
-            return lm_loss(logits, tokens)
-
-        # donate the state: params + optimizer buffers are dead after the step,
-        # so XLA updates them in place instead of copying each iteration
-        @partial(jax.jit, donate_argnums=(0,))
-        def step_fn(state: TrainState, tokens, dropout_key):
-            loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens, dropout_key)
-            grads, _ = clip_by_global_norm(grads, grad_clip)
-            updates, opt_state = tx.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-            return TrainState(state.step + 1, params, opt_state), loss
-
-        @jax.jit
-        def eval_fn(params, tokens):
-            return lm_loss(model.apply(params, tokens), tokens)
-
-        state = TrainState.create(params, tx)
-        if mesh is not None:
-            from katib_tpu.parallel.mesh import replicate
-
-            state = replicate(state, mesh)
 
         def place(tokens):
             tokens = jnp.asarray(tokens)
@@ -227,18 +308,18 @@ def train_lm(
         dkey = jax.random.PRNGKey(seed + 1)
 
     def evaluate(step: int, first: bool) -> float:
-        # the first call also traces, lowers and loads the eval program
         with tracing.span("trial.eval", step=step, first=first):
-            return float(eval_fn(state.params, eval_tokens))
+            return float(programs.eval_fn(state.params, eval_tokens))
 
     for s in range(steps):
         idx = rng.integers(0, len(train), size=batch_size)
         dkey, sub = jax.random.split(dkey)
         tokens = place(train[idx])
-        # the first call alone, call to return (it is asynchronous): trace,
-        # lower, cache lookup, executable load and dispatch
+        # the first call alone, call to return (it is asynchronous): dispatch,
+        # and where the process has not run this structure and shape yet,
+        # trace, lower, cache lookup and executable load
         with tracing.span("trial.first_step") if s == 0 else nullcontext():
-            state, loss = step_fn(state, tokens, sub)
+            state, loss = programs.step_fn(state, tokens, sub, *schedule)
         eval_loss = None  # stale after this step's update
         if report is not None and (s % report_every == 0 or s == steps - 1):
             eval_loss = evaluate(s, first=s == 0)

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 # interpreter-mode Pallas + sharded training loops: merge-gate tier
-pytestmark = pytest.mark.slow
+slow = pytest.mark.slow
 
 from katib_tpu.ops.flash_attention import (
     flash_attention,
@@ -29,6 +29,7 @@ def _qkv(b=2, h=2, s=64, d=16, seed=0):
     return tuple(jax.random.normal(k, (b, h, s, d), jnp.float32) for k in keys)
 
 
+@slow
 class TestFlashAttention:
     @pytest.mark.parametrize("causal", [True, False])
     @pytest.mark.parametrize("blocks", [(32, 32), (32, 64), (64, 32)])
@@ -108,6 +109,7 @@ class TestFlashAttention:
             np.testing.assert_allclose(a, b, atol=2e-5)
 
 
+@slow
 class TestSequenceParallelAttention:
     @pytest.mark.parametrize("strategy", ["ring", "ulysses"])
     @pytest.mark.parametrize("causal", [True, False])
@@ -144,6 +146,7 @@ class TestSequenceParallelAttention:
         )
 
 
+@slow
 class TestTransformerLM:
     def test_training_reduces_loss_on_sharded_mesh(self):
         from katib_tpu.models.transformer import (
@@ -210,3 +213,254 @@ class TestTransformerLM:
         assert exp.condition is ExperimentCondition.MAX_TRIALS_REACHED
         assert exp.completed_count == 2
         assert exp.optimal is not None
+
+
+# -- a trial's programs, built once a process (tier-1: tiny, dense, CPU) ------
+
+TINY = dict(vocab_size=32, d_model=16, n_heads=2, max_seq_len=16)
+
+
+def _tiny_lm(n_layers=2, mesh=None, **fields):
+    from katib_tpu.models.transformer import TransformerLM, make_attention_fn
+
+    fields.setdefault("attn_fn", make_attention_fn(mesh))
+    return TransformerLM(n_layers=n_layers, **{**TINY, **fields})
+
+
+def _tiny_data(seed=0):
+    from katib_tpu.models.transformer import markov_dataset
+
+    return markov_dataset(TINY["vocab_size"], 48, TINY["max_seq_len"], seed=seed)
+
+
+def _traced_train_lm(tmp_path, name, model, **kwargs):
+    """``train_lm`` inside a ``train_fn`` span, as the trial runner calls it;
+    returns the reported losses and the journal's records by span name."""
+    from katib_tpu.models.transformer import train_lm
+    from katib_tpu.utils import tracing
+
+    path = str(tmp_path / f"{name}.jsonl")
+    tracer = tracing.Tracer(path)
+    losses = []
+    with tracing.use_tracer(tracer), tracing.span("train_fn", trial=name) as sp:
+        for counter in tracing.JIT_COUNTERS:  # as runner/trial_runner.py opens it
+            sp.add(counter, 0)
+        train_lm(
+            model, _tiny_data(), batch_size=4,
+            report=lambda step, loss, eval_loss: losses.append((loss, eval_loss)), **kwargs,
+        )
+    tracer.close()
+    return losses, {r["name"]: r["args"] for r in tracing.read_journal(path)}
+
+
+class TestTrialPrograms:
+    @pytest.mark.parametrize(
+        "second",
+        [
+            dict(lr=3e-3, steps=6, warmup_frac=0.1),
+            dict(lr=1e-3, steps=9, warmup_frac=0.1),
+            dict(lr=1e-3, steps=6, warmup_frac=0.5),
+            dict(lr=1e-4, steps=3, warmup_frac=0.4),
+        ],
+        ids=["lr", "steps", "warmup_frac", "all"],
+    )
+    def test_equal_structure_reuses_programs(self, tmp_path, second):
+        # dropout 0.05 is this test's own structure: whatever ran before in
+        # the process, the first call here is the one that builds at most
+        _traced_train_lm(tmp_path, "a", _tiny_lm(dropout=0.05), lr=1e-3, steps=6, warmup_frac=0.1)
+        losses, spans = _traced_train_lm(tmp_path, "b", _tiny_lm(dropout=0.05), **second)
+        assert spans["trial.init"]["programs"] == "reused"
+        assert spans["train_fn"]["jit_programs"] == 0
+        assert spans["train_fn"]["jit_trace_s"] == spans["train_fn"]["jit_lower_s"] == 0
+        assert len(losses) >= 2 and np.all(np.isfinite(losses))
+
+    def test_new_depth_builds_and_trains_its_own(self, tmp_path):
+        from katib_tpu.models import transformer
+
+        _traced_train_lm(tmp_path, "a", _tiny_lm(2, dropout=0.07), lr=1e-3, steps=3)
+        _, spans = _traced_train_lm(tmp_path, "b", _tiny_lm(3, dropout=0.07), lr=1e-3, steps=3)
+        assert spans["trial.init"]["programs"] == "built"
+        assert spans["train_fn"]["jit_programs"] >= 3  # init, step_fn, eval_fn
+        blocks = {}
+        for depth in (2, 3):
+            programs, reused = transformer._programs_for(_tiny_lm(depth, dropout=0.07), 1.0, None)
+            assert reused
+            state = jax.eval_shape(lambda k: programs.init(k, 16), jax.random.PRNGKey(0))
+            blocks[depth] = sorted(k for k in state.params["params"] if k.startswith("Block_"))
+            # AdamW's two moments, shaped as the parameters
+            assert jax.tree_util.tree_structure(state.opt_state[0].mu) == jax.tree_util.tree_structure(state.params)
+        assert blocks == {2: ["Block_0", "Block_1"], 3: ["Block_0", "Block_1", "Block_2"]}
+
+    def test_same_arguments_same_attention(self):
+        from katib_tpu.models.transformer import make_attention_fn
+
+        assert make_attention_fn() is make_attention_fn()
+        mesh = make_mesh({DATA_AXIS: 2, SEQ_AXIS: 4})
+        again = make_mesh({DATA_AXIS: 2, SEQ_AXIS: 4})
+        assert make_attention_fn(mesh, "ring") is make_attention_fn(again, strategy="ring")
+        assert make_attention_fn(mesh, "ring") is not make_attention_fn(mesh, "ulysses")
+        assert _tiny_lm(mesh=mesh) == _tiny_lm(mesh=again)
+        assert hash(_tiny_lm(mesh=mesh)) == hash(_tiny_lm(mesh=again))
+
+    @pytest.mark.parametrize("attention", ["of_the_mesh", "dense"])
+    def test_two_meshes_do_not_share_an_entry(self, attention):
+        from katib_tpu.models import transformer
+
+        a = make_mesh({DATA_AXIS: 2, SEQ_AXIS: 4})
+        b = make_mesh({DATA_AXIS: 4, SEQ_AXIS: 2})
+
+        def model(mesh):
+            return _tiny_lm(mesh=mesh) if attention == "of_the_mesh" else _tiny_lm(attn_fn=None)
+
+        on_a, _ = transformer._programs_for(model(a), 1.0, a)
+        on_b, _ = transformer._programs_for(model(b), 1.0, b)
+        on_none, _ = transformer._programs_for(model(None), 1.0, None)
+        assert on_a is not on_b and on_a is not on_none and on_b is not on_none
+        assert transformer._programs_for(model(a), 1.0, a) == (on_a, True)
+        # the state comes out replicated over its own mesh
+        state = on_b.init(jax.random.PRNGKey(0), 16)
+        assert all(x.sharding.mesh == b for x in jax.tree_util.tree_leaves(state))
+        assert all(x.sharding.is_fully_replicated for x in jax.tree_util.tree_leaves(state))
+
+    def test_grad_clip_is_part_of_the_key(self):
+        from katib_tpu.models import transformer
+
+        one, _ = transformer._programs_for(_tiny_lm(), 1.0, None)
+        half, _ = transformer._programs_for(_tiny_lm(), 0.5, None)
+        assert one is not half
+        assert transformer._programs_for(_tiny_lm(), 1, None)[0] is one
+
+    def test_table_is_bounded_least_recently_used_goes(self, monkeypatch):
+        from collections import OrderedDict
+
+        from katib_tpu.models import transformer
+
+        monkeypatch.setattr(transformer, "_PROGRAMS", OrderedDict())
+        widths = [8 * (i + 1) for i in range(transformer._PROGRAMS_MAX + 1)]
+        first = [transformer._programs_for(_tiny_lm(max_seq_len=w), 1.0, None)[0] for w in widths[:-1]]
+        # the oldest entry is used again, so the second oldest goes
+        assert transformer._programs_for(_tiny_lm(max_seq_len=widths[0]), 1.0, None) == (first[0], True)
+        transformer._programs_for(_tiny_lm(max_seq_len=widths[-1]), 1.0, None)
+        assert len(transformer._PROGRAMS) == transformer._PROGRAMS_MAX
+        assert transformer._programs_for(_tiny_lm(max_seq_len=widths[0]), 1.0, None) == (first[0], True)
+        assert transformer._programs_for(_tiny_lm(max_seq_len=widths[1]), 1.0, None)[1] is False
+
+    def test_threads_get_one_whole_entry(self, monkeypatch):
+        """More threads than cores ask for one new structure at once: every
+        one is handed the same complete entry, and one of them built it."""
+        import sys
+        import threading
+        from collections import OrderedDict
+
+        from katib_tpu.models import transformer
+
+        monkeypatch.setattr(transformer, "_PROGRAMS", OrderedDict())
+        n = 32
+        got = [None] * n
+        start = threading.Barrier(n)
+
+        def ask(i):
+            start.wait(timeout=30)
+            got[i] = transformer._programs_for(_tiny_lm(), 1.0, None)
+
+        threads = [threading.Thread(target=ask, args=(i,)) for i in range(n)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert all(g is not None and g[0] is got[0][0] for g in got)
+        assert all(callable(f) for f in got[0][0])
+        assert sorted(reused for _, reused in got) == [False] + [True] * (n - 1)
+
+
+def _old_way_losses(model, data, *, lr, steps, batch_size, warmup_frac, grad_clip=1.0, seed=0):
+    """The loop ``train_lm`` had before its programs were shared: the schedule
+    a constant of ``optax.adamw``, closures of this one call, eager init."""
+    import optax
+
+    from katib_tpu.models.transformer import lm_loss
+    from katib_tpu.parallel.train import TrainState, clip_by_global_norm
+
+    rng = np.random.default_rng(seed)
+    n_eval = max(batch_size, len(data) // 10)
+    train, heldout = data[:-n_eval], data[-n_eval:]
+    params = model.init(jax.random.PRNGKey(seed), jnp.zeros((1, data.shape[1]), jnp.int32))
+    sched = optax.warmup_cosine_decay_schedule(0.0, lr, max(1, int(steps * warmup_frac)), steps)
+    tx = optax.adamw(sched, weight_decay=0.01)
+
+    @jax.jit
+    def step_fn(state, tokens):
+        loss, grads = jax.value_and_grad(lambda p: lm_loss(model.apply(p, tokens), tokens))(state.params)
+        grads, _ = clip_by_global_norm(grads, grad_clip)
+        updates, opt_state = tx.update(grads, state.opt_state, state.params)
+        return TrainState(state.step + 1, optax.apply_updates(state.params, updates), opt_state), loss
+
+    @jax.jit
+    def eval_fn(params, tokens):
+        return lm_loss(model.apply(params, tokens), tokens)
+
+    state = TrainState.create(params, tx)
+    eval_tokens = jnp.asarray(heldout[:batch_size])
+    out = []
+    for _ in range(steps):
+        tokens = jnp.asarray(train[rng.integers(0, len(train), size=batch_size)])
+        state, loss = step_fn(state, tokens)
+        out.append((float(loss), float(eval_fn(state.params, eval_tokens))))
+    return out
+
+
+class TestSameMathematics:
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    @pytest.mark.parametrize("lr", [1e-3, 3e-2])
+    @pytest.mark.parametrize("steps,warmup_frac", [(12, 0.1), (12, 0.5), (30, 0.25)])
+    def test_losses_equal_the_constants_baked_in(self, lr, steps, warmup_frac, dtype):
+        """Parameters, optimizer state and logits are float32 either way;
+        ``dtype`` is the activations' (the program's default is bf16)."""
+        from katib_tpu.models.transformer import train_lm
+
+        model = _tiny_lm(dtype=dtype)
+        got = []
+        train_lm(
+            model, _tiny_data(), lr=lr, steps=steps, batch_size=4, warmup_frac=warmup_frac,
+            report=lambda step, loss, eval_loss: got.append((loss, eval_loss)) if step < 12 else None,
+            report_every=1,
+        )
+        want = _old_way_losses(
+            model, _tiny_data(), lr=lr, steps=steps, batch_size=4, warmup_frac=warmup_frac
+        )[:12]
+        assert len(got) == 12
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+        assert want[11][0] < want[0][0]  # and it trained
+
+    @pytest.mark.parametrize("steps,warmup_frac", [(40, 0.1), (12, 0.5), (7, 0.3), (1, 0.1)])
+    def test_schedule_is_optax_warmup_cosine(self, steps, warmup_frac):
+        import optax
+
+        from katib_tpu.models.transformer import warmup_cosine
+
+        warm = max(1, int(steps * warmup_frac))
+        counts = jnp.arange(steps + 3, dtype=jnp.int32)
+        got = jax.jit(jax.vmap(warmup_cosine, in_axes=(0, None, None, None)))(
+            counts, jnp.float32(3e-3), jnp.int32(warm), jnp.int32(steps)
+        )
+        assert got[0] == 0.0 and np.all(np.isfinite(got))
+        if steps > warm:  # optax refuses a schedule with no decay
+            want = jax.vmap(optax.warmup_cosine_decay_schedule(0.0, 3e-3, warm, steps))(counts)
+            np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-12)
+            assert got[warm] == np.float32(3e-3) and abs(got[steps]) < 1e-9
+
+    def test_dropout_runs_and_is_finite(self):
+        from katib_tpu.models.transformer import train_lm
+
+        got = []
+        final = train_lm(
+            _tiny_lm(dropout=0.1), _tiny_data(), lr=1e-3, steps=12, batch_size=4,
+            report=lambda step, loss, eval_loss: got.append((loss, eval_loss)),
+        )
+        assert len(got) == 3 and np.all(np.isfinite(got)) and np.isfinite(final)

@@ -578,7 +578,7 @@ class TestOrchestratorTracing:
                 names.append(rec["name"])
             return names
 
-        for trial in exp.trials:
+        for nth, trial in enumerate(exp.trials):
             mine = [r for r in recs if r.get("args", {}).get("trial") == trial]
             count = lambda name: sum(r["name"] == name for r in mine)  # noqa: E731
             assert count("trial.data") == count("trial.init") == count("trial.first_step") == 1
@@ -593,7 +593,15 @@ class TestOrchestratorTracing:
             assert first_eval["args"]["step"] == 0
             (train,) = [r for r in mine if r["name"] == "train_fn"]
             assert set(tracing.JIT_COUNTERS) <= set(train["args"])
-            # step_fn and eval_fn are built anew by every trial
-            assert train["args"]["jit_programs"] >= 2
+            # init, step_fn and eval_fn are built by the process's first
+            # trial of a structure and found again by every later one
+            (init,) = [r for r in mine if r["name"] == "trial.init"]
             (first_step,) = [r for r in mine if r["name"] == "trial.first_step"]
-            assert first_step["args"]["jit_programs"] == 1
+            if init["args"]["programs"] == "built":
+                assert nth == 0
+                assert train["args"]["jit_programs"] >= 3
+                assert first_step["args"]["jit_programs"] == 1
+            else:
+                assert init["args"]["programs"] == "reused"
+                assert train["args"]["jit_programs"] == 0
+                assert not any(r["name"].startswith("jit.") for r in mine)
