@@ -10,8 +10,14 @@ axis — so HP search (lr, width, depth, heads) can drive long-sequence
 training on a sharded mesh with the same trial API as the CNN workloads.
 
 Tunable parameters understood by ``transformer_trial``: lr, d_model,
-n_heads, n_layers, seq_len, batch_size, steps, warmup_frac,
-attn(ring|ulysses), dropout.
+n_heads, n_layers, seq_len, vocab_size, batch_size, n_seq, data_seed, steps,
+warmup_frac, attn(ring|ulysses), dropout, and block(gpt2|mla_moe).  With
+``block: mla_moe`` (latent attention and sparse experts,
+``katib_tpu.models.mla_moe``) also: first_dense_layers, qk_nope_dim,
+qk_rope_dim, v_head_dim, kv_lora_rank, dense_width, expert_width, n_experts,
+experts_per_token, n_shared_experts, routed_scaling, rope_theta, eps, and the
+share of the routed experts this trial holds: experts_held_first,
+experts_held (default: all); dropout and a ``seq`` mesh axis are refused.
 
 The training task is a synthetic first-order Markov language-modelling
 problem: next-token structure is learnable (entropy well below uniform) and
@@ -22,6 +28,7 @@ meaningfully.
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 from collections import OrderedDict
 from contextlib import nullcontext
@@ -34,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from katib_tpu.models.mla_moe import ROUTING, MlaMoeLM, MlaMoeSizes
 from katib_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, replicated, shard_batch
 from katib_tpu.parallel.ring_attention import make_sequence_parallel_attention
 from katib_tpu.parallel.train import TrainState, clip_by_global_norm
@@ -72,6 +80,8 @@ class Block(nn.Module):
 
 
 class TransformerLM(nn.Module):
+    BLOCK = "gpt2"  # the block family's name, as ``transformer_trial`` takes it
+
     vocab_size: int
     d_model: int = 128
     n_heads: int = 4
@@ -185,15 +195,20 @@ class TrialPrograms(NamedTuple):
     call and for each new shape, as any jitted function does."""
 
     init: Callable  # (key, seq_len) -> TrainState
-    step_fn: Callable  # (state, tokens, dropout_key, lr, warmup_steps, steps) -> (state, loss)
+    # (state, tokens, dropout_key, lr, warmup_steps, steps) -> (state, loss,
+    # routing): what the model's expert layers sowed into ``ROUTING`` this
+    # step; an empty tree for a model that has none
+    step_fn: Callable
     eval_fn: Callable  # (params, tokens) -> loss
 
 
-def _build_programs(model: TransformerLM, grad_clip: float, weight_decay: float, mesh) -> TrialPrograms:
+def _build_programs(
+    model: TransformerLM | MlaMoeLM, grad_clip: float, weight_decay: float, mesh
+) -> TrialPrograms:
     # AdamW without its rate: ``step_fn`` scales the update by the schedule's
     # value, so lr, steps and warmup_frac are operands and not constants
     tx = optax.chain(optax.scale_by_adam(), optax.add_decayed_weights(weight_decay))
-    use_dropout = model.dropout > 0.0
+    use_dropout = getattr(model, "dropout", 0.0) > 0.0
     # init batch must divide the mesh's data axis (the attention shard_map
     # shards the batch dimension even while tracing init)
     init_batch = 1
@@ -201,13 +216,9 @@ def _build_programs(model: TransformerLM, grad_clip: float, weight_decay: float,
         init_batch = mesh.shape[DATA_AXIS]
 
     def loss_fn(params, tokens, dropout_key):
-        if use_dropout:
-            logits = model.apply(
-                params, tokens, deterministic=False, rngs={"dropout": dropout_key}
-            )
-        else:
-            logits = model.apply(params, tokens)
-        return lm_loss(logits, tokens)
+        dropout = {"deterministic": False, "rngs": {"dropout": dropout_key}} if use_dropout else {}
+        logits, sown = model.apply(params, tokens, mutable=[ROUTING], **dropout)
+        return lm_loss(logits, tokens), sown.get(ROUTING, {})
 
     # parameters and optimizer state in one program (the forward pass that
     # ``model.init`` traces is dead code in it), replicated over the mesh
@@ -222,13 +233,15 @@ def _build_programs(model: TransformerLM, grad_clip: float, weight_decay: float,
     # so XLA updates them in place instead of copying each iteration
     @partial(jax.jit, donate_argnums=(0,))
     def step_fn(state: TrainState, tokens, dropout_key, lr, warmup_steps, steps):
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens, dropout_key)
+        (loss, routing), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state.params, tokens, dropout_key
+        )
         grads, _ = clip_by_global_norm(grads, grad_clip)
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
         rate = warmup_cosine(state.step, lr, warmup_steps, steps)
         updates = jax.tree_util.tree_map(lambda u: -rate * u, updates)
         params = optax.apply_updates(state.params, updates)
-        return TrainState(state.step + 1, params, opt_state), loss
+        return TrainState(state.step + 1, params, opt_state), loss, routing
 
     @jax.jit
     def eval_fn(params, tokens):
@@ -247,7 +260,9 @@ _PROGRAMS_MAX = 8
 _PROGRAMS_LOCK = threading.Lock()
 
 
-def _programs_for(model: TransformerLM, grad_clip: float, mesh) -> tuple[TrialPrograms, bool]:
+def _programs_for(
+    model: TransformerLM | MlaMoeLM, grad_clip: float, mesh
+) -> tuple[TrialPrograms, bool]:
     """The structure's programs, and whether the process had them already."""
     key = (model, float(grad_clip), WEIGHT_DECAY, mesh)
     try:
@@ -268,7 +283,7 @@ def _programs_for(model: TransformerLM, grad_clip: float, mesh) -> tuple[TrialPr
 
 
 def train_lm(
-    model: TransformerLM,
+    model: TransformerLM | MlaMoeLM,
     data: np.ndarray,
     *,
     lr: float,
@@ -291,7 +306,7 @@ def train_lm(
         train, heldout = data[:-n_eval], data[-n_eval:]
 
         programs, reused = _programs_for(model, grad_clip, mesh)
-        sp.set(programs="reused" if reused else "built")
+        sp.set(programs="reused" if reused else "built", block=model.BLOCK)
         state = programs.init(jax.random.PRNGKey(seed), data.shape[1])
         schedule = (
             jnp.float32(lr),
@@ -305,11 +320,17 @@ def train_lm(
 
         eval_tokens = place(heldout[:batch_size])
         eval_loss: float | None = None
+        routing = None
         dkey = jax.random.PRNGKey(seed + 1)
 
     def evaluate(step: int, first: bool) -> float:
-        with tracing.span("trial.eval", step=step, first=first):
-            return float(programs.eval_fn(state.params, eval_tokens))
+        with tracing.span("trial.eval", step=step, first=first) as sp:
+            value = float(programs.eval_fn(state.params, eval_tokens))
+            if routing:
+                # the last step's routing counts, as the model with the expert
+                # layers reads them; fetched where the loss is: no wait of its own
+                sp.set(**model.step_counters(jax.device_get(routing)))
+            return value
 
     for s in range(steps):
         idx = rng.integers(0, len(train), size=batch_size)
@@ -319,7 +340,7 @@ def train_lm(
         # and where the process has not run this structure and shape yet,
         # trace, lower, cache lookup and executable load
         with tracing.span("trial.first_step") if s == 0 else nullcontext():
-            state, loss = programs.step_fn(state, tokens, sub, *schedule)
+            state, loss, routing = programs.step_fn(state, tokens, sub, *schedule)
         eval_loss = None  # stale after this step's update
         if report is not None and (s % report_every == 0 or s == steps - 1):
             eval_loss = evaluate(s, first=s == 0)
@@ -333,6 +354,37 @@ def train_lm(
 # -- the white-box trial function -------------------------------------------
 
 
+def _mla_moe_model(p, vocab: int, mesh) -> MlaMoeLM:
+    """The ``block: mla_moe`` model from a trial's parameters: every size of
+    ``MlaMoeSizes`` under its own name, the experts held as two integers."""
+    if mesh is not None and mesh.shape.get(SEQ_AXIS, 1) > 1:
+        raise ValueError(
+            "transformer_trial: block 'mla_moe' cannot run on a mesh with a 'seq' axis: the "
+            "ring and all-to-all attention paths assume keys and values of one width"
+        )
+    if float(p.get("dropout", 0.0)) > 0.0:
+        raise ValueError("transformer_trial: block 'mla_moe' has no dropout")
+    sizes = {
+        f.name: type(f.default)(p.get(f.name, f.default))
+        for f in dataclasses.fields(MlaMoeSizes)
+        if f.name != "experts_held"
+    }
+    held = (
+        int(p.get("experts_held_first", 0)),
+        int(p.get("experts_held", sizes["n_experts"])),
+    )
+    if not 0 <= held[0] < held[0] + held[1] <= sizes["n_experts"]:
+        raise ValueError(
+            f"transformer_trial: experts held {held} (first, count) lie outside the "
+            f"{sizes['n_experts']} routed experts"
+        )
+    return MlaMoeLM(
+        vocab_size=vocab,
+        sizes=MlaMoeSizes(experts_held=held, **sizes),
+        attn_fn=make_attention_fn(mesh),
+    )
+
+
 def transformer_trial(ctx) -> None:
     """White-box trial: tunable long-context LM reporting train/eval loss."""
     p = ctx.params
@@ -342,15 +394,21 @@ def transformer_trial(ctx) -> None:
     strategy = str(p.get("attn", "ring"))
 
     with tracing.span("trial.data"):
-        model = TransformerLM(
-            vocab_size=vocab,
-            d_model=int(p.get("d_model", 128)),
-            n_heads=int(p.get("n_heads", 4)),
-            n_layers=int(p.get("n_layers", 2)),
-            max_seq_len=seq_len,
-            dropout=float(p.get("dropout", 0.0)),
-            attn_fn=make_attention_fn(mesh, strategy=strategy),
-        )
+        block = str(p.get("block", "gpt2"))
+        if block == "gpt2":
+            model = TransformerLM(
+                vocab_size=vocab,
+                d_model=int(p.get("d_model", 128)),
+                n_heads=int(p.get("n_heads", 4)),
+                n_layers=int(p.get("n_layers", 2)),
+                max_seq_len=seq_len,
+                dropout=float(p.get("dropout", 0.0)),
+                attn_fn=make_attention_fn(mesh, strategy=strategy),
+            )
+        elif block == "mla_moe":
+            model = _mla_moe_model(p, vocab, mesh)
+        else:
+            raise ValueError(f"transformer_trial: block {block!r} is neither 'gpt2' nor 'mla_moe'")
         data = markov_dataset(
             vocab, int(p.get("n_seq", 512)), seq_len, seed=int(p.get("data_seed", 0))
         )

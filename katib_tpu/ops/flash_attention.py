@@ -61,7 +61,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_
     """``shift = seq_k - seq_q`` makes the causal mask bottom-right aligned
     (last query row sees every key), matching ``reference_attention_with_lse``
     for seq_q != seq_k; both collapse to the usual mask when shift == 0."""
-    bq, d = q_ref.shape[-2], q_ref.shape[-1]
+    bq, d_v = q_ref.shape[-2], v_ref.shape[-1]
     seq_k = k_ref.shape[-2]
     n_kb = seq_k // block_k
     qi = pl.program_id(2)
@@ -100,7 +100,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_
         )
         return o_new, m_new, l_new
 
-    o0 = jnp.zeros((bq, d), jnp.float32)
+    o0 = jnp.zeros((bq, d_v), jnp.float32)
     m0 = jnp.full((bq,), _MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
     o, m, l = jax.lax.fori_loop(0, n_kb_live, body, (o0, m0, l0))
@@ -115,7 +115,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_
 
 def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, d_v = k.shape[2], v.shape[3]
     bq, bk = _block_sizes(sq, sk, block_q, block_k)
     grid = (b, h, sq // bq)
     o, lse = pl.pallas_call(
@@ -127,14 +127,14 @@ def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda i, j, l: (i, j, l, 0)),
             pl.BlockSpec((1, 1, sk, d), lambda i, j, l: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, sk, d), lambda i, j, l: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, sk, d_v), lambda i, j, l: (i, j, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda i, j, l: (i, j, l, 0)),
+            pl.BlockSpec((1, 1, bq, d_v), lambda i, j, l: (i, j, l, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda i, j, l: (i, j, l, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, d_v), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ],
         interpret=interpret,
@@ -227,15 +227,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dk_ref, dv_ref, *
         )
         return dk_new, dv_new
 
-    z = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(first_qb, n_qb, body, (z, z))
+    zk = jnp.zeros((bk, d), jnp.float32)
+    zv = zk if v_ref.shape[-1] == d else jnp.zeros((bk, v_ref.shape[-1]), jnp.float32)
+    dk, dv = jax.lax.fori_loop(first_qb, n_qb, body, (zk, zv))
     dk_ref[0, 0, :, :] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0, :, :] = dv.astype(dv_ref.dtype)
 
 
 def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
-    sk = k.shape[2]
+    sk, d_v = k.shape[2], v.shape[3]
     bq, bk = _block_sizes(sq, sk, block_q, block_k)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     dmd = delta - dlse.astype(jnp.float32)  # [b, h, sq]
@@ -250,8 +251,8 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda i, j, l: (i, j, l, 0)),
             pl.BlockSpec((1, 1, sk, d), lambda i, j, l: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, sk, d), lambda i, j, l: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda i, j, l: (i, j, l, 0)),
+            pl.BlockSpec((1, 1, sk, d_v), lambda i, j, l: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, bq, d_v), lambda i, j, l: (i, j, l, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda i, j, l: (i, j, l, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda i, j, l: (i, j, l, 0)),
         ],
@@ -268,14 +269,14 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
         in_specs=[
             pl.BlockSpec((1, 1, sq, d), lambda i, j, l: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda i, j, l: (i, j, l, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda i, j, l: (i, j, l, 0)),
-            pl.BlockSpec((1, 1, sq, d), lambda i, j, l: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, bk, d_v), lambda i, j, l: (i, j, l, 0)),
+            pl.BlockSpec((1, 1, sq, d_v), lambda i, j, l: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, sq, 1), lambda i, j, l: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, sq, 1), lambda i, j, l: (i, j, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda i, j, l: (i, j, l, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda i, j, l: (i, j, l, 0)),
+            pl.BlockSpec((1, 1, bk, d_v), lambda i, j, l: (i, j, l, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -302,7 +303,10 @@ def flash_attention_with_lse(
     block_k: int = 128,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused attention over [batch, heads, seq, head_dim] inputs.
+    """Fused attention over [batch, heads, seq, head_dim] inputs.  The values
+    (and so the output) may have another width than the queries and keys, as
+    in latent attention (192-wide keys, 128-wide values); the scale comes from
+    the query width unless given.
 
     Returns ``(output, logsumexp)``; the logsumexp output makes this the
     mergeable building block for ring attention.  Rows with every key masked

@@ -62,6 +62,44 @@ def test_mixed_op_kernel_compiles(one_chip, shape, dtype):
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+def test_flash_attention_compiles_at_latent_widths(one_chip, grad):
+    """Keys 192 wide, values 128, 4096 positions: a whole K and V of one head
+    and, in the dkv kernel, a whole Q, dO and their row statistics sit in
+    VMEM (the benchmark's kanana-2-30b-a3b-ep8 shapes)."""
+    from katib_tpu.ops.flash_attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, interpret=False)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    qk = jax.ShapeDtypeStruct((2, 32, 4096, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 32, 4096, 128), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(fn).lower(qk, qk, v).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == (2 if grad else 1) + grad
+
+
+def test_grouped_expert_product_is_a_kernel(one_chip):
+    """``jax.lax.ragged_dot`` and both products of its transpose compile to
+    grouped-product kernels on the TPU, not to a dense product over all
+    experts with a mask (models/mla_moe.py relies on it; the benchmark's
+    EXPERT_PRODUCT_MARK finds them by their int32 group metadata)."""
+
+    def loss(x, w, sizes):
+        return jnp.sum(jax.lax.ragged_dot(x, w, sizes, preferred_element_type=jnp.float32))
+
+    x = jax.ShapeDtypeStruct((49152, 2048), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((16, 2048, 768), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w, sizes).compile().as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) >= 2 and all("operand_layout_constraints={s32[" in k for k in kernels)
+    assert "bf16[16,49152" not in text  # no [experts, rows, ...] dense intermediate
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
 def test_flash_attention_compiles(one_chip, grad):
     from katib_tpu.ops.flash_attention import flash_attention
 

@@ -172,7 +172,10 @@ class TestRetryStopResponsiveness:
         backoff (30s here) must return promptly — the backoff waits on the
         stop event instead of a blind sleep."""
 
+        attempted = threading.Event()
+
         def boom(ctx):
+            attempted.set()
             raise OSError("preempted")
 
         spec = ExperimentSpec(
@@ -189,14 +192,22 @@ class TestRetryStopResponsiveness:
             train_fn=boom,
         )
         orch = Orchestrator(workdir=str(tmp_path))
-        timer = threading.Timer(0.5, orch.stop)
-        timer.start()
-        try:
-            t0 = time.monotonic()
-            exp = orch.run(spec)
-            assert time.monotonic() - t0 < 10.0
-        finally:
-            timer.cancel()
+
+        def stop_mid_backoff():
+            # half a second after the first attempt failed, however long the
+            # experiment took to reach it (a fixed timer from run()'s start
+            # fired before the first attempt on a loaded machine)
+            if attempted.wait(timeout=20.0):
+                time.sleep(0.5)
+            orch.stop()
+
+        stopper = threading.Thread(target=stop_mid_backoff, daemon=True)
+        stopper.start()
+        t0 = time.monotonic()
+        exp = orch.run(spec)
+        assert time.monotonic() - t0 < 10.0
+        stopper.join(timeout=5.0)
+        assert not stopper.is_alive()
         assert exp.condition is ExperimentCondition.FAILED
         trial = next(iter(exp.trials.values()))
         assert trial.retry_count >= 1  # it was mid-backoff when stopped
