@@ -267,6 +267,11 @@ class MlaMoeLM(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
     attn_fn: Callable | None = None
 
+    @property
+    def attn_widths(self) -> tuple[int, int]:
+        """A head's key and value widths."""
+        return self.sizes.qk_nope_dim + self.sizes.qk_rope_dim, self.sizes.v_head_dim
+
     @nn.compact
     def __call__(self, tokens):
         z = self.sizes

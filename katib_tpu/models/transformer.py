@@ -91,6 +91,11 @@ class TransformerLM(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
     attn_fn: Callable | None = None  # default set in setup-free __call__
 
+    @property
+    def attn_widths(self) -> tuple[int, int]:
+        """A head's key and value widths."""
+        return (self.d_model // self.n_heads,) * 2
+
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True):
         attn = self.attn_fn
@@ -137,6 +142,21 @@ def make_attention_fn(mesh=None, strategy: str = "ring"):
             return _flash_causal_attention
         return _dense_causal_attention
     return _sequence_parallel_attention(mesh, strategy)
+
+
+def attn_tiles(model, seq_len: int) -> str:
+    """What a trial's attention runs, for the ``trial.init`` span: the flash
+    kernel's operand dtype and the tiles it plans for these shapes, ``dense``
+    where no kernel runs, ``seq-parallel`` over a mesh's ``seq`` axis."""
+    if model.attn_fn in (None, _dense_causal_attention):
+        return "dense"
+    if model.attn_fn is not _flash_causal_attention:
+        return "seq-parallel"
+    from katib_tpu.ops.flash_attention import plan_tiles
+
+    dtype = jnp.dtype(model.dtype)
+    bq, bk = plan_tiles(seq_len, seq_len, *model.attn_widths, dtype)
+    return f"{dtype.name} q{bq} k{bk}"
 
 
 # ---------------------------------------------------------------------------
@@ -306,7 +326,11 @@ def train_lm(
         train, heldout = data[:-n_eval], data[-n_eval:]
 
         programs, reused = _programs_for(model, grad_clip, mesh)
-        sp.set(programs="reused" if reused else "built", block=model.BLOCK)
+        sp.set(
+            programs="reused" if reused else "built",
+            block=model.BLOCK,
+            attn_tiles=attn_tiles(model, data.shape[1]),
+        )
         state = programs.init(jax.random.PRNGKey(seed), data.shape[1])
         schedule = (
             jnp.float32(lr),

@@ -7,49 +7,179 @@ MXU speed without materialising the [S, S] score matrix in HBM.
 
 Design (FlashAttention-2 style, adapted to the TPU memory hierarchy):
 
-- forward: grid over (batch, head, q-block); K/V stream through VMEM while
-  an online softmax keeps running (max, sum, output) accumulators in f32.
-  Emits the per-row logsumexp so sequence-parallel ring attention
-  (``katib_tpu.parallel.ring_attention``) can merge partial results from
-  other sequence shards.
-- backward: two kernels — dq over q-blocks, dk/dv over k-blocks — that
+- forward: grid over (batch, head, q tile); a head's K and V sit in VMEM and
+  stream through in k tiles while an online softmax keeps running (max, sum,
+  output) accumulators in f32.  Emits the per-row logsumexp so
+  sequence-parallel ring attention (``katib_tpu.parallel.ring_attention``)
+  can merge partial results from other sequence shards.
+- backward: two kernels — dq over q tiles, dk/dv over k tiles — that
   recompute probabilities from the saved logsumexp instead of storing the
   score matrix (rematerialisation trades FLOPs for HBM, the TPU-native
   default).
 - both are exposed through one ``jax.custom_vjp`` so ``jax.grad`` composes
   with jit/shard_map/scan.
 
+Precision follows the inputs' dtype.  Every product takes its operands as
+they come (q, k, v, dO) or cast to that dtype (the probabilities ``p`` and
+``dS`` before P.V, P^T.dO, dS.K and dS^T.Q) and accumulates in float32; the
+softmax scale is applied to the float32 scores; the running max and sum, the
+logsumexp, ``delta``, the exponent and every accumulator are float32.  So
+float32 inputs agree with a dense jnp reference to ~1e-5, and bfloat16
+inputs to bfloat16's rounding of ``p`` (2**-8 of the largest value summed,
+and as much again for the output's own rounding): the precision a bfloat16
+model states, and what a dense bfloat16 attention does to its ``probs``.
+
+Tiles: ``block_q`` / ``block_k`` where the caller names them, else
+``plan_tiles`` from the lengths, widths and dtype under a stated VMEM
+budget.  The row statistics (logsumexp, delta) cross HBM lane-dense,
+``[batch, heads, 1, seq]``.
+
 On non-TPU backends (CPU tests, the 8-device virtual mesh) the kernels run
-in interpreter mode automatically; numerics match a dense jnp reference to
-~1e-5 (f32).
+in interpreter mode automatically.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float("-inf")
 _MASK_VALUE = -1e30  # large-negative instead of -inf inside kernels (no NaNs)
+
+#: what a kernel may hold in VMEM (``vmem_limit_bytes``; the compiler's own
+#: default is 16 MiB of a v5e core's 128 MiB).  ``plan_tiles`` keeps its
+#: estimate of a kernel's blocks under half of it: the other half is the
+#: compiler's, for the temporaries of a tile's elementwise work.
+VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES // 2
+#: tiles in order of preference, read on the chip at the benchmark's shapes
+#: (PERF.md section 6, PR 32): q tiles (rows streamed through the MXU per
+#: k tile loaded), then k tiles (the width of a score tile)
+_Q_TILES = (512, 256, 128)
+_K_TILES = (512, 256, 128)
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _block_sizes(seq_q: int, seq_k: int, block_q: int, block_k: int):
-    bq = min(block_q, seq_q)
-    bk = min(block_k, seq_k)
+def _padded(rows: int, cols: int, itemsize: int) -> int:
+    """Bytes of a [rows, cols] block in VMEM: (8, 128) tiles of 32 bits,
+    (16, 128) of 16."""
+    sublanes = 8 * 4 // itemsize
+    return -(-rows // sublanes) * sublanes * -(-cols // 128) * 128 * itemsize
+
+
+def vmem_bytes(seq_q: int, seq_k: int, d_k: int, d_v: int, dtype, bq: int, bk: int) -> int:
+    """Estimate of the largest of the three kernels' VMEM blocks at tiles
+    ``(bq, bk)``: pipelined blocks twice (double-buffered), the float32
+    accumulators, and four float32 tiles of scores (s, p, dp, ds)."""
+    size = jnp.dtype(dtype).itemsize
+
+    def row(n):  # a lane-dense row of statistics
+        return _padded(1, n, 4)
+
+    scores = 4 * _padded(bq, bk, 4)
+    forward = 2 * (
+        _padded(bq, d_k, size) + _padded(seq_k, d_k, size) + _padded(seq_k, d_v, size)
+        + _padded(bq, d_v, size) + row(bq)
+    ) + _padded(d_v, bq, 4)
+    dq = 2 * (
+        2 * _padded(bq, d_k, size) + _padded(seq_k, d_k, size) + _padded(seq_k, d_v, size)
+        + _padded(bq, d_v, size) + 2 * row(bq)
+    ) + _padded(d_k, bq, 4)
+    dkv = 2 * (
+        _padded(seq_q, d_k, size) + _padded(seq_q, d_v, size) + 2 * row(seq_q)
+        + 2 * _padded(bk, d_k, size) + 2 * _padded(bk, d_v, size)
+    ) + _padded(bk, d_k, 4) + _padded(bk, d_v, 4)
+    return scores + max(forward, dq, dkv)
+
+
+def plan_tiles(seq_q: int, seq_k: int, d_k: int, d_v: int, dtype) -> tuple[int, int]:
+    """The (q tile, k tile) the kernels run where the caller names none, from
+    what they can observe: the lengths, the widths and the operand dtype.
+    The first pair of ``_Q_TILES`` x ``_K_TILES`` that divides the lengths and
+    whose ``vmem_bytes`` is within ``VMEM_BUDGET_BYTES``; a length that none
+    divides (or shorter than 128) is one tile."""
+
+    def candidates(seq, tiles):
+        return [t for t in tiles if seq % t == 0] or [seq]
+
+    pairs = [(bq, bk) for bq in candidates(seq_q, _Q_TILES) for bk in candidates(seq_k, _K_TILES)]
+    for bq, bk in pairs:
+        if vmem_bytes(seq_q, seq_k, d_k, d_v, dtype, bq, bk) <= VMEM_BUDGET_BYTES:
+            return bq, bk
+    return pairs[-1]
+
+
+def _block_sizes(q, k, v, block_q: int | None, block_k: int | None):
+    """Explicit tiles as given (clamped to the lengths), the plan's where
+    ``None``; a tile that does not divide its length raises."""
+    seq_q, seq_k = q.shape[2], k.shape[2]
+    if block_q is None or block_k is None:
+        planned = plan_tiles(seq_q, seq_k, q.shape[3], v.shape[3], q.dtype)
+        block_q = planned[0] if block_q is None else block_q
+        block_k = planned[1] if block_k is None else block_k
+    bq, bk = min(block_q, seq_q), min(block_k, seq_k)
     if seq_q % bq or seq_k % bk:
         raise ValueError(
             f"block sizes ({bq}, {bk}) must divide sequence lengths ({seq_q}, {seq_k})"
         )
     return bq, bk
+
+
+def _pallas_call(kernel, **kwargs):
+    return pl.pallas_call(
+        kernel,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        **kwargs,
+    )
+
+
+# ---------------------------------------------------------------------------
+# which tiles the causal mask leaves
+# ---------------------------------------------------------------------------
+#
+# ``shift = seq_k - seq_q`` makes the causal mask bottom-right aligned (last
+# query row sees every key), matching ``reference_attention_with_lse`` for
+# seq_q != seq_k: key ``c`` is visible to query ``r`` when ``c <= r + shift``.
+# Tiles wholly above the diagonal are skipped by the loop bounds; every other
+# tile of a causal call is masked (a second, mask-free loop body for the tiles
+# wholly under the diagonal read slower on the chip at both benchmark shapes:
+# PERF.md section 6, PR 32).
+
+
+def _live_k_tiles(qi, bq, bk, n_kb, shift, causal):
+    """k tiles ``[0, n)`` hold a key that q tile ``qi``'s last row sees."""
+    if not causal:
+        return n_kb
+    return jnp.minimum(pl.cdiv(jnp.maximum((qi + 1) * bq + shift, 0), bk), n_kb)
+
+
+def _first_live_q_tile(ki, bq, bk, n_qb, shift, causal):
+    """q tiles ``[first, n_qb)`` hold a row that sees k tile ``ki``'s first key."""
+    if not causal:
+        return 0
+    return jnp.minimum(jnp.maximum(ki * bk - shift, 0) // bq, n_qb)
+
+
+def _visible(shape, k0, q0):
+    """The causal mask of a [keys, queries] tile: keys from ``k0``, queries
+    from ``q0`` (the shift included)."""
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return k_pos <= q_pos
 
 
 # ---------------------------------------------------------------------------
@@ -58,72 +188,58 @@ def _block_sizes(seq_q: int, seq_k: int, block_q: int, block_k: int):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_k, shift):
-    """``shift = seq_k - seq_q`` makes the causal mask bottom-right aligned
-    (last query row sees every key), matching ``reference_attention_with_lse``
-    for seq_q != seq_k; both collapse to the usual mask when shift == 0."""
+    """One q tile against the k tiles it sees.  Scores are held transposed,
+    [block_k, bq]: the running max and sum are then reductions along sublanes
+    (elementwise across registers) and live in lane-dense [1, bq] rows, where
+    reductions along lanes cost more than the products at these widths."""
     bq, d_v = q_ref.shape[-2], v_ref.shape[-1]
-    seq_k = k_ref.shape[-2]
-    n_kb = seq_k // block_k
+    n_kb = k_ref.shape[-2] // block_k
     qi = pl.program_id(2)
-    q = q_ref[0, 0, :, :].astype(jnp.float32) * sm_scale
-
-    if causal:
-        # only k-blocks starting at or before the last query row's diagonal
-        last_col = jnp.maximum((qi + 1) * bq + shift, 0)
-        n_kb_live = jnp.clip(pl.cdiv(last_col, block_k), 0, n_kb)
-    else:
-        n_kb_live = n_kb
+    q = q_ref[0, 0, :, :]
 
     def body(j, carry):
-        o_acc, m_acc, l_acc = carry
-        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, block_k]
+        o_acc, m_acc, l_acc = carry  # [d_v, bq], [1, bq], [1, bq]
+        start = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[0, 0, pl.ds(start, block_k), :]
+        v = v_ref[0, 0, pl.ds(start, block_k), :]
+        # operands in the inputs' dtype, scores and everything after in f32
+        s = sm_scale * jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
         if causal:
-            rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            mask = cols <= rows + shift
-            s = jnp.where(mask, s, _MASK_VALUE)
-        m_new = jnp.maximum(m_acc, jnp.max(s, axis=1))
-        # mask the exponent, not just the score: a fully-masked row has
-        # s == m_new == _MASK_VALUE, where exp(s - m_new) would be exp(0)=1
-        e = s - m_new[:, None]
-        if causal:
-            e = jnp.where(mask, e, _MASK_VALUE)
-        p = jnp.exp(e)
+            s = jnp.where(_visible(s.shape, start, qi * bq + shift), s, _MASK_VALUE)
+        m_new = jnp.maximum(m_acc, jnp.max(s, axis=0, keepdims=True))
+        # a row that has seen no key yet has m_new == _MASK_VALUE and p == 1
+        # on masked keys: wiped by alpha == 0 at its first visible key, or at
+        # the end if there is none
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_acc - m_new)
-        l_new = l_acc * alpha + jnp.sum(p, axis=1)
-        o_new = o_acc * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
-        )
-        return o_new, m_new, l_new
+        l_new = l_acc * alpha + jnp.sum(p, axis=0, keepdims=True)
+        pv = jax.lax.dot_general(v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
+        return o_acc * alpha + pv, m_new, l_new
 
-    o0 = jnp.zeros((bq, d_v), jnp.float32)
-    m0 = jnp.full((bq,), _MASK_VALUE, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    o, m, l = jax.lax.fori_loop(0, n_kb_live, body, (o0, m0, l0))
+    o0 = jnp.zeros((d_v, bq), jnp.float32)
+    m0 = jnp.full((1, bq), _MASK_VALUE, jnp.float32)
+    l0 = jnp.zeros((1, bq), jnp.float32)
+    n_live = _live_k_tiles(qi, bq, block_k, n_kb, shift, causal)
+    o, m, l = jax.lax.fori_loop(0, n_live, body, (o0, m0, l0))
 
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0, :, :] = (o / l_safe[:, None]).astype(o_ref.dtype)
-    lse = jnp.where(l == 0.0, _MASK_VALUE, m + jnp.log(l_safe))
-    # trailing singleton keeps the block 4-D: TPU tiling requires the last
-    # two block dims divide (8, 128) or equal the array dims
-    lse_ref[0, 0, :, 0] = lse
+    # rows with every key masked: output 0, log-sum-exp the mask value
+    dead = m == _MASK_VALUE
+    l_safe = jnp.where(dead, 1.0, l)
+    o_ref[0, 0, :, :] = jnp.where(dead, 0.0, o / l_safe).T.astype(o_ref.dtype)
+    # row statistics leave the kernel lane-dense, [1, bq]: a [bq, 1] block is
+    # padded to 128 lanes in VMEM and in HBM
+    lse_ref[0, 0, :, :] = jnp.where(dead, _MASK_VALUE, m + jnp.log(l_safe))
 
 
 def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
-    bq, bk = _block_sizes(sq, sk, block_q, block_k)
-    grid = (b, h, sq // bq)
-    o, lse = pl.pallas_call(
+    bq, bk = _block_sizes(q, k, v, block_q, block_k)
+    o, lse = _pallas_call(
         functools.partial(
-            _fwd_kernel, sm_scale=sm_scale, causal=causal, block_k=bk,
-            shift=sk - sq,
+            _fwd_kernel, sm_scale=sm_scale, causal=causal, block_k=bk, shift=sk - sq
         ),
-        grid=grid,
+        grid=(b, h, sq // bq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda i, j, l: (i, j, l, 0)),
             pl.BlockSpec((1, 1, sk, d), lambda i, j, l: (i, j, 0, 0)),
@@ -131,15 +247,15 @@ def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d_v), lambda i, j, l: (i, j, l, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda i, j, l: (i, j, l, 0)),
+            pl.BlockSpec((1, 1, 1, bq), lambda i, j, l: (i, j, 0, l)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d_v), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-    return o, lse[..., 0]
+    return o, lse[:, :, 0, :]
 
 
 # ---------------------------------------------------------------------------
@@ -148,102 +264,81 @@ def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret):
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dq_ref, *, sm_scale, causal, block_k, shift):
-    """dq for one q-block; streams K/V blocks.  ``dmd`` = rowsum(dO*O) - d_lse,
-    folding the logsumexp cotangent into the usual flash "delta" term."""
+    """dq for one q tile; streams K/V tiles.  ``dmd`` = rowsum(dO*O) - d_lse,
+    folding the logsumexp cotangent into the usual flash "delta" term.
+    Transposed like the forward, [block_k, bq]: the row statistics broadcast
+    along sublanes from their lane-dense rows."""
     bq, d = q_ref.shape[-2], q_ref.shape[-1]
-    seq_k = k_ref.shape[-2]
-    n_kb = seq_k // block_k
+    n_kb = k_ref.shape[-2] // block_k
     qi = pl.program_id(2)
-    q = q_ref[0, 0, :, :].astype(jnp.float32)
-    do = do_ref[0, 0, :, :].astype(jnp.float32)
-    lse = lse_ref[0, 0, :, 0]
-    dmd = dmd_ref[0, 0, :, 0]
-
-    n_kb_live = (
-        jnp.clip(pl.cdiv(jnp.maximum((qi + 1) * bq + shift, 0), block_k), 0, n_kb)
-        if causal
-        else n_kb
-    )
+    q = q_ref[0, 0, :, :]
+    do = do_ref[0, 0, :, :]
+    lse = lse_ref[0, 0, :, :]  # [1, bq]
+    dmd = dmd_ref[0, 0, :, :]
 
     def body(j, dq_acc):
-        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = sm_scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        e = s - lse[:, None]
+        start = pl.multiple_of(j * block_k, block_k)
+        k = k_ref[0, 0, pl.ds(start, block_k), :]
+        v = v_ref[0, 0, pl.ds(start, block_k), :]
+        s = sm_scale * jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+        e = s - lse
         if causal:
-            rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            e = jnp.where(cols <= rows + shift, e, _MASK_VALUE)
-        p = jnp.exp(e)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - dmd[:, None])
-        return dq_acc + sm_scale * jnp.dot(ds, k, preferred_element_type=jnp.float32)
+            e = jnp.where(_visible(e.shape, start, qi * bq + shift), e, _MASK_VALUE)
+        p = jnp.exp(e)  # [block_k, bq]
+        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+        ds = (p * (dp - dmd)).astype(k.dtype)
+        return dq_acc + jax.lax.dot_general(k, ds, _TN, preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(0, n_kb_live, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0, 0, :, :] = dq.astype(dq_ref.dtype)
+    n_live = _live_k_tiles(qi, bq, block_k, n_kb, shift, causal)
+    dq = jax.lax.fori_loop(0, n_live, body, jnp.zeros((d, bq), jnp.float32))
+    dq_ref[0, 0, :, :] = (sm_scale * dq).T.astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dk_ref, dv_ref, *, sm_scale, causal, block_q, shift):
-    """dk, dv for one k-block; streams q-blocks (with their dO/lse/delta rows)."""
+    """dk, dv for one k tile; streams q tiles (with their dO/lse/delta).
+    Transposed too, [bk, block_q], which makes every product here a plain
+    ``a @ b`` or ``a @ b.T``."""
     bk, d = k_ref.shape[-2], k_ref.shape[-1]
-    seq_q = q_ref.shape[-2]
-    n_qb = seq_q // block_q
+    n_qb = q_ref.shape[-2] // block_q
     ki = pl.program_id(2)
-    k = k_ref[0, 0, :, :].astype(jnp.float32)
-    v = v_ref[0, 0, :, :].astype(jnp.float32)
-
-    # with causal masking, q-blocks strictly above this k-block's diagonal
-    # (bottom-right aligned: row + shift >= col) contribute 0
-    first_qb = jnp.maximum(0, ki * bk - shift) // block_q if causal else 0
+    k = k_ref[0, 0, :, :]
+    v = v_ref[0, 0, :, :]
 
     def body(i, carry):
         dk_acc, dv_acc = carry
-        q = q_ref[0, 0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, 0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q), 0]
-        dmd = dmd_ref[0, 0, pl.ds(i * block_q, block_q), 0]
-        s = sm_scale * jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, bk]
-        e = s - lse[:, None]
+        start = pl.multiple_of(i * block_q, block_q)
+        q = q_ref[0, 0, pl.ds(start, block_q), :]
+        do = do_ref[0, 0, pl.ds(start, block_q), :]
+        lse = lse_ref[0, 0, :, pl.ds(start, block_q)]  # [1, block_q]
+        dmd = dmd_ref[0, 0, :, pl.ds(start, block_q)]
+        s = sm_scale * jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+        e = s - lse
         if causal:
-            rows = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
-            cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
-            e = jnp.where(cols <= rows + shift, e, _MASK_VALUE)
-        p = jnp.exp(e)
-        dv_new = dv_acc + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - dmd[:, None])
-        dk_new = dk_acc + sm_scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            e = jnp.where(_visible(e.shape, ki * bk, start + shift), e, _MASK_VALUE)
+        p = jnp.exp(e)  # [bk, block_q]
+        dv_new = dv_acc + jnp.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+        ds = (p * (dp - dmd)).astype(q.dtype)
+        dk_new = dk_acc + jnp.dot(ds, q, preferred_element_type=jnp.float32)
         return dk_new, dv_new
 
-    zk = jnp.zeros((bk, d), jnp.float32)
-    zv = zk if v_ref.shape[-1] == d else jnp.zeros((bk, v_ref.shape[-1]), jnp.float32)
-    dk, dv = jax.lax.fori_loop(first_qb, n_qb, body, (zk, zv))
-    dk_ref[0, 0, :, :] = dk.astype(dk_ref.dtype)
+    zeros = (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, v_ref.shape[-1]), jnp.float32))
+    first = _first_live_q_tile(ki, block_q, bk, n_qb, shift, causal)
+    dk, dv = jax.lax.fori_loop(first, n_qb, body, zeros)
+    dk_ref[0, 0, :, :] = (sm_scale * dk).astype(dk_ref.dtype)
     dv_ref[0, 0, :, :] = dv.astype(dv_ref.dtype)
 
 
 def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, interpret):
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
-    bq, bk = _block_sizes(sq, sk, block_q, block_k)
+    bq, bk = _block_sizes(q, k, v, block_q, block_k)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     dmd = delta - dlse.astype(jnp.float32)  # [b, h, sq]
-    lse4 = lse[..., None]
-    dmd4 = dmd[..., None]
+    lse4 = lse[:, :, None, :]
+    dmd4 = dmd[:, :, None, :]
 
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         functools.partial(
             _dq_kernel, sm_scale=sm_scale, causal=causal, block_k=bk, shift=sk - sq
         ),
@@ -253,15 +348,15 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
             pl.BlockSpec((1, 1, sk, d), lambda i, j, l: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, sk, d_v), lambda i, j, l: (i, j, 0, 0)),
             pl.BlockSpec((1, 1, bq, d_v), lambda i, j, l: (i, j, l, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda i, j, l: (i, j, l, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda i, j, l: (i, j, l, 0)),
+            pl.BlockSpec((1, 1, 1, bq), lambda i, j, l: (i, j, 0, l)),
+            pl.BlockSpec((1, 1, 1, bq), lambda i, j, l: (i, j, 0, l)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda i, j, l: (i, j, l, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(q, k, v, do, lse4, dmd4)
 
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas_call(
         functools.partial(
             _dkv_kernel, sm_scale=sm_scale, causal=causal, block_q=bq, shift=sk - sq
         ),
@@ -271,8 +366,8 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
             pl.BlockSpec((1, 1, bk, d), lambda i, j, l: (i, j, l, 0)),
             pl.BlockSpec((1, 1, bk, d_v), lambda i, j, l: (i, j, l, 0)),
             pl.BlockSpec((1, 1, sq, d_v), lambda i, j, l: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, sq, 1), lambda i, j, l: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, sq, 1), lambda i, j, l: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, sq), lambda i, j, l: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, sq), lambda i, j, l: (i, j, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda i, j, l: (i, j, l, 0)),
@@ -299,14 +394,15 @@ def flash_attention_with_lse(
     v: jax.Array,
     causal: bool = True,
     sm_scale: float | None = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused attention over [batch, heads, seq, head_dim] inputs.  The values
     (and so the output) may have another width than the queries and keys, as
     in latent attention (192-wide keys, 128-wide values); the scale comes from
-    the query width unless given.
+    the query width unless given.  ``block_q`` / ``block_k``: the tiles, from
+    ``plan_tiles`` where ``None``.
 
     Returns ``(output, logsumexp)``; the logsumexp output makes this the
     mergeable building block for ring attention.  Rows with every key masked
@@ -346,8 +442,8 @@ def flash_attention(
     *,
     causal: bool = True,
     sm_scale: float | None = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Standard entry point: fused attention output only."""

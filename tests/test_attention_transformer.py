@@ -6,6 +6,8 @@ The Pallas kernels run in interpreter mode on the 8-device CPU platform
 how the reference repo checks algorithm services against hand-built
 requests (SURVEY.md §4 grpc_testing harness)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +19,7 @@ slow = pytest.mark.slow
 from katib_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_with_lse,
+    plan_tiles,
     reference_attention,
     reference_attention_with_lse,
 )
@@ -107,6 +110,151 @@ class TestFlashAttention:
         gd = jax.grad(g, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(gf, gd):
             np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+# (seq_q, seq_k, d_k, d_v, causal, block_q, block_k).  The causal square has
+# eight k tiles to a q tile: q tile 1 (rows 64-127) runs k tiles 0-3 (0 and 1
+# wholly visible, 2 and 3 crossed by the diagonal) and skips 4-7.
+KERNEL_CASES = {
+    "causal-square": (256, 256, 16, 16, True, 64, 32),
+    "q-shorter": (128, 256, 16, 16, True, 64, 32),
+    "q-longer": (256, 128, 16, 16, True, 32, 64),
+    "narrow-values": (128, 128, 48, 32, True, 32, 32),
+    "full": (128, 128, 16, 16, False, 64, 32),
+}
+
+
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
+def _kernel_inputs(sq, sk, d_k, d_v, dtype, seed=5):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(ks[0], (1, 2, sq, d_k), dtype)
+    k = jax.random.normal(ks[1], (1, 2, sk, d_k), dtype)
+    v = jax.random.normal(ks[2], (1, 2, sk, d_v), dtype)
+    w_o = jax.random.normal(ks[3], (1, 2, sq, d_v), jnp.float32)
+    w_lse = jax.random.normal(ks[4], (1, 2, sq), jnp.float32)
+    return q, k, v, w_o, w_lse
+
+
+def _outputs_and_grads(attn, q, k, v, w_o, w_lse):
+    """Output, log-sum-exp, and dq, dk, dv of a loss that weighs both (the
+    ``lse`` cotangent flows); rows that see no key leave the loss."""
+
+    def loss(q, k, v):
+        o, lse = attn(q, k, v)
+        seen = lse > -1e20
+        return jnp.sum(_f32(o) * w_o) + jnp.sum(jnp.where(seen, lse, 0.0) * w_lse), (o, lse)
+
+    (_, (o, lse)), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return (o, lse) + grads
+
+
+def _kernel_tolerances(dtype, want):
+    """float32 operands: 1e-5, as the kernel always agreed with the dense
+    reference.  bfloat16 operands: ``p`` and ``dS`` are rounded to 8 bits
+    before the second product (each moves by at most 2**-8 of itself) and the
+    result is rounded once more on the way out (2**-8 of itself): a sum of such
+    terms stays within 2**-7 of the largest value, and 2**-6 leaves a margin of
+    two.  The log-sum-exp never sees a rounded ``p``: its products of bfloat16
+    operands are exact in float32."""
+    if dtype == jnp.float32:
+        return [1e-5] * 5
+    top = [float(jnp.max(jnp.abs(_f32(w)))) for w in want]
+    return [2.0**-6 * top[0], 1e-5 * max(top[1], 1.0)] + [2.0**-6 * t for t in top[2:]]
+
+
+class TestKernelNumerics:
+    """Tier-1: the three kernels in interpret mode against the dense reference
+    computed in float32 from the same inputs."""
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+    def test_matches_dense_in_float32(self, case, dtype):
+        sq, sk, d_k, d_v, causal, bq, bk = KERNEL_CASES[case]
+        q, k, v, w_o, w_lse = _kernel_inputs(sq, sk, d_k, d_v, dtype)
+        got = _outputs_and_grads(
+            lambda q, k, v: flash_attention_with_lse(q, k, v, causal, None, bq, bk, True),
+            q, k, v, w_o, w_lse,
+        )
+        want = _outputs_and_grads(
+            lambda q, k, v: reference_attention_with_lse(q, k, v, causal),
+            _f32(q), _f32(k), _f32(v), w_o, w_lse,
+        )
+        assert got[0].dtype == got[2].dtype == dtype and got[1].dtype == jnp.float32
+        assert got[0].shape == (1, 2, sq, d_v) and got[4].shape == v.shape
+        seen = np.asarray(want[1]) > -1e20
+        if sq > sk:  # rows before the diagonal: output 0, log-sum-exp the mask value
+            assert not seen[..., : sq - sk].any() and seen[..., sq - sk :].all()
+            np.testing.assert_array_equal(_f32(got[0])[..., : sq - sk, :], 0.0)
+            np.testing.assert_array_equal(got[1][..., : sq - sk], want[1][..., : sq - sk])
+        for name, g, w, tol in zip(("o", "lse", "dq", "dk", "dv"), got, want, _kernel_tolerances(dtype, want)):
+            np.testing.assert_allclose(_f32(g), w, rtol=0, atol=tol, err_msg=name)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    def test_planned_tiles_equal_explicit_128(self, dtype):
+        q, k, v, w_o, w_lse = _kernel_inputs(256, 256, 32, 32, dtype)
+        assert plan_tiles(256, 256, 32, 32, dtype) == (256, 256)
+        planned, explicit = (
+            _outputs_and_grads(
+                lambda q, k, v: flash_attention_with_lse(q, k, v, True, None, bq, bk, True),
+                q, k, v, w_o, w_lse,
+            )
+            for bq, bk in ((None, None), (128, 128))
+        )
+        # other tiles sum the same terms in another order (and round p
+        # against another running maximum): equal to the rounding above
+        for g, w, tol in zip(planned, explicit, _kernel_tolerances(dtype, explicit)):
+            np.testing.assert_allclose(_f32(g), _f32(w), rtol=0, atol=tol)
+
+
+# the shapes that run the kernel: the benchmark's two cells, gpt2-medium
+# (a configuration file, no cell), and the ring path's chunks
+# (scripts/run_longcontext_tpu.py: 4096 positions over 4 chips, float32 in the
+# slow tests); (seq_q, seq_k, d_k, d_v, dtype)
+PLANNED_SHAPES = {
+    "gpt2-small": (1024, 1024, 64, 64, jnp.bfloat16),
+    "kanana-2-30b-a3b-ep8": (4096, 4096, 192, 128, jnp.bfloat16),
+    "gpt2-medium": (1024, 1024, 64, 64, jnp.bfloat16),
+    "ring-chunk-bf16": (1024, 1024, 64, 64, jnp.bfloat16),
+    "ring-chunk-f32": (1024, 1024, 64, 64, jnp.float32),
+    "long-context": (16384, 16384, 128, 128, jnp.bfloat16),
+    "short": (64, 64, 16, 16, jnp.float32),
+    "odd-length": (1000, 1000, 64, 64, jnp.bfloat16),
+}
+
+
+class TestTilePlan:
+    @pytest.mark.parametrize("name", sorted(PLANNED_SHAPES))
+    def test_tiles_divide_align_and_fit(self, name):
+        from katib_tpu.ops import flash_attention as fa
+
+        sq, sk, d_k, d_v, dtype = PLANNED_SHAPES[name]
+        bq, bk = plan_tiles(sq, sk, d_k, d_v, dtype)
+        assert sq % bq == 0 and sk % bk == 0
+        # a block's last two dimensions divide the dtype's tile or are whole:
+        # rows by 8 (float32) or 16 (bfloat16), the statistics' lanes by 128
+        sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
+        assert bq == sq or (bq % 128 == 0 and bq % sublanes == 0)
+        assert bk == sk or bk % sublanes == 0
+        assert fa.vmem_bytes(sq, sk, d_k, d_v, dtype, bq, bk) <= fa.VMEM_BUDGET_BYTES < fa.VMEM_LIMIT_BYTES
+        if min(sq, sk) >= 1024 and sq % 128 == 0:
+            assert bq >= 256 and bk >= 128  # more rows streamed per operand loaded than the old 128
+
+    def test_explicit_tiles_pass_through(self):
+        q, k, v, _, _ = _kernel_inputs(256, 256, 16, 16, jnp.float32)
+        jaxpr = str(jax.make_jaxpr(lambda q, k, v: flash_attention(q, k, v, block_q=64, block_k=32, interpret=True))(q, k, v))
+        assert "grid=(1, 2, 4)" in jaxpr  # 256 rows in q tiles of 64
+        # and a tile longer than the sequence is the sequence
+        o = flash_attention(q[:, :, :32], k[:, :, :32], v[:, :, :32], block_q=128, block_k=128, interpret=True)
+        np.testing.assert_allclose(o, reference_attention(q[:, :, :32], k[:, :, :32], v[:, :, :32]), atol=1e-5)
+
+    @pytest.mark.parametrize("blocks", [(48, 32), (32, 48), (None, 96)], ids=str)
+    def test_tile_that_does_not_divide_raises(self, blocks):
+        q, k, v, _, _ = _kernel_inputs(128, 128, 16, 16, jnp.float32)
+        with pytest.raises(ValueError, match="must divide sequence lengths"):
+            flash_attention(q, k, v, block_q=blocks[0], block_k=blocks[1], interpret=True)
 
 
 @slow
@@ -273,6 +421,27 @@ class TestTrialPrograms:
         assert spans["train_fn"]["jit_programs"] == 0
         assert spans["train_fn"]["jit_trace_s"] == spans["train_fn"]["jit_lower_s"] == 0
         assert len(losses) >= 2 and np.all(np.isfinite(losses))
+
+    def test_trial_init_names_what_attention_runs(self, tmp_path):
+        from katib_tpu.models import transformer
+
+        _, spans = _traced_train_lm(tmp_path, "a", _tiny_lm(dropout=0.05), lr=1e-3, steps=2)
+        assert spans["trial.init"]["attn_tiles"] == "dense"  # no kernel on the CPU
+        # on the chip make_attention_fn gives the kernel: the dtype and the
+        # tiles it plans for the benchmark's shapes
+        small = transformer.TransformerLM(
+            vocab_size=50257, d_model=768, n_heads=12, attn_fn=transformer._flash_causal_attention
+        )
+        assert transformer.attn_tiles(small, 1024) == "bfloat16 q%d k%d" % plan_tiles(1024, 1024, 64, 64, jnp.bfloat16)
+        from katib_tpu.models.mla_moe import MlaMoeLM, MlaMoeSizes
+
+        latent = MlaMoeLM(
+            vocab_size=64,
+            sizes=MlaMoeSizes(n_heads=32, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128),
+            attn_fn=transformer._flash_causal_attention,
+        )
+        assert transformer.attn_tiles(latent, 4096) == "bfloat16 q%d k%d" % plan_tiles(4096, 4096, 192, 128, jnp.bfloat16)
+        assert transformer.attn_tiles(dataclasses.replace(small, attn_fn=lambda q, k, v: q), 1024) == "seq-parallel"
 
     def test_new_depth_builds_and_trains_its_own(self, tmp_path):
         from katib_tpu.models import transformer

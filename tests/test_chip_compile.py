@@ -61,11 +61,10 @@ def test_mixed_op_kernel_compiles(one_chip, shape, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
-def test_flash_attention_compiles_at_latent_widths(one_chip, grad):
-    """Keys 192 wide, values 128, 4096 positions: a whole K and V of one head
-    and, in the dkv kernel, a whole Q, dO and their row statistics sit in
-    VMEM (the benchmark's kanana-2-30b-a3b-ep8 shapes)."""
+def _attention_kernels(one_chip, q_shape, v_shape, grad):
+    """The ``tpu_custom_call`` lines of the attention program (forward, or
+    forward + dq + dkv under ``jax.grad``) at the tiles the kernel plans,
+    compiled for the described chip."""
     from katib_tpu.ops.flash_attention import flash_attention
 
     def fwd(q, k, v):
@@ -75,10 +74,34 @@ def test_flash_attention_compiles_at_latent_widths(one_chip, grad):
         return jnp.sum(fwd(q, k, v).astype(jnp.float32))
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    qk = jax.ShapeDtypeStruct((2, 32, 4096, 192), jnp.bfloat16, sharding=one_chip)
-    v = jax.ShapeDtypeStruct((2, 32, 4096, 128), jnp.bfloat16, sharding=one_chip)
-    compiled = jax.jit(fn).lower(qk, qk, v).compile()
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == (2 if grad else 1) + grad
+    qk = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct(v_shape, jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(fn).lower(qk, qk, v).compile().as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    # the benchmark's marks find a kernel by its first operand: q, bfloat16
+    # (families/mla_moe.py FLASH_KERNEL_MARK); int32 first is how it finds the
+    # grouped products (EXPERT_PRODUCT_MARK)
+    for line in kernels:
+        assert "operand_layout_constraints={bf16[" in line and "{s32[" not in line, line
+    return kernels
+
+
+# the shapes that run the kernel on the chip: the benchmark's two cells
+# ([batch, heads, positions, width] of q and k, then of v) and a longer context
+ATTENTION_SHAPES = {
+    "gpt2-small": ((8, 12, 1024, 64), (8, 12, 1024, 64)),
+    "kanana-2-30b-a3b-ep8": ((2, 32, 4096, 192), (2, 32, 4096, 128)),
+    "long-context": ((4, 8, 4096, 64), (4, 8, 4096, 64)),
+}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
+@pytest.mark.parametrize("name", sorted(ATTENTION_SHAPES))
+def test_flash_attention_compiles(one_chip, name, grad):
+    """At 4096 positions a whole K and V of one head and, in the dkv kernel,
+    a whole Q, dO and their row statistics sit in VMEM beside the tiles."""
+    kernels = _attention_kernels(one_chip, *ATTENTION_SHAPES[name], grad)
+    assert len(kernels) == (3 if grad else 1)  # forward, dq, dkv
 
 
 def test_grouped_expert_product_is_a_kernel(one_chip):
@@ -97,24 +120,6 @@ def test_grouped_expert_product_is_a_kernel(one_chip):
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert len(kernels) >= 2 and all("operand_layout_constraints={s32[" in k for k in kernels)
     assert "bf16[16,49152" not in text  # no [experts, rows, ...] dense intermediate
-
-
-@pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
-def test_flash_attention_compiles(one_chip, grad):
-    from katib_tpu.ops.flash_attention import flash_attention
-
-    def fwd(q, k, v):
-        return flash_attention(q, k, v, interpret=False)
-
-    def loss(q, k, v):
-        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
-
-    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    qkv = [
-        jax.ShapeDtypeStruct((4, 8, 4096, 64), jnp.bfloat16, sharding=one_chip)
-    ] * 3
-    compiled = jax.jit(fn).lower(*qkv).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 def _mnist_cohort_step_avals(k, member_sharding, shared_sharding, mesh=None):

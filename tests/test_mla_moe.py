@@ -9,7 +9,6 @@ loaded by path: the repo's one copy), at tiny sizes on the CPU.
 from __future__ import annotations
 
 import dataclasses
-import hashlib
 import importlib.util
 import os
 
@@ -23,7 +22,6 @@ from katib_tpu.models import transformer
 from katib_tpu.models.mla_moe import ROUTING, ExpertLayer, MlaMoeLM, MlaMoeSizes, SwiGLU
 from katib_tpu.ops.flash_attention import (
     flash_attention,
-    flash_attention_with_lse,
     reference_attention,
 )
 from katib_tpu.utils import tracing
@@ -330,17 +328,6 @@ class TestExpertLayer:
 # the widened kernel
 # ---------------------------------------------------------------------------
 
-# sha256 (first 16 hex) of the jaxpr of the kernel's forward and backward at
-# equal widths, recorded at the commit before values could differ in width
-# (f96651d, jax 0.9.0): the programs built there are the ones built today
-EQUAL_WIDTH_JAXPRS = {
-    ("bfloat16", (1, 2, 256, 64)): "787640651b790ed5",
-    ("bfloat16", (2, 1, 128, 128)): "4663b8918db91770",
-    ("float32", (1, 2, 256, 64)): "b4487f68db2bf201",
-    ("float32", (2, 1, 128, 128)): "902faffbeac966c9",
-}
-
-
 class TestWidenedKernel:
     @staticmethod
     def _qkv(d_k=48, d_v=32, s=128, dtype=jnp.float32):
@@ -372,22 +359,6 @@ class TestWidenedKernel:
         out = flash_attention(q, k, v, block_q=64, block_k=64)
         scaled = flash_attention(q, k, v, sm_scale=1.0 / np.sqrt(48), block_q=64, block_k=64)
         np.testing.assert_array_equal(out, scaled)
-
-    @pytest.mark.parametrize("dtype,shape", sorted(EQUAL_WIDTH_JAXPRS), ids=str)
-    def test_equal_widths_build_the_programs_they_built(self, dtype, shape):
-        def program(q, k, v):
-            def loss(q, k, v):
-                o, lse = flash_attention_with_lse(q, k, v, True, None, 128, 128, True)
-                return jnp.sum(o.astype(jnp.float32)) + jnp.sum(lse)
-
-            return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
-
-        x = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
-        text = str(jax.make_jaxpr(program)(x, x, x))
-        assert hashlib.sha256(text.encode()).hexdigest()[:16] == EQUAL_WIDTH_JAXPRS[dtype, shape], (
-            "the kernel's jaxpr at equal widths changed (or jax's printing of it did: "
-            f"recorded under jax 0.9.0, this is {jax.__version__})"
-        )
 
 
 # ---------------------------------------------------------------------------
