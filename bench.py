@@ -282,15 +282,11 @@ def _amortize_child() -> None:
     effect ``katib-tpu prewarm`` and the in-run worker bank on."""
     import jax
 
-    from katib_tpu.compile import artifacts
-    from katib_tpu.compile.prewarm import PrewarmRequest
     from katib_tpu.compile.registry import REGISTRY
-    from katib_tpu.models.mnist import mnist_prewarm, mnist_trial
-    from katib_tpu.runner.cohort import cohort_fn_of
+    from katib_tpu.models.mnist import mnist_prewarm
     from katib_tpu.runner.trial_runner import init_compile_cache
 
     init_compile_cache(os.environ.get("KATIB_COMPILE_CACHE"))
-    artifacts.ARTIFACTS.configure(None)  # KATIB_ARTIFACT_DIR (if any) wins
     shared = {
         "units": 16,
         "num_layers": 1,
@@ -299,53 +295,17 @@ def _amortize_child() -> None:
         "batch_size": 64,
     }
     k = int(os.environ.get("BENCH_AMORTIZE_K", "4"))
-    req = PrewarmRequest(
-        train_fn=mnist_trial,
-        shared=shared,
-        k=k,
-        program_fn=cohort_fn_of(mnist_trial) if k > 1 else None,
-    )
-    if os.environ.get("BENCH_AMORTIZE_MODE") == "fetch":
-        # simulated new host: fresh local XLA cache, shared artifact tier
-        # pre-published by the cold child — first step = fetch +
-        # deserialize + one real dispatch of each loaded executable
-        t0 = time.perf_counter()
-        loaded = artifacts.ARTIFACTS.fetch_family(req.signature())
-        for la in loaded:
-            la(*la.dummy_args())
-        _device_barrier(jax)
-        first = time.perf_counter() - t0
-        print(
-            _RESULT_TAG
-            + json.dumps(
-                {
-                    "first_step_secs": round(first, 4),
-                    "fetched": len(loaded),
-                    "registry_signatures": len(REGISTRY.signatures()),
-                }
-            )
-        )
-        return
-    artifacts.clear_observed()
     t0 = time.perf_counter()
     mnist_prewarm(shared, k, None)
     # prewarm's dummy step is dispatched async; without the barrier this
     # timer measured trace+compile+enqueue, not the executed first step
     _device_barrier(jax)
     first = time.perf_counter() - t0
-    # publish the observed programs into the artifact tiers (untimed: the
-    # shared-fetch phase measures the consumer side)
-    published = (
-        artifacts.publish_observed(req.signature())
-        if artifacts.ARTIFACTS.shared_dir()
-        else 0
-    )
     print(
         _RESULT_TAG
         + json.dumps(
             {
                 "first_step_secs": round(first, 4),
-                "published": published,
                 "registry_signatures": len(REGISTRY.signatures()),
             }
         )
@@ -364,9 +324,6 @@ def _run_compile_amortization() -> dict | None:
     expected = {
         "small_shapes": _SMALL,
         "k": int(os.environ.get("BENCH_AMORTIZE_K", "4")),
-        # schema marker: memos measured before the shared-tier point
-        # existed re-measure instead of reporting a two-phase block
-        "tiers": "cold/warm/shared_fetch",
     }
     memo_path = os.path.join(
         _HERE, "artifacts", "flagship", "compile_amortization.json"
@@ -426,31 +383,12 @@ def _run_compile_amortization() -> dict | None:
     # fixed paths, emptied first: a cache directory that moves never hits
     root = os.path.join(_HERE, ".jax_cache", "bench-amortize")
     shutil.rmtree(root, ignore_errors=True)
-    cache, artdir, fresh = (
-        os.path.join(root, name) for name in ("cache", "artifacts", "newhost")
-    )
-    env["KATIB_COMPILE_CACHE"] = cache
+    env["KATIB_COMPILE_CACHE"] = os.path.join(root, "cache")
     for phase in ("cold", "warm"):
-        penv = dict(env)
-        penv.pop("BENCH_AMORTIZE_MODE", None)
-        if phase == "cold":
-            # the cold child publishes serialized executables into the
-            # shared tier; warm stays artifact-blind so it measures the
-            # pure persistent-XLA-cache deserialize path
-            penv["KATIB_ARTIFACT_DIR"] = artdir
-        else:
-            penv.pop("KATIB_ARTIFACT_DIR", None)
-        block = _phase(phase, penv)
+        block = _phase(phase, env)
         if block is None:
             return None
         runs.append(block)
-    # simulated new host: FRESH local cache, only the shared artifact
-    # tier pre-published — the zero-cold-start fleet point
-    fenv = dict(env)
-    fenv["KATIB_COMPILE_CACHE"] = fresh
-    fenv["KATIB_ARTIFACT_DIR"] = artdir
-    fenv["BENCH_AMORTIZE_MODE"] = "fetch"
-    fetch_block = _phase("shared_fetch", fenv)
     cold = float(runs[0]["first_step_secs"])
     warm = float(runs[1]["first_step_secs"])
     result = {
@@ -460,15 +398,6 @@ def _run_compile_amortization() -> dict | None:
         "speedup": round(cold / warm, 2) if warm > 0 else None,
         "platform": "cpu",
     }
-    if fetch_block is not None and fetch_block.get("fetched"):
-        fetch = float(fetch_block["first_step_secs"])
-        result["shared_fetch_first_step_secs"] = fetch
-        result["shared_fetch_artifacts"] = int(fetch_block["fetched"])
-        result["published_by_cold"] = int(runs[0].get("published", 0))
-        # < 1 means fetching another host's executable beats even this
-        # host's own persistent-cache deserialize; the acceptance bar is
-        # "within 2x of local-warm" (vs the cold compile's much larger gap)
-        result["fetch_vs_warm"] = round(fetch / warm, 2) if warm > 0 else None
     try:
         import jax as _jax
 

@@ -97,9 +97,6 @@ def _obs_totals() -> dict:
         "fallbacks": obs.cohort_fallbacks,
         "registry_warm": obs.compile_cache_hits,
         "registry_cold": obs.compile_cache_misses,
-        "artifact_hits": obs.artifact_hits,
-        "artifact_misses": obs.artifact_misses,
-        "artifact_publishes": obs.artifact_publishes,
     }
     return {
         name: float(sum(v for _labels, v in metric.samples()))
@@ -206,8 +203,7 @@ def sweep_doc(name: str, tiny: bool, width: int, trials: int) -> dict:
     """An experiment of the shape of examples/hp-tuning/cohort-prewarm.yaml:
     mnist_trial at the model's own width (units 64) on the dataset's full
     rows (60000/10000; synthetic MNIST-shaped data made from a fixed seed,
-    models/data.py), random search, vmapped cohorts, prewarm and the
-    artifact tier on."""
+    models/data.py), random search, vmapped cohorts, prewarm on."""
     n_train, n_test = (1024, 256) if tiny else (60000, 10000)
 
     def pinned(pname: str, value: int) -> dict:
@@ -237,7 +233,6 @@ def sweep_doc(name: str, tiny: bool, width: int, trials: int) -> dict:
             "cohortKey": "mnist-mlp64",
             "cohortBuckets": True,
             "prewarm": True,
-            "artifactDir": os.path.join(OUT, "artifacts"),
             "parameters": [
                 {
                     "name": "lr",
@@ -312,9 +307,6 @@ def run_sweep(phase, name, workdir, tiny, width, trials, counts, placement, mesh
         **counts.since(c0),
         registry_warm_first_steps=delta["registry_warm"],
         registry_cold_first_steps=delta["registry_cold"],
-        artifact_fetch_hits=delta["artifact_hits"],
-        artifact_fetch_misses=delta["artifact_misses"],
-        artifact_publishes=delta["artifact_publishes"],
     )
     n_cohorts = trials // width
     check(delta["cohorts"] == n_cohorts, f"{delta['cohorts']} cohorts ran, expected {n_cohorts}")
@@ -603,10 +595,14 @@ def main(argv=None) -> int:
         print(f"chip_smoke: --chips {args.chips} needs {args.chips} devices, JAX reports {len(devices)}", file=sys.stderr)
         return 2
 
+    from importlib import metadata
+
     import jaxlib
 
-    from katib_tpu.compile.artifacts import env_fingerprint
-
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = ""
     say(
         "start",
         platform=dev.platform,
@@ -614,7 +610,7 @@ def main(argv=None) -> int:
         count=len(devices),
         jax=jax.__version__,
         jaxlib=jaxlib.__version__,
-        libtpu=env_fingerprint()["libtpu"],
+        libtpu=libtpu,
         JAX_COMPILATION_CACHE_DIR=os.environ.get("JAX_COMPILATION_CACHE_DIR"),
         rehearsal=args.allow_cpu,
     )

@@ -189,7 +189,6 @@ def cmd_prewarm(args: argparse.Namespace) -> int:
     run: the fleet analog of the orchestrator's in-run prewarm worker.  Runs
     meshless (single-host default placement) — sharded-mesh executables warm
     in-run instead."""
-    from katib_tpu.compile.artifacts import ARTIFACTS
     from katib_tpu.compile.buckets import prewarm_widths
     from katib_tpu.compile.prewarm import (
         PrewarmRequest,
@@ -216,16 +215,6 @@ def cmd_prewarm(args: argparse.Namespace) -> int:
             "created — prewarming helps only this process",
             file=sys.stderr,
         )
-    artifact_dir = ARTIFACTS.configure(
-        getattr(args, "artifact_dir", None) or spec.artifact_dir
-    )
-    if args.fetch_only and not artifact_dir:
-        print(
-            "error: --fetch-only needs a shared artifact tier "
-            "(--artifact-dir / artifactDir / KATIB_ARTIFACT_DIR)",
-            file=sys.stderr,
-        )
-        return 2
     shared = _pinned_structural(spec)
     cohort_fn = cohort_fn_of(spec.train_fn)
     if args.widths:
@@ -236,14 +225,7 @@ def cmd_prewarm(args: argparse.Namespace) -> int:
         widths = prewarm_widths(spec.cohort_width, buckets=spec.cohort_buckets)
     else:
         widths = [1]
-    # --publish forces submission past the registry dedupe so a re-run can
-    # backfill artifacts for signatures that are already warm locally (the
-    # content address dedupes the actual writes)
-    worker = PrewarmWorker(
-        publish=args.publish,
-        fetch_only=args.fetch_only,
-        force=args.publish,
-    )
+    worker = PrewarmWorker()
     queued = 0
     for k in widths:
         req = PrewarmRequest(
@@ -270,9 +252,7 @@ def cmd_prewarm(args: argparse.Namespace) -> int:
     ]
     print(
         f"prewarm: {queued} queued, {worker.compiled} compiled, "
-        f"{worker.fetched} fetched, {worker.published} published, "
-        f"{worker.failed} failed (cache: {cache or '<in-process only>'}"
-        f"{', artifacts: ' + artifact_dir if artifact_dir else ''})"
+        f"{worker.failed} failed (cache: {cache or '<in-process only>'})"
     )
     if rows:
         print(_table(rows, ["program", "k", "source", "compile_s"]))
@@ -948,103 +928,13 @@ def _chaos_crash(args: argparse.Namespace) -> int:
 def cmd_fsck(args: argparse.Namespace) -> int:
     """Validate and repair an experiment directory (journal checksums,
     torn tails, snapshot integrity, suggester fence) — see
-    ``orchestrator/fsck.py`` — or an artifact-cache directory (envelope
-    checksums + content addresses, corrupt files quarantined) — see
-    ``compile/artifacts.py``.  Exit 0 when consistent after repairs."""
-    from katib_tpu.compile.artifacts import fsck_artifacts, is_artifact_dir
-
-    if is_artifact_dir(args.path):
-        report = fsck_artifacts(args.path, repair=not args.dry_run)
-        print(f"artifact dir {report.root}")
-        print(report.summary())
-        for name in report.corrupt:
-            print(f"  corrupt: {name}")
-        for name in report.misaddressed:
-            print(f"  misaddressed: {name}")
-        for name in report.stale:
-            print(f"  stale(other-env): {name}")
-        for name in report.quarantined:
-            print(f"  quarantined -> {name}{_QUARANTINE_NOTE}")
-        return 0 if report.consistent else 1
+    ``orchestrator/fsck.py``.  Exit 0 when consistent after repairs."""
     from katib_tpu.orchestrator.fsck import fsck_experiment
 
     report = fsck_experiment(args.path, repair=not args.dry_run)
     for line in report.lines():
         print(line)
     return 0 if report.ok() else 1
-
-
-_QUARANTINE_NOTE = ".quarantined (inspect or delete; never auto-loaded)"
-
-
-def cmd_cache(args: argparse.Namespace) -> int:
-    """Inspect an artifact-cache tier: one row per serialized executable
-    with its program, width, publishing environment, and whether this
-    host's environment fingerprint can load it (``ok`` vs ``stale``)."""
-    import json as _json
-
-    from katib_tpu.compile.artifacts import (
-        ARTIFACTS,
-        env_fingerprint,
-        scan_dir,
-    )
-
-    path = args.path or ARTIFACTS.shared_dir()
-    if not path:
-        print(
-            "error: no artifact dir (pass a path or set KATIB_ARTIFACT_DIR)",
-            file=sys.stderr,
-        )
-        return 2
-    # a compile-cache dir holds its local tier under artifacts/
-    sub = os.path.join(path, "artifacts")
-    if not any(n.endswith(".katibx") for n in _ls(path)) and os.path.isdir(sub):
-        path = sub
-    rows = scan_dir(path)
-    if args.json:
-        print(_json.dumps({"dir": path, "artifacts": rows}, indent=2))
-        return 0
-    fp = env_fingerprint()
-    print(
-        f"artifact dir {os.path.abspath(path)} · this host: "
-        f"jax {fp['jax']} · {fp['platform']}/{fp['device_kind']} "
-        f"x{fp['device_count']}"
-    )
-    if not rows:
-        print("(empty)")
-        return 0
-    table = [
-        [
-            r.get("program", "?"),
-            r.get("k", "?"),
-            r.get("status", "?"),
-            f"{r.get('bytes', 0) / 1024:.0f}K",
-            r.get("jax", "?"),
-            f"{r.get('platform', '?')}/{r.get('device_kind', '?')}",
-            "yes" if r.get("cost") else "-",
-        ]
-        for r in rows
-    ]
-    print(
-        _table(
-            table,
-            ["program", "k", "status", "size", "jax", "target", "cost"],
-        )
-    )
-    loadable = sum(1 for r in rows if r.get("status") == "ok")
-    print(
-        f"{len(rows)} artifact(s), {loadable} loadable here "
-        f"({sum(1 for r in rows if r.get('status') == 'corrupt')} corrupt — "
-        "run `katib-tpu fsck` to quarantine)"
-    )
-    return 0
-
-
-def _ls(path: str) -> list[str]:
-    try:
-        return os.listdir(path)
-    except OSError:
-        return []
 
 
 def cmd_chaos(args: argparse.Namespace) -> int:
@@ -1863,25 +1753,6 @@ def main(argv: list[str] | None = None) -> int:
         default=600.0,
         help="max seconds to wait for queued compiles",
     )
-    p.add_argument(
-        "--publish",
-        action="store_true",
-        help="serialize compiled executables into the artifact tiers "
-        "(--artifact-dir / artifactDir / KATIB_ARTIFACT_DIR) so other "
-        "hosts fetch instead of compiling",
-    )
-    p.add_argument(
-        "--fetch-only",
-        action="store_true",
-        help="only fetch published artifacts into the local tier (new-host "
-        "sync: never compiles, misses stay cold)",
-    )
-    p.add_argument(
-        "--artifact-dir",
-        default=None,
-        help="shared artifact tier directory (overrides the spec's "
-        "artifactDir; KATIB_ARTIFACT_DIR wins over both)",
-    )
     p.set_defaults(fn=cmd_prewarm)
 
     p = sub.add_parser("list", help="list experiments")
@@ -2161,24 +2032,6 @@ def main(argv: list[str] | None = None) -> int:
         "--json", action="store_true", help="machine-readable verdict"
     )
     p.set_defaults(fn=cmd_sim)
-
-    p = sub.add_parser(
-        "cache",
-        help="inspect an artifact-cache tier (serialized executables: "
-        "program, width, publishing env, loadable here?)",
-    )
-    p.add_argument(
-        "path",
-        nargs="?",
-        default=None,
-        help="artifact dir or compile-cache dir (default: KATIB_ARTIFACT_DIR)",
-    )
-    p.add_argument(
-        "--json",
-        action="store_true",
-        help="machine-readable inventory",
-    )
-    p.set_defaults(fn=cmd_cache)
 
     p = sub.add_parser(
         "db-manager", help="run the native observation-log daemon"

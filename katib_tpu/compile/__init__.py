@@ -1,38 +1,32 @@
-"""Compile amortization: shape bucketing, a compile-signature registry, and
-a background prewarm worker.
+"""Where a trial's compiled program comes from.
 
-An earlier round's record puts a live XLA compile at 470s against a 0.54s
-step — at fleet trial volumes compilation, not training, is the bill.  Three coordinated
-pieces keep cohort dispatches on a warm cache:
+A compiled program has two homes, and no third:
+
+- below, jax's persistent compilation cache: one directory a process,
+  placed once by ``runner.trial_runner.init_compile_cache``.  A program
+  some process has compiled there is read back instead of compiled again;
+  an entry that is missing or damaged is a cache miss, and the program
+  compiles;
+- above, a process-wide table of jitted programs, kept by the model that
+  owns them (``models/transformer.py:_PROGRAMS``,
+  ``models/mnist.py:_STEP_CACHE``): trials of one structure call the same
+  function object, so a process traces and loads a program once.
+
+What is in this package serves those two:
 
 - :mod:`katib_tpu.compile.buckets` quantizes cohort width K onto a few
   padded power-of-two sizes, so heterogeneous cohorts collapse onto a
-  handful of cached executables (the inert ghost-member padding from
+  handful of programs (the inert ghost-member padding from
   ``runner/cohort.py`` makes the extra rows free);
+- :mod:`katib_tpu.compile.prewarm` warms the lower home: a strictly
+  best-effort background worker (and the ``prewarm`` verb) that calls a
+  train function's compile-only twin for the programs the orchestrator's
+  proposal groups will need, while current trials execute;
 - :mod:`katib_tpu.compile.registry` records every (program, shapes, mesh,
-  donation) signature compiled and classifies each trial's first step
-  warm/cold, exporting hit/miss counters and compile-time histograms;
-- :mod:`katib_tpu.compile.prewarm` runs a strictly best-effort background
-  worker that compiles upcoming cohort programs (fed by the orchestrator's
-  proposal groups) while current trials execute, so the next cohort's
-  first step deserializes instead of recompiling;
-- :mod:`katib_tpu.compile.artifacts` makes compiled executables portable
-  *across hosts*: serialized AOT executables in a content-addressed,
-  tiered artifact cache (local dir → shared dir → cold compile) keyed by
-  compile signature + environment fingerprint, so a brand-new host's
-  first step fetches instead of compiling.
+  donation) signature compiled, dedupes prewarm requests on it, and labels
+  each trial's first step warm or cold.
 """
 
-from katib_tpu.compile.artifacts import (  # noqa: F401
-    ARTIFACTS,
-    ArtifactCache,
-    DirectoryBackend,
-    LoadedArtifact,
-    env_fingerprint,
-    fsck_artifacts,
-    is_artifact_dir,
-    resolve,
-)
 from katib_tpu.compile.buckets import (  # noqa: F401
     bucket_size,
     bucket_table,

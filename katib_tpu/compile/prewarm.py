@@ -90,49 +90,20 @@ class PrewarmRequest:
 
 
 class PrewarmWorker:
-    """Daemon-thread compile worker over a bounded queue of requests.
-
-    With the artifact cache wired (``compile/artifacts.py``), each
-    request first tries a *fetch*: a signature whose serialized
-    executables already exist in a tier loads them instead of compiling
-    (and marks the registry warm).  After a cold twin compile, ``publish``
-    mode serializes every step program the twin observed
-    (``costmodel.observe_program`` mirrors them into the artifact offer
-    slot) and publishes one content-addressed envelope per program — one
-    host's compile warms the whole fleet.  ``fetch_only`` skips the cold
-    compile entirely (a new host syncing executables without paying for
-    the misses).
-    """
+    """Daemon-thread compile worker over a bounded queue of requests."""
 
     # the worker thread bumps the counters; the CLI/tests read them after
     # drain() — both sides go through _lock, like the thread handle itself
-    _GUARDS = guarded_by(
-        _lock=("_thread", "compiled", "failed", "fetched", "published")
-    )
+    _GUARDS = guarded_by(_lock=("_thread", "compiled", "failed"))
 
-    def __init__(
-        self,
-        registry: ShapeRegistry = REGISTRY,
-        max_queue: int = 64,
-        publish: bool = True,
-        fetch_only: bool = False,
-        force: bool = False,
-    ):
+    def __init__(self, registry: ShapeRegistry = REGISTRY, max_queue: int = 64):
         self._registry = registry
         self._queue: queue.Queue = queue.Queue(maxsize=max_queue)
         self._thread: threading.Thread | None = None
         self._stop = threading.Event()
         self._lock = make_lock("prewarm.worker")
-        self._publish = publish
-        self._fetch_only = fetch_only
-        # force: bypass the registry dedupe (CLI --publish re-runs want to
-        # ensure artifacts exist even for already-registered signatures;
-        # the artifact content address still dedupes the actual writes)
-        self._force = force
         self.compiled = 0  # successful prewarm compiles (tests/CLI)
         self.failed = 0
-        self.fetched = 0  # requests satisfied by an artifact fetch
-        self.published = 0  # programs serialized into an artifact tier
 
     def submit(self, request: PrewarmRequest) -> bool:
         """Enqueue a request; returns False (without queuing) when the
@@ -140,7 +111,7 @@ class PrewarmWorker:
         the queue is full — submission never blocks the caller."""
         if prewarm_fn_of(request.train_fn) is None:
             return False
-        if not self._force and self._registry.seen(request.signature()):
+        if self._registry.seen(request.signature()):
             return False
         try:
             self._queue.put_nowait(request)
@@ -180,7 +151,7 @@ class PrewarmWorker:
 
     def _compile(self, req: PrewarmRequest) -> None:
         sig = req.signature()
-        if not self._force and self._registry.seen(sig):
+        if self._registry.seen(sig):
             return  # raced with a trial (or a duplicate submit): already warm
         fn = prewarm_fn_of(req.train_fn)
         if fn is None:
@@ -188,26 +159,8 @@ class PrewarmWorker:
         import time
 
         from katib_tpu import costmodel
-        from katib_tpu.compile import artifacts
 
-        # cheapest warm path: someone in the fleet already published this
-        # signature's executables — load them instead of compiling
-        loaded = artifacts.ARTIFACTS.fetch_family(sig)
-        if loaded:
-            with self._lock:  # LCK001: counter read from the caller thread
-                self.fetched += 1
-            if self._publish:
-                # a local-tier hit in publish mode still syncs the shared
-                # tier (content-address dedupe makes this cheap)
-                for la in loaded:
-                    if artifacts.ARTIFACTS.replicate(la):
-                        with self._lock:
-                            self.published += 1
-            return
-        if self._fetch_only:
-            return  # sync-only mode: misses stay cold, nothing compiles
         costmodel.clear_active()  # worker thread is reused across requests
-        artifacts.clear_observed()
         started = time.perf_counter()
         fn(dict(req.shared), int(req.k), req.mesh)
         elapsed = time.perf_counter() - started
@@ -224,14 +177,6 @@ class PrewarmWorker:
                 self._registry.record_cost(sig, active[0].as_dict())
             except Exception:
                 pass  # cost is telemetry; the prewarm itself succeeded
-        if self._publish:
-            # serialize every step program the twin just observed into the
-            # artifact tiers, linked to the request signature so a fresh
-            # host's fetch_family collects them all (best-effort)
-            n = artifacts.publish_observed(sig)
-            if n:
-                with self._lock:  # LCK001: CLI reads after drain()
-                    self.published += n
 
     def drain(self, timeout: float = 30.0) -> bool:
         """Wait (bounded) for the queue to empty — CLI verb / tests only;

@@ -696,13 +696,6 @@ class ExperimentSpec:
     # outranks everything, then KATIB_COMPILE_CACHE, then this field;
     # None/empty resolves to the fixed <checkout>/.jax_cache.
     compile_cache: str | None = None
-    # Shared artifact tier: a fleet-shared directory of serialized AOT
-    # executables (compile/artifacts.py).  With it wired, the prewarm
-    # worker publishes what it compiles and the dispatch path fetches
-    # before tracing, so a brand-new host's first step is warm.  None
-    # falls back to KATIB_ARTIFACT_DIR; empty/unset disables the tier
-    # (the local <compile_cache>/artifacts tier still works).
-    artifact_dir: str | None = None
     # Hang watchdog: classify a trial FailureKind.HANG (and interrupt it)
     # when no progress signal lands for this long — propagated into every
     # TrialSpec (see TrialSpec.progress_deadline_seconds).  None = disabled.

@@ -73,14 +73,7 @@ def observe_program(
                 if label in _MEMO:
                     rec, hit = _MEMO[label], True
         if not hit:
-            # a fetched artifact carries its cost record in the envelope —
-            # adopt it and skip the extra trace (the whole point of the
-            # ride-along: fetched programs publish MFU without re-tracing)
-            rec = _artifact_cost(fn, args, program)
-            if rec is None:
-                rec = extract_cost(
-                    fn, args, program=program, steps=steps, dtype=dtype
-                )
+            rec = extract_cost(fn, args, program=program, steps=steps, dtype=dtype)
             if hashable:
                 with _MEMO_LOCK:
                     _MEMO[label] = rec
@@ -88,33 +81,7 @@ def observe_program(
                         _MEMO.pop(next(iter(_MEMO)))
         if rec is not None:
             set_active_cost(rec, per_report=per_report)
-        # mirror the (fn, args, cost) into the artifact offer slot: the
-        # prewarm worker publishes what its twin observed, and this call
-        # is the one place twins hand over exactly that pair
-        try:
-            from katib_tpu.compile import artifacts
-
-            artifacts.note_observed(
-                fn,
-                args,
-                program=program,
-                cost=rec.as_dict() if rec is not None else None,
-            )
-        except Exception:
-            pass
         return rec
-    except Exception:
-        return None
-
-
-def _artifact_cost(fn: Any, args: tuple, program: str) -> CostRecord | None:
-    """The cost record riding with a loaded artifact matching this
-    program at these avals, or None (then the caller traces)."""
-    try:
-        from katib_tpu.compile.artifacts import ARTIFACTS
-
-        cost = ARTIFACTS.cost_for(program, args)
-        return CostRecord.from_dict(cost) if cost else None
     except Exception:
         return None
 

@@ -19,7 +19,6 @@ import numpy as np
 import optax
 
 from katib_tpu import costmodel
-from katib_tpu.compile import artifacts as compile_artifacts
 from katib_tpu.models.data import Dataset, batches, load_mnist
 from katib_tpu.parallel.mesh import shard_batch
 from katib_tpu.parallel.train import (
@@ -248,13 +247,6 @@ def train_classifier(
     tx, step, evaluate, cached_scan_epoch, aug_step = _steps_for(
         model, optimizer, mesh, augment_fn
     )
-    # streamed-path twin of the scan_epoch resolve below (one report spans
-    # an epoch's worth of single-step dispatches)
-    step = compile_artifacts.resolve(
-        step,
-        program="train_classifier.step",
-        per_report=max(1, len(dataset.x_train) // batch_size),
-    )
     # augmentation randomness: independent of the shuffle stream, folded
     # with the GLOBAL step in both execution paths (scan folds
     # TrainState.step in-body; the streamed loop mirrors it with a running
@@ -287,12 +279,7 @@ def train_classifier(
         # trials reuse one executable
         xd = jax.device_put(dataset.x_train)
         yd = jax.device_put(dataset.y_train)
-        # artifact dispatch seam: a serialized executable fetched for this
-        # program (compile/artifacts.py) takes the first dispatch instead
-        # of tracing; no artifact loaded = plain jit, one dict probe
-        scan_epoch = compile_artifacts.resolve(
-            cached_scan_epoch, program="train_classifier.scan_epoch"
-        )
+        scan_epoch = cached_scan_epoch
 
     # eval prefix is constant across epochs — build (and place) it once;
     # under a mesh it truncates to a multiple of the data-axis size
@@ -491,13 +478,6 @@ def mnist_cohort_trial(cctx) -> None:
         jax.random.PRNGKey(seed), jnp.zeros((1, *dataset.input_shape), jnp.float32)
     )
     tx, step, evaluate = _cohort_steps_for(model, optimizer, cctx.cohort_mesh)
-    # artifact dispatch seam (see train_classifier): fetched cohort-step
-    # executables dispatch without tracing
-    step = compile_artifacts.resolve(
-        step,
-        program="mnist_cohort_trial.step",
-        per_report=max(1, len(dataset.x_train) // batch_size),
-    )
     base = TrainState.create(params, tx)
     state = stack_pytrees([base] * k)
     # per-member hyperparameters as [K] runtime operands (stacked() pads

@@ -219,11 +219,6 @@ class Orchestrator:
         # persistent XLA compilation cache (init_compile_cache resolves the
         # directory); process-global, first writer wins
         init_compile_cache(spec.compile_cache)
-        # shared serialized-executable tier (KATIB_ARTIFACT_DIR env wins,
-        # spec field second); same first-caller-wins contract
-        from katib_tpu.compile.artifacts import ARTIFACTS
-
-        ARTIFACTS.configure(spec.artifact_dir)
         if resume and experiment is None:
             experiment = self.load_experiment(spec)
         exp = experiment or Experiment(spec=spec)

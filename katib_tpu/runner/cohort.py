@@ -515,15 +515,6 @@ def run_cohort(
                     cohort_fn, survivors, ctx.padded_size, ctx.cohort_mesh
                 )
                 cost_sig_holder[0] = sig_holder[0]
-                # dispatch tries a fetch before tracing: published
-                # executables for this cohort signature load (warm +
-                # resolve() adoption) instead of compiling — best-effort
-                try:
-                    from katib_tpu.compile.artifacts import ARTIFACTS
-
-                    ARTIFACTS.fetch_family(sig_holder[0])
-                except Exception:
-                    pass
                 costmodel.clear_active()  # fresh tier = fresh program cost
                 first_step_at[0] = time.perf_counter()
                 last_beat[0] = first_step_at[0]

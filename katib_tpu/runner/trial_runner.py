@@ -64,8 +64,8 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
 
 def compile_cache_dir() -> str | None:
     """The directory ``init_compile_cache`` put in force — the one place
-    the shape registry, the local artifact tier and the first-step spans
-    read it from.  None until the cache is wired."""
+    the shape registry and the first-step spans read it from.  None until
+    the cache is wired."""
     return _COMPILE_CACHE_DIR
 
 
@@ -254,17 +254,6 @@ def _run_whitebox(
     first_step_sig = compile_registry.trial_signature(
         trial.spec.train_fn, trial, mesh
     )
-    # dispatch tries a fetch before tracing: executables another host (or
-    # an earlier process) published for this signature load here — marking
-    # it warm and arming the model's resolve() seam — instead of compiling.
-    # Best-effort: a miss, an unreadable tier, or no tier at all just
-    # means the ordinary trace-and-compile path below.
-    try:
-        from katib_tpu.compile.artifacts import ARTIFACTS
-
-        ARTIFACTS.fetch_family(first_step_sig)
-    except Exception:
-        pass
     started_holder = [get_clock().perf_counter()]
     first_step_seen = [False]
     last_beat = [0.0]

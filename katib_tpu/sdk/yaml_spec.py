@@ -358,9 +358,6 @@ def experiment_spec_from_dict(data: Mapping[str, Any]) -> ExperimentSpec:
         compile_cache=(
             str(spec["compileCache"]) if spec.get("compileCache") is not None else None
         ),
-        artifact_dir=(
-            str(spec["artifactDir"]) if spec.get("artifactDir") is not None else None
-        ),
         compile_deadline_seconds=(
             float(spec["compileDeadlineSeconds"])
             if spec.get("compileDeadlineSeconds") is not None

@@ -621,9 +621,6 @@ async function counters(){
   // separate Prometheus scrape needed for the counter strip
   const s=await j('/api/status');const m=s.metrics||{};
   const tot=n=>m[n]?m[n].total:0;
-  // per-tier slice of a labeled counter (artifact hit/miss strip)
-  const tier=(n,t)=>m[n]?m[n].samples.filter(x=>(x.labels||{}).tier===t)
-    .reduce((a,x)=>a+x.value,0):0;
   const dur=m['katib_trial_duration_seconds'];
   const mean=dur&&dur.total?(dur.samples.reduce((a,x)=>a+x.sum,0)/dur.total):null;
   // device-health strip: the per-device preflight gauge (1 healthy / 0
@@ -669,9 +666,6 @@ async function counters(){
     ((tot('katib_compile_cache_hits_total')||tot('katib_compile_cache_misses_total'))?
       ` · compile cache: ${tot('katib_compile_cache_hits_total')} warm / ${tot('katib_compile_cache_misses_total')} cold`:'')+
     (tot('katib_prewarm_compiles_total')?` · prewarmed: ${tot('katib_prewarm_compiles_total')}`:'')+
-    ((tot('katib_artifact_hits_total')||tot('katib_artifact_publishes_total'))?
-      ` · artifacts: ${tier('katib_artifact_hits_total','local')} local / ${tier('katib_artifact_hits_total','shared')} shared fetched · ${tot('katib_artifact_publishes_total')} published`:'')+
-    (tot('katib_artifact_quarantines_total')?` · <b>artifact quarantines: ${tot('katib_artifact_quarantines_total')}</b>`:'')+
     (tot('katib_journal_replayed_events_total')?` · journal replayed: ${tot('katib_journal_replayed_events_total')}`:'')+
     (tot('katib_settlement_duplicates_total')?` · settle dups dropped: ${tot('katib_settlement_duplicates_total')}`:'')+
     (tot('katib_suggester_fence_rebuilds_total')?` · fence rebuilds: ${tot('katib_suggester_fence_rebuilds_total')}`:'')+

@@ -559,26 +559,6 @@ first_step_compile_seconds = REGISTRY.histogram(
     "Time from trial start to the first step boundary, split warm vs cold "
     "(cache label) — a cache regression shows as the cold series growing",
 )
-artifact_hits = REGISTRY.counter(
-    "katib_artifact_hits_total",
-    "Serialized-executable artifacts fetched and loaded, per tier "
-    "(tier=local|shared) — a shared hit is a compile another host paid",
-)
-artifact_misses = REGISTRY.counter(
-    "katib_artifact_misses_total",
-    "Artifact lookups that found nothing in a tier (tier label); a miss "
-    "in every tier degrades to a cold compile",
-)
-artifact_publishes = REGISTRY.counter(
-    "katib_artifact_publishes_total",
-    "Serialized executables published to an artifact tier (tier label; "
-    "deduped on content address, so fleets publish each program once)",
-)
-artifact_quarantines = REGISTRY.counter(
-    "katib_artifact_quarantines_total",
-    "Corrupt or mismatched artifact envelopes moved aside "
-    "(tier=local|shared|fsck) instead of crashing the fetch path",
-)
 
 # -- preemption / hang robustness (utils/watchdog.py, orchestrator drain) -----
 

@@ -17,6 +17,8 @@ run on the same mesh shapes the TPU path uses.
 
 from __future__ import annotations
 
+import json
+import os
 import threading
 import time
 
@@ -78,6 +80,10 @@ def _make_trial(name, spec_kw=None, **params):
             **(spec_kw or {}),
         ),
     )
+
+
+def _sig(program: str, k: int = 2) -> CompileSignature:
+    return CompileSignature(program=program, shapes=(("units", "8"),), k=k)
 
 
 def _total(metric) -> float:
@@ -190,6 +196,68 @@ class TestShapeRegistry:
         assert reg.note_first_step(sig, 0.1) == "warm"
         assert obs.compile_cache_misses.get(program=sig.program) == m0 + 1
         assert obs.compile_cache_hits.get(program=sig.program) == h0 + 1
+
+
+class TestRegistryCompaction:
+    def _registry_file(self, tmp_path, monkeypatch):
+        import katib_tpu.compile.registry as registry_mod
+
+        monkeypatch.setattr(registry_mod, "_cache_dir", lambda: str(tmp_path))
+        return tmp_path / "shape_registry.jsonl"
+
+    def test_duplicate_rows_compact_on_open(self, tmp_path, monkeypatch):
+        path = self._registry_file(tmp_path, monkeypatch)
+        sig = _sig("compact.step")
+        row = {
+            "key": sig.key(), "program": sig.program, "k": sig.k,
+            "mesh": sig.mesh, "shapes": dict(sig.shapes),
+            "donation": sig.donation, "source": "trial",
+        }
+        lines = [dict(row), dict(row, cost={"flops": 1.0}),
+                 dict(row, cost={"flops": 2.0})]
+        path.write_text("".join(json.dumps(r) + "\n" for r in lines))
+        reg = ShapeRegistry()
+        assert reg.seen(sig)  # triggers load + compaction
+        # the freshest cost won the merge
+        assert reg.cost_of(sig) == {"flops": 2.0}
+        kept = [json.loads(l) for l in path.read_text().splitlines()]
+        assert len(kept) == 1
+        assert kept[0]["cost"] == {"flops": 2.0}
+        # no temp residue from the durable rewrite
+        assert os.listdir(tmp_path) == ["shape_registry.jsonl"]
+
+    def test_unique_rows_left_alone(self, tmp_path, monkeypatch):
+        path = self._registry_file(tmp_path, monkeypatch)
+        rows = [
+            {"key": _sig(f"p{i}.step").key(), "program": f"p{i}.step",
+             "k": 2, "mesh": "", "shapes": {}, "donation": True,
+             "source": "trial"}
+            for i in range(3)
+        ]
+        body = "".join(json.dumps(r) + "\n" for r in rows)
+        path.write_text(body)
+        reg = ShapeRegistry()
+        assert len(reg.signatures()) == 3
+        assert path.read_text() == body  # byte-identical: no rewrite
+
+    def test_torn_tail_with_dupes_heals(self, tmp_path, monkeypatch):
+        path = self._registry_file(tmp_path, monkeypatch)
+        sig = _sig("torn.step")
+        row = {
+            "key": sig.key(), "program": sig.program, "k": sig.k,
+            "mesh": sig.mesh, "shapes": dict(sig.shapes),
+            "donation": sig.donation, "source": "trial",
+        }
+        path.write_text(
+            json.dumps(row) + "\n" + json.dumps(row) + "\n" + '{"key": "to'
+        )
+        with pytest.warns(RuntimeWarning, match="torn"):
+            reg = ShapeRegistry()
+            assert reg.seen(sig)
+        # compaction rewrote the file: dupes merged, torn tail gone
+        kept = path.read_text()
+        assert kept.endswith("\n") and len(kept.splitlines()) == 1
+        assert ShapeRegistry().seen(sig)
 
 
 class TestPrewarmWorker:
