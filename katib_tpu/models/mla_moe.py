@@ -46,6 +46,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from katib_tpu.models.lm_head import LMHead
+
 #: the collection an expert layer sows a step's routing counts into
 ROUTING = "routing"
 
@@ -273,7 +275,7 @@ class MlaMoeLM(nn.Module):
         return self.sizes.qk_nope_dim + self.sizes.qk_rope_dim, self.sizes.v_head_dim
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, multiply_head: bool = True):
         z = self.sizes
         attn = self.attn_fn
         if attn is None:
@@ -284,7 +286,7 @@ class MlaMoeLM(nn.Module):
                 z, i < z.first_dense_layers, attn, self.dtype, name=f"layer_{i}"
             )(x)
         x = RMSNorm(z.eps, self.dtype, name="norm")(x)
-        return nn.Dense(self.vocab_size, use_bias=False, dtype=jnp.float32, name="head")(x)
+        return LMHead(self.vocab_size, use_bias=False, name="head")(x, multiply_head)
 
     @staticmethod
     def step_counters(routing) -> dict:

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from katib_tpu.models.lm_head import HeadInputs, LMHead, chunk_rows, head_loss, next_token_loss
 from katib_tpu.models.mla_moe import ROUTING, MlaMoeLM, MlaMoeSizes
 from katib_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, replicated, shard_batch
 from katib_tpu.parallel.ring_attention import make_sequence_parallel_attention
@@ -97,7 +98,7 @@ class TransformerLM(nn.Module):
         return (self.d_model // self.n_heads,) * 2
 
     @nn.compact
-    def __call__(self, tokens, deterministic: bool = True):
+    def __call__(self, tokens, deterministic: bool = True, multiply_head: bool = True):
         attn = self.attn_fn
         if attn is None:
             attn = _dense_causal_attention
@@ -112,7 +113,7 @@ class TransformerLM(nn.Module):
                 dropout=self.dropout, dtype=self.dtype,
             )(x, deterministic)
         x = nn.LayerNorm(dtype=self.dtype)(x)
-        return nn.Dense(self.vocab_size, dtype=jnp.float32)(x)
+        return LMHead(self.vocab_size, name="Dense_0")(x, multiply_head)
 
 
 def _dense_causal_attention(q, k, v):
@@ -159,6 +160,16 @@ def attn_tiles(model, seq_len: int) -> str:
     return f"{dtype.name} q{bq} k{bk}"
 
 
+def loss_path(model, batch: int, seq_len: int, mesh) -> str:
+    """How a trial's loss runs, for the ``trial.init`` span: ``fused`` on the
+    dense logits a model on a mesh multiplies out, ``fused rows=<sequences a
+    chunk> x <chunks>`` where the head's product runs inside it."""
+    if mesh is not None:
+        return "fused"
+    rows = chunk_rows(batch, seq_len, model.vocab_size)
+    return f"fused rows={rows} x {batch // rows}"
+
+
 # ---------------------------------------------------------------------------
 # synthetic Markov LM data
 # ---------------------------------------------------------------------------
@@ -186,12 +197,14 @@ def markov_dataset(
 # ---------------------------------------------------------------------------
 
 
-def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
-    """Next-token cross entropy over [B, S, V] logits / [B, S] tokens."""
-    logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
-    tgt = tokens[:, 1:]
-    nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-    return jnp.mean(nll)
+def lm_loss(logits: jnp.ndarray | HeadInputs, tokens: jnp.ndarray) -> jnp.ndarray:
+    """Next-token cross entropy over [B, S, V] logits / [B, S] tokens.  In
+    place of dense logits it takes what the head would multiply (a model
+    called with ``multiply_head=False``); the product then runs inside the
+    loss, in row chunks (``models/lm_head.py``)."""
+    if isinstance(logits, HeadInputs):
+        return head_loss(logits, tokens)
+    return next_token_loss(logits, tokens)
 
 
 #: AdamW's decoupled weight decay, on every parameter
@@ -235,9 +248,14 @@ def _build_programs(
     if mesh is not None and DATA_AXIS in mesh.shape:
         init_batch = mesh.shape[DATA_AXIS]
 
+    # on one device the head's product runs inside the loss, in row chunks;
+    # over a mesh the model multiplies it out (the chunk loop would run over
+    # the axis a ``data`` mesh shards) and the loss takes dense logits
+    multiply_head = mesh is not None
+
     def loss_fn(params, tokens, dropout_key):
         dropout = {"deterministic": False, "rngs": {"dropout": dropout_key}} if use_dropout else {}
-        logits, sown = model.apply(params, tokens, mutable=[ROUTING], **dropout)
+        logits, sown = model.apply(params, tokens, mutable=[ROUTING], multiply_head=multiply_head, **dropout)
         return lm_loss(logits, tokens), sown.get(ROUTING, {})
 
     # parameters and optimizer state in one program (the forward pass that
@@ -265,7 +283,7 @@ def _build_programs(
 
     @jax.jit
     def eval_fn(params, tokens):
-        return lm_loss(model.apply(params, tokens), tokens)
+        return lm_loss(model.apply(params, tokens, multiply_head=multiply_head), tokens)
 
     return TrialPrograms(init, step_fn, eval_fn)
 
@@ -330,6 +348,7 @@ def train_lm(
             programs="reused" if reused else "built",
             block=model.BLOCK,
             attn_tiles=attn_tiles(model, data.shape[1]),
+            loss=loss_path(model, batch_size, data.shape[1], mesh),
         )
         state = programs.init(jax.random.PRNGKey(seed), data.shape[1])
         schedule = (

@@ -443,6 +443,22 @@ class TestTrialPrograms:
         assert transformer.attn_tiles(latent, 4096) == "bfloat16 q%d k%d" % plan_tiles(4096, 4096, 192, 128, jnp.bfloat16)
         assert transformer.attn_tiles(dataclasses.replace(small, attn_fn=lambda q, k, v: q), 1024) == "seq-parallel"
 
+    def test_trial_init_names_how_the_loss_runs(self, tmp_path):
+        """One device: the head's product inside the loss, in chunks sized
+        from the shapes; over a mesh: the loss on the dense logits."""
+        from katib_tpu.models import transformer
+
+        _, spans = _traced_train_lm(tmp_path, "a", _tiny_lm(dropout=0.05), lr=1e-3, steps=2)
+        assert spans["trial.init"]["loss"] == "fused rows=4 x 1"
+        small = transformer.TransformerLM(vocab_size=50257, d_model=768, n_heads=12)
+        assert transformer.loss_path(small, 8, 1024, None) == "fused rows=4 x 2"
+        mesh = make_mesh({DATA_AXIS: 4, SEQ_AXIS: 1}, devices=jax.devices()[:4])
+        assert transformer.loss_path(small, 8, 1024, mesh) == "fused"
+        losses, spans = _traced_train_lm(
+            tmp_path, "m", _tiny_lm(mesh=mesh, dropout=0.05), lr=1e-3, steps=2, mesh=mesh
+        )
+        assert spans["trial.init"]["loss"] == "fused" and np.all(np.isfinite(losses))
+
     def test_new_depth_builds_and_trains_its_own(self, tmp_path):
         from katib_tpu.models import transformer
 
@@ -548,14 +564,148 @@ class TestTrialPrograms:
         assert sorted(reused for _, reused in got) == [False] + [True] * (n - 1)
 
 
+def _plain_lm_loss(logits, tokens):
+    """Next-token cross entropy as autodiff sees it written down: float32
+    ``log_softmax`` over the vocabulary, the targets' log-probabilities, their
+    mean over the ``B x (S - 1)`` positions that have a next token."""
+    logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32), axis=-1)
+    return -jnp.mean(jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0])
+
+
+# -- the cross entropy as one operation with its own backward ----------------
+
+
+def _head_case(dtype, bias=True, shift=0.0, vocab=131, batch=4):
+    """Hidden states, kernel, bias and tokens at a vocabulary that is no
+    multiple of 128; ``shift`` moves every logit (through the bias)."""
+    k = jax.random.split(jax.random.PRNGKey(3), 4)
+    x = jax.random.normal(k[0], (batch, 9, 16)).astype(dtype)
+    w = jax.random.normal(k[1], (16, vocab))
+    b = 2.0 * jax.random.normal(k[2], (vocab,)) + shift if bias else None
+    tokens = jax.random.randint(k[3], (batch, 9), 0, vocab)
+    return x, w, b, tokens
+
+
+def _plain_head_loss(x, w, b, tokens):
+    logits = jnp.dot(x.astype(jnp.float32), w)
+    return _plain_lm_loss(logits if b is None else logits + b, tokens)
+
+
+def _results_of_shape(jaxpr, shape, dtype=jnp.float32):
+    """Every equation result of one shape and dtype, sub-programs included,
+    and the primitives' names."""
+    n, names = 0, set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        if "name" in eqn.params:
+            names.add(str(eqn.params["name"]))
+        n += sum(getattr(v.aval, "shape", None) == shape and v.aval.dtype == dtype for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            m, more = _results_of_shape(sub, shape, dtype)
+            n, names = n + m, names | more
+    return n, names
+
+
+class TestLmLoss:
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    @pytest.mark.parametrize("shift", [0.0, 80.0, -80.0])
+    def test_dense_logits_equal_the_plain_formula(self, shift, dtype):
+        from katib_tpu.models.transformer import lm_loss
+
+        x, w, b, tokens = _head_case(jnp.float32, shift=shift)
+        logits = (jnp.dot(x, w) + b).astype(dtype)
+        got, d_got = jax.value_and_grad(lm_loss)(logits, tokens)
+        want, d_want = jax.value_and_grad(_plain_lm_loss)(logits, tokens)
+        assert got.dtype == jnp.float32 and d_got.dtype == dtype
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+        tol = 1e-7 if dtype == jnp.float32 else 2e-4  # one bfloat16 rounding of a gradient below 1/32
+        np.testing.assert_allclose(d_got.astype(jnp.float32), d_want.astype(jnp.float32), rtol=2e-5, atol=tol)
+        assert np.all(d_got[:, -1] == 0)  # the last position has no next token
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    @pytest.mark.parametrize("bias", [True, False], ids=["bias", "nobias"])
+    @pytest.mark.parametrize("shift", [0.0, 80.0, -80.0])
+    def test_head_inside_equals_the_plain_formula(self, shift, bias, dtype):
+        """What the model hands over with ``multiply_head=False``: value, dx,
+        dW and db against the product multiplied out and the plain formula."""
+        from katib_tpu.models.lm_head import HeadInputs
+        from katib_tpu.models.transformer import lm_loss
+
+        x, w, b, tokens = _head_case(dtype, bias, shift)
+        args = (0, 1, 2) if bias else (0, 1)
+        got, d_got = jax.value_and_grad(lambda x, w, b: lm_loss(HeadInputs(x, w, b), tokens), args)(x, w, b)
+        want, d_want = jax.value_and_grad(_plain_head_loss, args)(x, w, b, tokens)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+        for a, c in zip(d_got, d_want):
+            assert a.dtype == c.dtype and a.shape == c.shape
+            rtol = 2e-5 if a.dtype == jnp.float32 else 2**-7  # dx in the hidden states' dtype: one rounding
+            np.testing.assert_allclose(a.astype(jnp.float32), c.astype(jnp.float32), rtol=rtol, atol=1e-6)
+
+    @pytest.mark.parametrize("rows", [2, 1])
+    @pytest.mark.parametrize("bias", [True, False], ids=["bias", "nobias"])
+    def test_several_chunks_equal_one(self, bias, rows):
+        from katib_tpu.models.lm_head import HeadInputs, head_loss
+
+        x, w, b, tokens = _head_case(jnp.bfloat16, bias)
+        args = (0, 1, 2) if bias else (0, 1)
+
+        def grads(rows):
+            # a cotangent other than 1: the backward scales, then rounds dx once
+            return jax.jit(jax.value_and_grad(lambda x, w, b: 3.0 * head_loss(HeadInputs(x, w, b), tokens, rows), args))(x, w, b)
+
+        one, d_one = grads(4)
+        some, d_some = grads(rows)
+        np.testing.assert_allclose(some, one, rtol=1e-6)
+        for a, c in zip(d_some, d_one):
+            assert a.dtype == c.dtype
+            np.testing.assert_allclose(a.astype(jnp.float32), c.astype(jnp.float32), atol=3e-7)
+        with pytest.raises(ValueError, match="do not divide"):
+            head_loss(HeadInputs(x, w, b), tokens, 3)
+
+    def test_chunk_rows_come_from_the_shapes(self):
+        from katib_tpu.models.lm_head import CHUNK_BYTES, chunk_rows
+
+        assert chunk_rows(8, 1024, 50257) == 4  # gpt2-small: two chunks of 0.82 GB
+        assert chunk_rows(2, 4096, 16032) == 2  # the whole array is 0.53 GB: one chunk
+        assert chunk_rows(4, 16, 32) == 4  # every CPU test: one chunk
+        assert chunk_rows(6, 1024, 100_000) == 2  # a divisor of the batch: not 4, which fits too
+        assert chunk_rows(3, CHUNK_BYTES, 2) == 1  # one sequence passes the limit: still one a chunk
+
+    @pytest.mark.parametrize("form", ["dense", "head"])
+    def test_gradient_program_holds_no_log_softmax_and_no_scatter(self, form):
+        from katib_tpu.models.lm_head import HeadInputs
+        from katib_tpu.models.transformer import lm_loss
+
+        x, w, b, tokens = _head_case(jnp.bfloat16)
+        big = (*tokens.shape, w.shape[1])
+        if form == "dense":
+            logits = jnp.dot(x.astype(jnp.float32), w) + b
+            ours = jax.make_jaxpr(jax.grad(lm_loss))(logits, tokens)
+            plain = jax.make_jaxpr(jax.grad(_plain_lm_loss))(logits, tokens)
+            most = 7  # forward: l - max, exp; backward: l - lse, exp, the hit as float32, p - hit, the scale
+        else:
+            ours = jax.make_jaxpr(jax.grad(lambda x, w, b: lm_loss(HeadInputs(x, w, b), tokens), (0, 1, 2)))(x, w, b)
+            plain = jax.make_jaxpr(jax.grad(_plain_head_loss, (0, 1, 2)))(x, w, b, tokens)
+            most = 9  # and the product, and its bias
+        n, names = _results_of_shape(ours.jaxpr, big)
+        sliced = (big[0], big[1] - 1, big[2])  # the plain formula slices to S - 1 and pads back
+        n_plain, names_plain = _results_of_shape(plain.jaxpr, big)
+        n_plain += _results_of_shape(plain.jaxpr, sliced)[0]
+        # the test's own eyes: the plain formula shows what must not be here
+        assert "log_softmax" in names_plain and any(p.startswith("scatter") for p in names_plain)
+        assert "log_softmax" not in names and not any(p.startswith("scatter") for p in names)
+        assert n <= most < n_plain, (n, n_plain)
+
+
+
 def _old_way_losses(model, data, *, lr, steps, batch_size, warmup_frac, grad_clip=1.0, seed=0):
     """The loop ``train_lm`` had before its programs were shared: the schedule
     a constant of ``optax.adamw``, closures of this one call, eager init."""
     import optax
 
-    from katib_tpu.models.transformer import lm_loss
     from katib_tpu.parallel.train import TrainState, clip_by_global_norm
 
+    lm_loss = _plain_lm_loss  # the formula written out: independent of the program's
     rng = np.random.default_rng(seed)
     n_eval = max(batch_size, len(data) // 10)
     train, heldout = data[:-n_eval], data[-n_eval:]
