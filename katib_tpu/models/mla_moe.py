@@ -39,7 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Callable
+from typing import Callable, ClassVar
 
 import flax.linen as nn
 import jax
@@ -74,6 +74,9 @@ class MlaMoeSizes:
     experts_held: tuple[int, int] = (0, 16)  # (first index, count)
     rope_theta: float = 1e6
     eps: float = 1e-6
+    # what ``ExpertLayer`` reads besides, fixed in this family
+    scoring: ClassVar[str] = "sigmoid"
+    expert_act: ClassVar[str] = "silu"
 
 
 class RMSNorm(nn.Module):
@@ -88,17 +91,22 @@ class RMSNorm(nn.Module):
         return (x * scale).astype(self.dtype)
 
 
-def rotary(x, theta: float):
-    """Rotary position embedding over the last axis of ``x`` [B, S, H, R],
-    pairs interleaved: (x[2i], x[2i+1]) turns by ``pos * theta**(-2i/R)``.
-    The result holds the first members of the pairs, then the second (queries
-    and keys alike, so their products do not see the order)."""
+def rotary(x, theta: float, interleaved: bool = True):
+    """Rotary position embedding over the last axis of ``x`` [B, S, H, R]: the
+    pair ``i`` turns by ``pos * theta**(-2i/R)``.  Pairs interleaved: (x[2i],
+    x[2i+1]); else the halves paired: (x[i], x[i + R/2]).  The result holds
+    the first members of the pairs, then the second (queries and keys alike,
+    so their products do not see the order)."""
     r = x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, r, 2, dtype=jnp.float32) / r))
     angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq  # [S, R/2]
     cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
-    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], r // 2, 2)
-    a, b = pairs[..., 0], pairs[..., 1]
+    x32 = x.astype(jnp.float32)
+    if interleaved:
+        pairs = x32.reshape(*x.shape[:-1], r // 2, 2)
+        a, b = pairs[..., 0], pairs[..., 1]
+    else:
+        a, b = x32[..., : r // 2], x32[..., r // 2 :]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
 
 
@@ -179,13 +187,22 @@ _rows_to_assignments.defvjp(_rows_to_assignments_fwd, _rows_to_assignments_bwd)
 class ExpertLayer(nn.Module):
     """Router over ``n_experts``, the routed experts held here, and the shared
     experts.  Returns what this share adds to the residual stream; sows the
-    step's routing counts into ``ROUTING`` (where that collection is mutable)."""
+    step's routing counts into ``ROUTING`` (where that collection is mutable).
 
-    sizes: MlaMoeSizes
+    One layer for every block family that has experts.  It reads of ``sizes``:
+    ``n_experts``, ``experts_per_token``, ``experts_held``, ``expert_width``,
+    ``n_shared_experts`` (0: none), ``routed_scaling``, ``scoring``
+    (``sigmoid``: the largest sigmoid scores, normalised over the chosen;
+    ``softmax``: the largest logits, a softmax over the chosen) and
+    ``expert_act`` (the gate's activation: ``silu`` | ``relu``).  A block
+    whose router reads another stream than ``h`` hands the ``router_logits``
+    [B, S, n_experts] in (float32); the router's matrix is then the block's."""
+
+    sizes: MlaMoeSizes  # or another family's sizes with the fields above
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, router_logits=None):
         z = self.sizes
         b, s, d = h.shape
         k = z.experts_per_token
@@ -193,12 +210,20 @@ class ExpertLayer(nn.Module):
         x = h.reshape(b * s, d)
 
         # -- route over all experts, in float32
-        w_router = self.param("router", nn.initializers.lecun_normal(), (d, z.n_experts))
-        scores = jax.nn.sigmoid(
-            jnp.dot(x.astype(jnp.float32), w_router, precision=jax.lax.Precision.HIGHEST)
-        )
-        top_scores, top_experts = jax.lax.top_k(scores, k)  # [T, k]
-        weights = z.routed_scaling * top_scores / (top_scores.sum(-1, keepdims=True) + 1e-20)
+        if router_logits is None:
+            w_router = self.param("router", nn.initializers.lecun_normal(), (d, z.n_experts))
+            logits = jnp.dot(x.astype(jnp.float32), w_router, precision=jax.lax.Precision.HIGHEST)
+        else:
+            logits = router_logits.reshape(b * s, z.n_experts)
+        if z.scoring == "sigmoid":
+            top_scores, top_experts = jax.lax.top_k(jax.nn.sigmoid(logits), k)  # [T, k]
+            weights = z.routed_scaling * top_scores / (top_scores.sum(-1, keepdims=True) + 1e-20)
+        elif z.scoring == "softmax":
+            top_logits, top_experts = jax.lax.top_k(logits, k)
+            weights = z.routed_scaling * jax.nn.softmax(top_logits, axis=-1)
+        else:
+            raise ValueError(f"ExpertLayer: scoring {z.scoring!r} is neither 'sigmoid' nor 'softmax'")
+        act = {"silu": nn.silu, "relu": nn.relu}[z.expert_act]
 
         # -- the assignments held here, sorted by expert; the others sort last
         local = top_experts - first
@@ -227,9 +252,10 @@ class ExpertLayer(nn.Module):
             return jnp.where(held_row, out, 0.0)
 
         rows = _rows_to_experts(x, order, inverse, k)
-        out = grouped(nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up), w_down)
+        out = grouped(act(grouped(rows, w_gate)) * grouped(rows, w_up), w_down)
         out = _rows_to_assignments(out.astype(self.dtype), order, inverse).reshape(b * s, k, d)
         routed = jnp.einsum("tkd,tk->td", out, jnp.where(held, weights, 0.0).astype(self.dtype))
+        routed = routed.reshape(b, s, d)
 
         if not self.is_initializing():
             # the rows computed for each held expert, and the assignments as
@@ -237,8 +263,31 @@ class ExpertLayer(nn.Module):
             self.sow(ROUTING, "expert_tokens", group_sizes)
             self.sow(ROUTING, "assignments", jnp.stack([held.sum(), jnp.int32(held.size)]))
 
+        if not z.n_shared_experts:
+            return routed
         shared = SwiGLU(z.expert_width * z.n_shared_experts, self.dtype, name="shared")(h)
-        return shared + routed.reshape(b, s, d)
+        return shared + routed
+
+
+def routing_counters(routing) -> dict:
+    """A step's routing counts (``ROUTING`` as the step returned it, fetched)
+    as the attributes of a span: assignments to the experts held and to all,
+    the busiest held expert's tokens in one layer against the mean, and the
+    assignments to a held expert that its product did not compute (0: the
+    sorted buffer holds every assignment)."""
+    leaves: dict = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(routing):
+        name = [p.key for p in path if hasattr(p, "key")][-1]
+        leaves.setdefault(name, []).append(np.asarray(leaf))
+    tokens = np.stack(leaves["expert_tokens"])  # [expert layers, held]
+    held, total = np.stack(leaves["assignments"]).sum(axis=0)
+    return {
+        "moe_assignments_held": int(held),
+        "moe_assignments_total": int(total),
+        "moe_expert_tokens_max": int(tokens.max()),
+        "moe_expert_tokens_mean": float(tokens.mean()),
+        "moe_tokens_dropped": int(held - tokens.sum()),
+    }
 
 
 class MlaMoeBlock(nn.Module):
@@ -274,6 +323,16 @@ class MlaMoeLM(nn.Module):
         """A head's key and value widths."""
         return self.sizes.qk_nope_dim + self.sizes.qk_rope_dim, self.sizes.v_head_dim
 
+    @property
+    def attn_heads(self) -> int:
+        """Query heads: the kernels' grids walk one at a time."""
+        return self.sizes.n_heads
+
+    @property
+    def attn_kinds(self) -> list[tuple[int | None, str, int]]:
+        """(window, positions, layers) of each kind of attention layer."""
+        return [(None, "rope", self.sizes.n_layers)]
+
     @nn.compact
     def __call__(self, tokens, multiply_head: bool = True):
         z = self.sizes
@@ -288,23 +347,4 @@ class MlaMoeLM(nn.Module):
         x = RMSNorm(z.eps, self.dtype, name="norm")(x)
         return LMHead(self.vocab_size, use_bias=False, name="head")(x, multiply_head)
 
-    @staticmethod
-    def step_counters(routing) -> dict:
-        """A step's routing counts (``ROUTING`` as the step returned it,
-        fetched) as the attributes of a span: assignments to the experts held
-        and to all, the busiest held expert's tokens in one layer against the
-        mean, and the assignments to a held expert that its product did not
-        compute (0: the sorted buffer holds every assignment)."""
-        leaves: dict = {}
-        for path, leaf in jax.tree_util.tree_leaves_with_path(routing):
-            name = [p.key for p in path if hasattr(p, "key")][-1]
-            leaves.setdefault(name, []).append(np.asarray(leaf))
-        tokens = np.stack(leaves["expert_tokens"])  # [expert layers, held]
-        held, total = np.stack(leaves["assignments"]).sum(axis=0)
-        return {
-            "moe_assignments_held": int(held),
-            "moe_assignments_total": int(total),
-            "moe_expert_tokens_max": int(tokens.max()),
-            "moe_expert_tokens_mean": float(tokens.mean()),
-            "moe_tokens_dropped": int(held - tokens.sum()),
-        }
+    step_counters = staticmethod(routing_counters)

@@ -11,13 +11,20 @@ training on a sharded mesh with the same trial API as the CNN workloads.
 
 Tunable parameters understood by ``transformer_trial``: lr, d_model,
 n_heads, n_layers, seq_len, vocab_size, batch_size, n_seq, data_seed, steps,
-warmup_frac, attn(ring|ulysses), dropout, and block(gpt2|mla_moe).  With
-``block: mla_moe`` (latent attention and sparse experts,
+warmup_frac, attn(ring|ulysses), dropout, and block(gpt2|mla_moe|gqa_moe).
+With ``block: mla_moe`` (latent attention and sparse experts,
 ``katib_tpu.models.mla_moe``) also: first_dense_layers, qk_nope_dim,
 qk_rope_dim, v_head_dim, kv_lora_rank, dense_width, expert_width, n_experts,
 experts_per_token, n_shared_experts, routed_scaling, rope_theta, eps, and the
 share of the routed experts this trial holds: experts_held_first,
-experts_held (default: all); dropout and a ``seq`` mesh axis are refused.
+experts_held (default: all).  With ``block: gqa_moe`` (grouped-query
+attention in a period of layer kinds, experts routed from the layer's input,
+``katib_tpu.models.gqa_moe``) also: n_kv_heads, head_dim, window,
+window_layout and rope_layout (a period of layer kinds as a string of 0 and
+1, one character a kind: ``"0111"``; 1 sees ``window`` keys / carries rotary
+positions), expert_width, n_experts, experts_per_token, rope_theta, eps,
+experts_held_first, experts_held.  Both expert blocks refuse dropout and a
+``seq`` mesh axis.
 
 The training task is a synthetic first-order Markov language-modelling
 problem: next-token structure is learnable (entropy well below uniform) and
@@ -41,8 +48,15 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from katib_tpu.models.gqa_moe import GqaMoeLM, GqaMoeSizes
 from katib_tpu.models.lm_head import HeadInputs, LMHead, chunk_rows, head_loss, next_token_loss
 from katib_tpu.models.mla_moe import ROUTING, MlaMoeLM, MlaMoeSizes
+from katib_tpu.ops.flash_attention import (
+    flash_attention,
+    plan_tiles,
+    reference_attention,
+    tile_visits,
+)
 from katib_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, replicated, shard_batch
 from katib_tpu.parallel.ring_attention import make_sequence_parallel_attention
 from katib_tpu.parallel.train import TrainState, clip_by_global_norm
@@ -97,6 +111,16 @@ class TransformerLM(nn.Module):
         """A head's key and value widths."""
         return (self.d_model // self.n_heads,) * 2
 
+    @property
+    def attn_heads(self) -> int:
+        """Query heads: the kernels' grids walk one at a time."""
+        return self.n_heads
+
+    @property
+    def attn_kinds(self) -> list[tuple[int | None, str, int]]:
+        """(window, positions, layers) of each kind of attention layer."""
+        return [(None, "learned", self.n_layers)]
+
     @nn.compact
     def __call__(self, tokens, deterministic: bool = True, multiply_head: bool = True):
         attn = self.attn_fn
@@ -116,16 +140,23 @@ class TransformerLM(nn.Module):
         return LMHead(self.vocab_size, name="Dense_0")(x, multiply_head)
 
 
-def _dense_causal_attention(q, k, v):
-    from katib_tpu.ops.flash_attention import reference_attention
+@lru_cache(maxsize=64)
+def _single_device_attention(kernel: bool, window: int | None = None):
+    """Causal attention on one device: the flash kernel or the dense
+    reference, over the whole prefix or the ``window`` newest keys.  The same
+    arguments give the same callable; it says what it runs (``kernel``,
+    ``window``) to ``attention_plan``."""
+    attend = flash_attention if kernel else reference_attention
 
-    return reference_attention(q, k, v, causal=True)
+    def attention(q, k, v):
+        return attend(q, k, v, causal=True, window=window)
+
+    attention.kernel, attention.window = kernel, window
+    return attention
 
 
-def _flash_causal_attention(q, k, v):
-    from katib_tpu.ops.flash_attention import flash_attention
-
-    return flash_attention(q, k, v, causal=True)
+_dense_causal_attention = _single_device_attention(False)
+_flash_causal_attention = _single_device_attention(True)
 
 
 @lru_cache(maxsize=8)
@@ -133,15 +164,16 @@ def _sequence_parallel_attention(mesh, strategy: str):
     return make_sequence_parallel_attention(mesh, strategy=strategy, causal=True)
 
 
-def make_attention_fn(mesh=None, strategy: str = "ring"):
+def make_attention_fn(mesh=None, strategy: str = "ring", window: int | None = None):
     """Attention for a trial's mesh: sequence-parallel when the mesh has a
-    ``seq`` axis > 1, single-device flash/dense otherwise.  The same
-    arguments give the same callable, so that models built from equal fields
-    compare equal and share their programs (``_programs_for``)."""
+    ``seq`` axis > 1, single-device flash/dense otherwise; ``window``: a layer
+    that sees only so many keys (single-device only).  The same arguments
+    give the same callable, so that models built from equal fields compare
+    equal and share their programs (``_programs_for``)."""
     if mesh is None:
-        if jax.default_backend() == "tpu":
-            return _flash_causal_attention
-        return _dense_causal_attention
+        return _single_device_attention(jax.default_backend() == "tpu", window)
+    if window is not None:
+        raise ValueError("make_attention_fn: no windowed attention over a mesh")
     return _sequence_parallel_attention(mesh, strategy)
 
 
@@ -149,15 +181,40 @@ def attn_tiles(model, seq_len: int) -> str:
     """What a trial's attention runs, for the ``trial.init`` span: the flash
     kernel's operand dtype and the tiles it plans for these shapes, ``dense``
     where no kernel runs, ``seq-parallel`` over a mesh's ``seq`` axis."""
-    if model.attn_fn in (None, _dense_causal_attention):
+    if model.attn_fn is None or getattr(model.attn_fn, "kernel", None) is False:
         return "dense"
-    if model.attn_fn is not _flash_causal_attention:
+    if not hasattr(model.attn_fn, "kernel"):
         return "seq-parallel"
-    from katib_tpu.ops.flash_attention import plan_tiles
-
     dtype = jnp.dtype(model.dtype)
     bq, bk = plan_tiles(seq_len, seq_len, *model.attn_widths, dtype)
     return f"{dtype.name} q{bq} k{bk}"
+
+
+def attention_plan(model, batch: int, seq_len: int) -> tuple[dict, dict]:
+    """The attention of a trial as the ``trial.init`` span carries it.
+    Attributes: ``attn_layers`` (the kinds of layer and how many of each:
+    ``"full nope x1, window4096 rope x3"``) and ``attn_tiles``.  Counters,
+    where the kernel runs: ``attn_tiles_run`` (the tiles the three kernels'
+    loops walk in one step: forward, dq, dkv, every layer, head and batch row;
+    a rematerialised forward not counted again) and ``attn_tiles_needed`` (the
+    least: the tiles of the planned size that hold a visible pair)."""
+    kinds = model.attn_kinds
+    attrs = {
+        "attn_layers": ", ".join(
+            f"{'full' if window is None else f'window{window}'} {positions} x{n}"
+            for window, positions, n in kinds
+        ),
+        "attn_tiles": attn_tiles(model, seq_len),
+    }
+    if not getattr(model.attn_fn, "kernel", False):
+        return attrs, {}
+    bq, bk = plan_tiles(seq_len, seq_len, *model.attn_widths, jnp.dtype(model.dtype))
+    run = needed = 0
+    for window, _positions, n in kinds:
+        walked, least = tile_visits(seq_len, seq_len, bq, bk, True, window)
+        run, needed = run + n * walked, needed + n * least
+    rows = batch * model.attn_heads
+    return attrs, {"attn_tiles_run": rows * run, "attn_tiles_needed": rows * needed}
 
 
 def loss_path(model, batch: int, seq_len: int, mesh) -> str:
@@ -236,7 +293,7 @@ class TrialPrograms(NamedTuple):
 
 
 def _build_programs(
-    model: TransformerLM | MlaMoeLM, grad_clip: float, weight_decay: float, mesh
+    model: TransformerLM | MlaMoeLM | GqaMoeLM, grad_clip: float, weight_decay: float, mesh
 ) -> TrialPrograms:
     # AdamW without its rate: ``step_fn`` scales the update by the schedule's
     # value, so lr, steps and warmup_frac are operands and not constants
@@ -299,7 +356,7 @@ _PROGRAMS_LOCK = threading.Lock()
 
 
 def _programs_for(
-    model: TransformerLM | MlaMoeLM, grad_clip: float, mesh
+    model: TransformerLM | MlaMoeLM | GqaMoeLM, grad_clip: float, mesh
 ) -> tuple[TrialPrograms, bool]:
     """The structure's programs, and whether the process had them already."""
     key = (model, float(grad_clip), WEIGHT_DECAY, mesh)
@@ -321,7 +378,7 @@ def _programs_for(
 
 
 def train_lm(
-    model: TransformerLM | MlaMoeLM,
+    model: TransformerLM | MlaMoeLM | GqaMoeLM,
     data: np.ndarray,
     *,
     lr: float,
@@ -344,12 +401,15 @@ def train_lm(
         train, heldout = data[:-n_eval], data[-n_eval:]
 
         programs, reused = _programs_for(model, grad_clip, mesh)
+        attention, tile_counters = attention_plan(model, batch_size, data.shape[1])
         sp.set(
             programs="reused" if reused else "built",
             block=model.BLOCK,
-            attn_tiles=attn_tiles(model, data.shape[1]),
+            **attention,
             loss=loss_path(model, batch_size, data.shape[1], mesh),
         )
+        for name, tiles in tile_counters.items():
+            sp.add(name, tiles)
         state = programs.init(jax.random.PRNGKey(seed), data.shape[1])
         schedule = (
             jnp.float32(lr),
@@ -397,21 +457,31 @@ def train_lm(
 # -- the white-box trial function -------------------------------------------
 
 
-def _mla_moe_model(p, vocab: int, mesh) -> MlaMoeLM:
-    """The ``block: mla_moe`` model from a trial's parameters: every size of
-    ``MlaMoeSizes`` under its own name, the experts held as two integers."""
+def _expert_block_sizes(cls, block: str, p, mesh):
+    """An expert block's sizes (``MlaMoeSizes`` | ``GqaMoeSizes``) from a
+    trial's parameters: every field under its own name, a layout as a string
+    of 0 and 1, the experts held as two integers."""
     if mesh is not None and mesh.shape.get(SEQ_AXIS, 1) > 1:
         raise ValueError(
-            "transformer_trial: block 'mla_moe' cannot run on a mesh with a 'seq' axis: the "
-            "ring and all-to-all attention paths assume keys and values of one width"
+            f"transformer_trial: block {block!r} cannot run on a mesh with a 'seq' axis: the ring "
+            "and all-to-all attention paths assume keys and values of one width and one head "
+            "count, and have no window"
         )
     if float(p.get("dropout", 0.0)) > 0.0:
-        raise ValueError("transformer_trial: block 'mla_moe' has no dropout")
-    sizes = {
-        f.name: type(f.default)(p.get(f.name, f.default))
-        for f in dataclasses.fields(MlaMoeSizes)
-        if f.name != "experts_held"
-    }
+        raise ValueError(f"transformer_trial: block {block!r} has no dropout")
+    sizes = {}
+    for f in dataclasses.fields(cls):
+        if f.name == "experts_held":
+            continue
+        if f.name.endswith("_layout"):
+            raw = str(p.get(f.name, "".join(map(str, f.default))))
+            if not raw or set(raw) - set("01"):
+                raise ValueError(
+                    f"transformer_trial: {f.name} {raw!r} is not a string of 0 and 1, one a layer kind"
+                )
+            sizes[f.name] = tuple(int(c) for c in raw)
+        else:
+            sizes[f.name] = type(f.default)(p.get(f.name, f.default))
     held = (
         int(p.get("experts_held_first", 0)),
         int(p.get("experts_held", sizes["n_experts"])),
@@ -421,10 +491,33 @@ def _mla_moe_model(p, vocab: int, mesh) -> MlaMoeLM:
             f"transformer_trial: experts held {held} (first, count) lie outside the "
             f"{sizes['n_experts']} routed experts"
         )
+    return cls(experts_held=held, **sizes)
+
+
+def _mla_moe_model(p, vocab: int, mesh) -> MlaMoeLM:
+    """The ``block: mla_moe`` model from a trial's parameters."""
     return MlaMoeLM(
         vocab_size=vocab,
-        sizes=MlaMoeSizes(experts_held=held, **sizes),
+        sizes=_expert_block_sizes(MlaMoeSizes, MlaMoeLM.BLOCK, p, mesh),
         attn_fn=make_attention_fn(mesh),
+    )
+
+
+def _gqa_moe_model(p, vocab: int, mesh) -> GqaMoeLM:
+    """The ``block: gqa_moe`` model from a trial's parameters.  No ``seq``
+    axis, so each kind of layer runs the one-device attention (a ``data`` axis
+    shards its batch as it does every other operation's)."""
+    sizes = _expert_block_sizes(GqaMoeSizes, GqaMoeLM.BLOCK, p, mesh)
+    if sizes.n_heads % sizes.n_kv_heads:
+        raise ValueError(
+            f"transformer_trial: {sizes.n_heads} query heads are not a multiple of "
+            f"{sizes.n_kv_heads} key-value heads"
+        )
+    return GqaMoeLM(
+        vocab_size=vocab,
+        sizes=sizes,
+        attn_fn=make_attention_fn(),
+        window_attn_fn=make_attention_fn(window=sizes.window),
     )
 
 
@@ -450,8 +543,12 @@ def transformer_trial(ctx) -> None:
             )
         elif block == "mla_moe":
             model = _mla_moe_model(p, vocab, mesh)
+        elif block == "gqa_moe":
+            model = _gqa_moe_model(p, vocab, mesh)
         else:
-            raise ValueError(f"transformer_trial: block {block!r} is neither 'gpt2' nor 'mla_moe'")
+            raise ValueError(
+                f"transformer_trial: block {block!r} is neither 'gpt2', 'mla_moe' nor 'gqa_moe'"
+            )
         data = markov_dataset(
             vocab, int(p.get("n_seq", 512)), seq_len, seed=int(p.get("data_seed", 0))
         )

@@ -34,6 +34,14 @@ Tiles: ``block_q`` / ``block_k`` where the caller names them, else
 budget.  The row statistics (logsumexp, delta) cross HBM lane-dense,
 ``[batch, heads, 1, seq]``.
 
+Keys and values may have fewer heads than the queries (grouped-query
+attention: query head ``j`` reads key-value head ``j // group``); ``dk`` and
+``dv`` are then summed over a group's query heads inside the dkv kernel, in
+float32, over one more grid axis.  ``window``: a query sees the ``window``
+newest keys up to itself; tiles wholly outside the band are skipped by the
+loop bounds as tiles wholly above the diagonal are (``tile_visits`` counts
+what the loops walk against what holds a visible pair).
+
 On non-TPU backends (CPU tests, the 8-device virtual mesh) the kernels run
 in interpreter mode automatically.
 """
@@ -45,6 +53,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -136,50 +145,110 @@ def _block_sizes(q, k, v, block_q: int | None, block_k: int | None):
     return bq, bk
 
 
-def _pallas_call(kernel, **kwargs):
+def _pallas_call(kernel, *, grid, **kwargs):
+    """Batch, head and tile are parallel; a fourth grid axis (the query heads
+    of a key-value head, in the dkv kernel) accumulates into one output block."""
     return pl.pallas_call(
         kernel,
+        grid=grid,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",) * (len(grid) - 3),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         **kwargs,
     )
 
 
+def _group_size(q, k) -> int:
+    """Query heads a key-value head."""
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(
+            f"{q.shape[1]} query heads are not a multiple of {k.shape[1]} key-value heads"
+        )
+    return q.shape[1] // k.shape[1]
+
+
+def _check_window(window, causal) -> None:
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window {window!r} needs causal attention and at least one key")
+
+
 # ---------------------------------------------------------------------------
-# which tiles the causal mask leaves
+# which tiles the causal mask and the window leave
 # ---------------------------------------------------------------------------
 #
 # ``shift = seq_k - seq_q`` makes the causal mask bottom-right aligned (last
 # query row sees every key), matching ``reference_attention_with_lse`` for
-# seq_q != seq_k: key ``c`` is visible to query ``r`` when ``c <= r + shift``.
-# Tiles wholly above the diagonal are skipped by the loop bounds; every other
-# tile of a causal call is masked (a second, mask-free loop body for the tiles
-# wholly under the diagonal read slower on the chip at both benchmark shapes:
+# seq_q != seq_k: key ``c`` is visible to query ``r`` when ``c <= r + shift``
+# and, under a window, ``c > r + shift - window``.  Tiles wholly above the
+# diagonal or wholly below the window are skipped by the loop bounds; every
+# other tile of a causal call is masked (a second, mask-free loop body for the
+# tiles wholly inside read slower on the chip at both benchmark shapes:
 # PERF.md section 6, PR 32).
 
 
-def _live_k_tiles(qi, bq, bk, n_kb, shift, causal):
-    """k tiles ``[0, n)`` hold a key that q tile ``qi``'s last row sees."""
+def _k_tile_range(qi, bq, bk, n_kb, shift, causal, window):
+    """k tiles ``[first, end)`` hold a key that a row of q tile ``qi`` sees:
+    up to its last row's own key, from the oldest key its first row's window
+    reaches."""
     if not causal:
-        return n_kb
-    return jnp.minimum(pl.cdiv(jnp.maximum((qi + 1) * bq + shift, 0), bk), n_kb)
+        return 0, n_kb
+    end = jnp.minimum(pl.cdiv(jnp.maximum((qi + 1) * bq + shift, 0), bk), n_kb)
+    if window is None:
+        return 0, end
+    return jnp.maximum(qi * bq + shift - window + 1, 0) // bk, end
 
 
-def _first_live_q_tile(ki, bq, bk, n_qb, shift, causal):
-    """q tiles ``[first, n_qb)`` hold a row that sees k tile ``ki``'s first key."""
+def _q_tile_range(ki, bq, bk, n_qb, shift, causal, window):
+    """q tiles ``[first, end)`` hold a row that sees a key of k tile ``ki``:
+    from the row of its first key, to the last row whose window reaches its
+    last key."""
     if not causal:
-        return 0
-    return jnp.minimum(jnp.maximum(ki * bk - shift, 0) // bq, n_qb)
+        return 0, n_qb
+    first = jnp.minimum(jnp.maximum(ki * bk - shift, 0) // bq, n_qb)
+    if window is None:
+        return first, n_qb
+    rows = jnp.maximum((ki + 1) * bk - 1 - shift + window, 0)  # rows [0, rows) can
+    return first, jnp.minimum(pl.cdiv(rows, bq), n_qb)
 
 
-def _visible(shape, k0, q0):
-    """The causal mask of a [keys, queries] tile: keys from ``k0``, queries
-    from ``q0`` (the shift included)."""
+def _visible(shape, k0, q0, window):
+    """The mask of a [keys, queries] tile: keys from ``k0``, queries from
+    ``q0`` (the shift included)."""
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return k_pos <= q_pos
+    if window is None:
+        return k_pos <= q_pos
+    return (k_pos <= q_pos) & (k_pos > q_pos - window)
+
+
+@functools.lru_cache(maxsize=64)
+def tile_visits(
+    seq_q: int, seq_k: int, bq: int, bk: int, causal: bool = True, window: int | None = None
+) -> tuple[int, int]:
+    """For one batch row and query head: the (q tile, k tile) pairs the three
+    kernels' loops walk (forward and dq ``_k_tile_range`` of every q tile, dkv
+    ``_q_tile_range`` of every k tile: the kernels' own bounds, evaluated),
+    and three times the tiles of this size that hold a visible pair, the least
+    any walk can make."""
+    n_qb, n_kb, shift = seq_q // bq, seq_k // bk, seq_k - seq_q
+
+    def walked(tile_range, n_tiles, n_across):  # a bound may be one number for all tiles
+        first, end = tile_range(jnp.arange(n_tiles), bq, bk, n_across, shift, causal, window)
+        return int(jnp.sum(jnp.broadcast_to(jnp.maximum(end - first, 0), (n_tiles,))))
+
+    per_q_tile, per_k_tile = walked(_k_tile_range, n_qb, n_kb), walked(_q_tile_range, n_kb, n_qb)
+    # a tile holds a visible pair when its last row reaches its first key and
+    # its first row's window still reaches its last key
+    last_row = (np.arange(n_qb)[:, None] + 1) * bq - 1 + shift
+    first_row = np.arange(n_qb)[:, None] * bq + shift
+    first_key, last_key = np.arange(n_kb) * bk, (np.arange(n_kb) + 1) * bk - 1
+    holds = np.ones((n_qb, n_kb), bool)
+    if causal:
+        holds = first_key <= last_row
+        if window is not None:
+            holds &= last_key > first_row - window
+    return 2 * per_q_tile + per_k_tile, 3 * int(holds.sum())
 
 
 # ---------------------------------------------------------------------------
@@ -187,7 +256,7 @@ def _visible(shape, k0, q0):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_k, shift):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_k, shift, window):
     """One q tile against the k tiles it sees.  Scores are held transposed,
     [block_k, bq]: the running max and sum are then reductions along sublanes
     (elementwise across registers) and live in lane-dense [1, bq] rows, where
@@ -205,7 +274,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_
         # operands in the inputs' dtype, scores and everything after in f32
         s = sm_scale * jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
         if causal:
-            s = jnp.where(_visible(s.shape, start, qi * bq + shift), s, _MASK_VALUE)
+            s = jnp.where(_visible(s.shape, start, qi * bq + shift, window), s, _MASK_VALUE)
         m_new = jnp.maximum(m_acc, jnp.max(s, axis=0, keepdims=True))
         # a row that has seen no key yet has m_new == _MASK_VALUE and p == 1
         # on masked keys: wiped by alpha == 0 at its first visible key, or at
@@ -219,8 +288,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_
     o0 = jnp.zeros((d_v, bq), jnp.float32)
     m0 = jnp.full((1, bq), _MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((1, bq), jnp.float32)
-    n_live = _live_k_tiles(qi, bq, block_k, n_kb, shift, causal)
-    o, m, l = jax.lax.fori_loop(0, n_live, body, (o0, m0, l0))
+    first, end = _k_tile_range(qi, bq, block_k, n_kb, shift, causal, window)
+    o, m, l = jax.lax.fori_loop(first, end, body, (o0, m0, l0))
 
     # rows with every key masked: output 0, log-sum-exp the mask value
     dead = m == _MASK_VALUE
@@ -231,19 +300,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_
     lse_ref[0, 0, :, :] = jnp.where(dead, _MASK_VALUE, m + jnp.log(l_safe))
 
 
-def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret):
+def _kv_head(group: int):
+    """The key-value head of query head ``j`` (an index map's head entry)."""
+    return (lambda j: j) if group == 1 else (lambda j: j // group)
+
+
+def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret, window):
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
     bq, bk = _block_sizes(q, k, v, block_q, block_k)
+    kv = _kv_head(_group_size(q, k))
     o, lse = _pallas_call(
         functools.partial(
-            _fwd_kernel, sm_scale=sm_scale, causal=causal, block_k=bk, shift=sk - sq
+            _fwd_kernel, sm_scale=sm_scale, causal=causal, block_k=bk, shift=sk - sq, window=window
         ),
         grid=(b, h, sq // bq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda i, j, l: (i, j, l, 0)),
-            pl.BlockSpec((1, 1, sk, d), lambda i, j, l: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, sk, d_v), lambda i, j, l: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, sk, d), lambda i, j, l: (i, kv(j), 0, 0)),
+            pl.BlockSpec((1, 1, sk, d_v), lambda i, j, l: (i, kv(j), 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d_v), lambda i, j, l: (i, j, l, 0)),
@@ -263,7 +338,7 @@ def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dq_ref, *, sm_scale, causal, block_k, shift):
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dq_ref, *, sm_scale, causal, block_k, shift, window):
     """dq for one q tile; streams K/V tiles.  ``dmd`` = rowsum(dO*O) - d_lse,
     folding the logsumexp cotangent into the usual flash "delta" term.
     Transposed like the forward, [block_k, bq]: the row statistics broadcast
@@ -283,21 +358,26 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dq_ref, *, sm_scal
         s = sm_scale * jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
         e = s - lse
         if causal:
-            e = jnp.where(_visible(e.shape, start, qi * bq + shift), e, _MASK_VALUE)
+            e = jnp.where(_visible(e.shape, start, qi * bq + shift, window), e, _MASK_VALUE)
         p = jnp.exp(e)  # [block_k, bq]
         dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
         ds = (p * (dp - dmd)).astype(k.dtype)
         return dq_acc + jax.lax.dot_general(k, ds, _TN, preferred_element_type=jnp.float32)
 
-    n_live = _live_k_tiles(qi, bq, block_k, n_kb, shift, causal)
-    dq = jax.lax.fori_loop(0, n_live, body, jnp.zeros((d, bq), jnp.float32))
+    first, end = _k_tile_range(qi, bq, block_k, n_kb, shift, causal, window)
+    dq = jax.lax.fori_loop(first, end, body, jnp.zeros((d, bq), jnp.float32))
     dq_ref[0, 0, :, :] = (sm_scale * dq).T.astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dk_ref, dv_ref, *, sm_scale, causal, block_q, shift):
-    """dk, dv for one k tile; streams q tiles (with their dO/lse/delta).
-    Transposed too, [bk, block_q], which makes every product here a plain
-    ``a @ b`` or ``a @ b.T``."""
+def _dkv_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dk_ref, dv_ref, *sums,
+    sm_scale, causal, block_q, shift, window, group,
+):
+    """dk, dv for one k tile; streams the q tiles (with their dO/lse/delta) of
+    one query head.  Transposed too, [bk, block_q], which makes every product
+    here a plain ``a @ b`` or ``a @ b.T``.  With ``group`` query heads a
+    key-value head the fourth grid axis walks them, the float32 ``sums`` (two
+    VMEM scratch blocks) add their parts up, and the last writes."""
     bk, d = k_ref.shape[-2], k_ref.shape[-1]
     n_qb = q_ref.shape[-2] // block_q
     ki = pl.program_id(2)
@@ -314,7 +394,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dk_ref, dv_ref, *
         s = sm_scale * jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
         e = s - lse
         if causal:
-            e = jnp.where(_visible(e.shape, ki * bk, start + shift), e, _MASK_VALUE)
+            e = jnp.where(_visible(e.shape, ki * bk, start + shift, window), e, _MASK_VALUE)
         p = jnp.exp(e)  # [bk, block_q]
         dv_new = dv_acc + jnp.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
@@ -323,16 +403,40 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dk_ref, dv_ref, *
         return dk_new, dv_new
 
     zeros = (jnp.zeros((bk, d), jnp.float32), jnp.zeros((bk, v_ref.shape[-1]), jnp.float32))
-    first = _first_live_q_tile(ki, block_q, bk, n_qb, shift, causal)
-    dk, dv = jax.lax.fori_loop(first, n_qb, body, zeros)
-    dk_ref[0, 0, :, :] = (sm_scale * dk).astype(dk_ref.dtype)
-    dv_ref[0, 0, :, :] = dv.astype(dv_ref.dtype)
+    first, end = _q_tile_range(ki, block_q, bk, n_qb, shift, causal, window)
+    dk, dv = jax.lax.fori_loop(first, end, body, zeros)
+
+    def write(dk, dv):
+        dk_ref[0, 0, :, :] = (sm_scale * dk).astype(dk_ref.dtype)
+        dv_ref[0, 0, :, :] = dv.astype(dv_ref.dtype)
+
+    if group == 1:
+        write(dk, dv)
+        return
+    dk_sum, dv_sum = sums
+    member = pl.program_id(3)
+
+    @pl.when(member == 0)
+    def _():
+        dk_sum[...] = dk
+        dv_sum[...] = dv
+
+    @pl.when(member > 0)
+    def _():
+        dk_sum[...] += dk
+        dv_sum[...] += dv
+
+    @pl.when(member == group - 1)
+    def _():
+        write(dk_sum[...], dv_sum[...])
 
 
-def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, interpret):
+def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, interpret, window):
     b, h, sq, d = q.shape
-    sk, d_v = k.shape[2], v.shape[3]
+    h_kv, sk, d_v = k.shape[1], k.shape[2], v.shape[3]
     bq, bk = _block_sizes(q, k, v, block_q, block_k)
+    group = _group_size(q, k)
+    kv = _kv_head(group)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     dmd = delta - dlse.astype(jnp.float32)  # [b, h, sq]
     lse4 = lse[:, :, None, :]
@@ -340,13 +444,13 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
 
     dq = _pallas_call(
         functools.partial(
-            _dq_kernel, sm_scale=sm_scale, causal=causal, block_k=bk, shift=sk - sq
+            _dq_kernel, sm_scale=sm_scale, causal=causal, block_k=bk, shift=sk - sq, window=window
         ),
         grid=(b, h, sq // bq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda i, j, l: (i, j, l, 0)),
-            pl.BlockSpec((1, 1, sk, d), lambda i, j, l: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, sk, d_v), lambda i, j, l: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, sk, d), lambda i, j, l: (i, kv(j), 0, 0)),
+            pl.BlockSpec((1, 1, sk, d_v), lambda i, j, l: (i, kv(j), 0, 0)),
             pl.BlockSpec((1, 1, bq, d_v), lambda i, j, l: (i, j, l, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda i, j, l: (i, j, 0, l)),
             pl.BlockSpec((1, 1, 1, bq), lambda i, j, l: (i, j, 0, l)),
@@ -356,27 +460,42 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
         interpret=interpret,
     )(q, k, v, do, lse4, dmd4)
 
+    # one grid point a (k tile, query head): with a group, the heads of a
+    # key-value head on a fourth axis, innermost, so that its dk and dv blocks
+    # stay in VMEM while the group's parts are summed into them
+    if group == 1:
+        grid = (b, h, sk // bk)
+        q_head = lambda i, j, l: (i, j, 0, 0)  # noqa: E731
+        kv_tile = lambda i, j, l: (i, j, l, 0)  # noqa: E731
+        scratch = []
+    else:
+        grid = (b, h_kv, sk // bk, group)
+        q_head = lambda i, j, l, m: (i, j * group + m, 0, 0)  # noqa: E731
+        kv_tile = lambda i, j, l, m: (i, j, l, 0)  # noqa: E731
+        scratch = [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, d_v), jnp.float32)]
     dk, dv = _pallas_call(
         functools.partial(
-            _dkv_kernel, sm_scale=sm_scale, causal=causal, block_q=bq, shift=sk - sq
+            _dkv_kernel, sm_scale=sm_scale, causal=causal, block_q=bq, shift=sk - sq,
+            window=window, group=group,
         ),
-        grid=(b, h, sk // bk),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, sq, d), lambda i, j, l: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda i, j, l: (i, j, l, 0)),
-            pl.BlockSpec((1, 1, bk, d_v), lambda i, j, l: (i, j, l, 0)),
-            pl.BlockSpec((1, 1, sq, d_v), lambda i, j, l: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, sq), lambda i, j, l: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, sq), lambda i, j, l: (i, j, 0, 0)),
+            pl.BlockSpec((1, 1, sq, d), q_head),
+            pl.BlockSpec((1, 1, bk, d), kv_tile),
+            pl.BlockSpec((1, 1, bk, d_v), kv_tile),
+            pl.BlockSpec((1, 1, sq, d_v), q_head),
+            pl.BlockSpec((1, 1, 1, sq), q_head),
+            pl.BlockSpec((1, 1, 1, sq), q_head),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bk, d), lambda i, j, l: (i, j, l, 0)),
-            pl.BlockSpec((1, 1, bk, d_v), lambda i, j, l: (i, j, l, 0)),
+            pl.BlockSpec((1, 1, bk, d), kv_tile),
+            pl.BlockSpec((1, 1, bk, d_v), kv_tile),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        scratch_shapes=scratch,
         interpret=interpret,
     )(q, k, v, do, lse4, dmd4)
     return dq, dk, dv
@@ -387,7 +506,7 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention_with_lse(
     q: jax.Array,
     k: jax.Array,
@@ -397,30 +516,38 @@ def flash_attention_with_lse(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
+    window: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused attention over [batch, heads, seq, head_dim] inputs.  The values
     (and so the output) may have another width than the queries and keys, as
     in latent attention (192-wide keys, 128-wide values); the scale comes from
-    the query width unless given.  ``block_q`` / ``block_k``: the tiles, from
-    ``plan_tiles`` where ``None``.
+    the query width unless given.  Keys and values may have fewer heads than
+    the queries, a whole number of query heads to each (query head ``j`` reads
+    key-value head ``j // group``).  ``window``: query ``t`` sees the keys
+    ``t - window < t' <= t`` (causal only; ``None``: the whole prefix).
+    ``block_q`` / ``block_k``: the tiles, from ``plan_tiles`` where ``None``.
 
     Returns ``(output, logsumexp)``; the logsumexp output makes this the
     mergeable building block for ring attention.  Rows with every key masked
     produce output 0 and logsumexp ≈ -1e30 (an exact no-op when merged).
     """
+    _check_window(window, causal)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
     itp = _interpret_default() if interpret is None else interpret
-    return _fwd(q, k, v, sm_scale=scale, causal=causal, block_q=block_q, block_k=block_k, interpret=itp)
+    return _fwd(
+        q, k, v, sm_scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=itp, window=window,
+    )
 
 
-def _vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window):
     o, lse = flash_attention_with_lse(
-        q, k, v, causal, sm_scale, block_q, block_k, interpret
+        q, k, v, causal, sm_scale, block_q, block_k, interpret, window
     )
     return (o, lse), (q, k, v, o, lse)
 
 
-def _vjp_bwd(causal, sm_scale, block_q, block_k, interpret, res, cts):
+def _vjp_bwd(causal, sm_scale, block_q, block_k, interpret, window, res, cts):
     q, k, v, o, lse = res
     do, dlse = cts
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
@@ -428,7 +555,7 @@ def _vjp_bwd(causal, sm_scale, block_q, block_k, interpret, res, cts):
     return _bwd(
         q, k, v, o, lse, do, dlse,
         sm_scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=itp,
+        interpret=itp, window=window,
     )
 
 
@@ -445,10 +572,11 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Standard entry point: fused attention output only."""
     o, _ = flash_attention_with_lse(
-        q, k, v, causal, sm_scale, block_q, block_k, interpret
+        q, k, v, causal, sm_scale, block_q, block_k, interpret, window
     )
     return o
 
@@ -460,10 +588,15 @@ def flash_attention(
 
 def reference_attention_with_lse(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
-    sm_scale: float | None = None,
+    sm_scale: float | None = None, window: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """O(S^2)-memory jnp attention returning (output, logsumexp)."""
+    """O(S^2)-memory jnp attention returning (output, logsumexp); the same
+    arguments as the kernel (fewer key-value heads are repeated)."""
+    _check_window(window, causal)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    group = _group_size(q, k)
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
     ) * scale
@@ -471,6 +604,8 @@ def reference_attention_with_lse(
     if causal:
         sq, sk = q.shape[2], k.shape[2]
         mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq - window)
         s = jnp.where(mask, s, _MASK_VALUE)
         if sq > sk:
             visible = mask.any(-1)  # rows before the diagonal see no key
@@ -487,6 +622,6 @@ def reference_attention_with_lse(
     return o.astype(q.dtype), lse
 
 
-def reference_attention(q, k, v, *, causal: bool = True, sm_scale=None) -> jax.Array:
-    o, _ = reference_attention_with_lse(q, k, v, causal, sm_scale)
+def reference_attention(q, k, v, *, causal: bool = True, sm_scale=None, window=None) -> jax.Array:
+    o, _ = reference_attention_with_lse(q, k, v, causal, sm_scale, window)
     return o

@@ -61,22 +61,24 @@ def test_mixed_op_kernel_compiles(one_chip, shape, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _attention_kernels(one_chip, q_shape, v_shape, grad):
+def _attention_kernels(one_chip, q_shape, v_shape, grad, window=None):
     """The ``tpu_custom_call`` lines of the attention program (forward, or
     forward + dq + dkv under ``jax.grad``) at the tiles the kernel plans,
-    compiled for the described chip."""
+    compiled for the described chip.  Keys have the values' heads and the
+    queries' width."""
     from katib_tpu.ops.flash_attention import flash_attention
 
     def fwd(q, k, v):
-        return flash_attention(q, k, v, interpret=False)
+        return flash_attention(q, k, v, interpret=False, window=window)
 
     def loss(q, k, v):
         return jnp.sum(fwd(q, k, v).astype(jnp.float32))
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    qk = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one_chip)
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct(v_shape[:3] + q_shape[3:], jnp.bfloat16, sharding=one_chip)
     v = jax.ShapeDtypeStruct(v_shape, jnp.bfloat16, sharding=one_chip)
-    text = jax.jit(fn).lower(qk, qk, v).compile().as_text()
+    text = jax.jit(fn).lower(q, k, v).compile().as_text()
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     # the benchmark's marks find a kernel by its first operand: q, bfloat16
     # (families/mla_moe.py FLASH_KERNEL_MARK); int32 first is how it finds the
@@ -86,21 +88,28 @@ def _attention_kernels(one_chip, q_shape, v_shape, grad):
     return kernels
 
 
-# the shapes that run the kernel on the chip: the benchmark's two cells
-# ([batch, heads, positions, width] of q and k, then of v) and a longer context
+# the shapes that run the kernel on the chip: the benchmark's cells
+# ([batch, heads, positions, width] of q, then of v, then the window) and a
+# longer context
 ATTENTION_SHAPES = {
-    "gpt2-small": ((8, 12, 1024, 64), (8, 12, 1024, 64)),
-    "kanana-2-30b-a3b-ep8": ((2, 32, 4096, 192), (2, 32, 4096, 128)),
-    "long-context": ((4, 8, 4096, 64), (4, 8, 4096, 64)),
+    "gpt2-small": ((8, 12, 1024, 64), (8, 12, 1024, 64), None),
+    "kanana-2-30b-a3b-ep8": ((2, 32, 4096, 192), (2, 32, 4096, 128), None),
+    "long-context": ((4, 8, 4096, 64), (4, 8, 4096, 64), None),
+    # 28 query heads over 4 key-value heads at 16384 positions: the layer that
+    # sees the whole prefix, and the three that see 4096 keys
+    "smallthinker-21b-a3b-ep8-full": ((1, 28, 16384, 128), (1, 4, 16384, 128), None),
+    "smallthinker-21b-a3b-ep8-window": ((1, 28, 16384, 128), (1, 4, 16384, 128), 4096),
 }
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "grad"])
 @pytest.mark.parametrize("name", sorted(ATTENTION_SHAPES))
 def test_flash_attention_compiles(one_chip, name, grad):
-    """At 4096 positions a whole K and V of one head and, in the dkv kernel,
-    a whole Q, dO and their row statistics sit in VMEM beside the tiles."""
-    kernels = _attention_kernels(one_chip, *ATTENTION_SHAPES[name], grad)
+    """A whole K and V of one head and, in the dkv kernel, a whole Q, dO and
+    their row statistics sit in VMEM beside the tiles: at 16384 positions of
+    width 128 too."""
+    q_shape, v_shape, window = ATTENTION_SHAPES[name]
+    kernels = _attention_kernels(one_chip, q_shape, v_shape, grad, window)
     assert len(kernels) == (3 if grad else 1)  # forward, dq, dkv
 
 
