@@ -50,7 +50,7 @@ import optax
 
 from katib_tpu.models.gqa_moe import GqaMoeLM, GqaMoeSizes
 from katib_tpu.models.lm_head import HeadInputs, LMHead, chunk_rows, head_loss, next_token_loss
-from katib_tpu.models.mla_moe import ROUTING, MlaMoeLM, MlaMoeSizes
+from katib_tpu.models.mla_moe import ROUTING, MlaMoeLM, MlaMoeSizes, expert_buffer
 from katib_tpu.ops.flash_attention import (
     flash_attention,
     plan_tiles,
@@ -410,6 +410,9 @@ def train_lm(
         )
         for name, tiles in tile_counters.items():
             sp.add(name, tiles)
+        if hasattr(model, "step_counters"):
+            # a model with expert layers: the lengths their sorted buffer may take
+            sp.set(expert_buffer=expert_buffer(model.sizes, batch_size * data.shape[1]))
         state = programs.init(jax.random.PRNGKey(seed), data.shape[1])
         schedule = (
             jnp.float32(lr),

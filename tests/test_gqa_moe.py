@@ -21,9 +21,10 @@ import numpy as np
 import optax
 import pytest
 
+import full_buffer_experts as planted
 from katib_tpu.models import transformer
 from katib_tpu.models.gqa_moe import GqaMoeLM, GqaMoeSizes
-from katib_tpu.models.mla_moe import ROUTING, ExpertLayer, rotary
+from katib_tpu.models.mla_moe import ROUTING, ExpertLayer, buffer_rungs, rotary
 from katib_tpu.ops import flash_attention as fa
 from katib_tpu.utils import tracing
 
@@ -453,6 +454,21 @@ class TestSharedExpertLayer:
         for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
             np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("routing", sorted(planted.ROUTINGS))
+    def test_every_rung_computes_what_the_full_buffer_does(self, routing):
+        """The logits handed in, planted (softmax over the chosen, ReLU gate,
+        no shared expert): on each rung, a row short of the short rung's
+        length and on it, every assignment held, all on one expert; the
+        logits' gradient among those compared."""
+        sizes = dataclasses.replace(LAYER_SIZES, n_experts=32, experts_held=planted.HELD)
+        assert buffer_rungs(planted.TOKENS, sizes) == planted.RUNGS
+        h = jax.random.normal(jax.random.PRNGKey(12), (2, planted.TOKENS // 2, sizes.d_model), jnp.float32)
+        logits = jnp.asarray(planted.planted_logits(routing)).reshape(2, planted.TOKENS // 2, 32)
+        planted.assert_matches_full_buffer(
+            sizes, planted.layer_params(sizes), h, logits,
+            rows=planted.ROUTINGS[routing][1], held=planted.held_assignments(routing),
+        )
+
     def test_weights_are_a_softmax_over_the_chosen_logits(self, stream):
         """With every expert's down projection the identity-like sum of its
         hidden units replaced by ones, the layer's output reads the weights:
@@ -544,6 +560,37 @@ class TestNormalPath:
             assert 0 <= args["moe_assignments_held"] <= args["moe_assignments_total"]
             assert args["moe_expert_tokens_max"] >= args["moe_expert_tokens_mean"]
         assert all(np.isfinite(r["eval_loss"]) for ctx in ctxs for r in ctx.reports)
+
+    @pytest.mark.parametrize("held", [1, 4], ids=["ladder", "one-rung"])
+    def test_a_data_mesh_chooses_the_rung_every_device_alike(self, tmp_path, held):
+        """Over a ``data`` axis the step is one GSPMD program: the rows held
+        are a global count, so every device takes the same branch, and the
+        losses are those of one device."""
+        from katib_tpu.parallel.mesh import DATA_AXIS, make_mesh
+
+        trial = {**TRIAL, "seq_len": 64, "experts_held": held, "n_layers": 2, "steps": 2}
+        mesh = make_mesh({DATA_AXIS: 2}, devices=jax.devices()[:2])
+        data = transformer.markov_dataset(64, 40, 64, seed=5)
+        series = {}
+        for name, m in (("one", None), ("mesh", mesh)):
+            model = transformer._gqa_moe_model(dict(trial), 64, m).clone(dtype=jnp.float32)
+            path = str(tmp_path / f"{name}.jsonl")
+            tracer, reported = tracing.Tracer(path), []
+            with tracing.use_tracer(tracer):
+                transformer.train_lm(
+                    model, data, lr=1e-3, steps=2, batch_size=4, mesh=m, report_every=1,
+                    report=lambda step, loss, eval_loss: reported.append((loss, eval_loss)),
+                )
+            tracer.close()
+            records = list(tracing.read_journal(path))
+            series[name] = (reported, [r["args"] for r in records if r["name"] == "trial.eval"])
+            (init,) = [r["args"] for r in records if r["name"] == "trial.init"]
+            assert init["expert_buffer"] == ("128 / 512" if held == 1 else "512")
+        np.testing.assert_allclose(series["mesh"][0], series["one"][0], rtol=2e-5)
+        for got, want in zip(series["mesh"][1], series["one"][1]):
+            assert got["moe_tokens_dropped"] == 0
+            assert got["moe_buffer_rows"] == want["moe_buffer_rows"]
+            assert got["moe_assignments_held"] == want["moe_assignments_held"]
 
     def test_example_runs_through_the_orchestrator(self, tmp_path):
         """Orchestrator.run -> trial runner -> transformer_trial -> train_lm."""
@@ -726,7 +773,10 @@ class TestTileOverrunReader:
             "layer": "kernel", "moves": "trials_per_hour", "workloads": [cell],
         }
         listed = {m["name"] for m in bench["per_layer"] if cell in m.get("workloads", ())}
-        assert listed == {"attn_tile_overrun", "moe_load_imbalance", "moe_tokens_dropped", "expert_product_roofline"}
+        assert listed == {
+            "attn_tile_overrun", "moe_load_imbalance", "moe_tokens_dropped", "expert_product_roofline",
+            "moe_buffer_share",  # PR 36
+        }
         (row,) = [w for w in bench["workloads"] if w["name"] == cell]
         assert (row["config"], row["traffic"], row["chips"]) == ("smallthinker-21b-a3b-ep8", "lr4low-steps12", 1)
         assert os.path.exists(os.path.join(REPO, "benchmark", "limits", f"{cell}.json"))

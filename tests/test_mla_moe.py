@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import importlib.util
+import json
 import os
 
 import jax
@@ -18,8 +19,9 @@ import numpy as np
 import optax
 import pytest
 
-from katib_tpu.models import transformer
-from katib_tpu.models.mla_moe import ROUTING, ExpertLayer, MlaMoeLM, MlaMoeSizes, SwiGLU
+import full_buffer_experts as planted
+from katib_tpu.models import mla_moe, transformer
+from katib_tpu.models.mla_moe import ROUTING, ExpertLayer, MlaMoeLM, MlaMoeSizes, SwiGLU, buffer_rungs
 from katib_tpu.ops.flash_attention import (
     flash_attention,
     reference_attention,
@@ -203,6 +205,23 @@ def _share(weights, first: int, count: int, h):
     return out, shared, sown[ROUTING]
 
 
+# the ladder's tests: experts 4-7 of 32 held, 384 tokens, rungs of 384 and 1152 rows
+LADDER_SIZES = MlaMoeSizes(
+    d_model=64, n_experts=32, experts_per_token=3, expert_width=32, n_shared_experts=2,
+    routed_scaling=2.448, experts_held=planted.HELD,
+)
+
+
+@pytest.fixture()
+def fresh_traces():
+    """The layer's arithmetic is one jitted function, traced once for equal
+    shapes: a test that replaces what it calls must not find a trace made
+    before, nor leave its own behind."""
+    mla_moe._experts.clear_cache()
+    yield
+    mla_moe._experts.clear_cache()
+
+
 class TestExpertLayer:
     @pytest.fixture()
     def stream(self):
@@ -258,12 +277,17 @@ class TestExpertLayer:
         assert counters["moe_expert_tokens_max"] == 48
         assert counters["moe_expert_tokens_mean"] == pytest.approx(tokens.sum() / 4)
 
-    def test_rows_past_the_last_group_never_reach_a_sum(self, family, stream, monkeypatch):
+    @pytest.mark.parametrize(
+        "first,count,rows", [(8, 4, 128), (4, 8, 144)], ids=["short-rung", "whole-buffer"]
+    )
+    def test_rows_past_the_last_group_never_reach_a_sum(self, family, stream, monkeypatch, fresh_traces, first, count, rows):
         """On the TPU the grouped product's kernels leave the rows that belong
         to no group unwritten (stale memory), in the forward result and in
         the gradient of the rows; XLA's CPU lowering writes zeros there, so
         the fault has to be planted: with NaN in those rows, the share's
-        result and gradients still match the reference."""
+        result and gradients still match the reference: on a rung shorter
+        than the ``T x k`` = 144 assignments, and where a share of half the
+        experts has the one rung of all 144."""
         real = jax.lax.ragged_dot
 
         def stale_rows(x, group_sizes):
@@ -289,25 +313,27 @@ class TestExpertLayer:
         monkeypatch.setattr(jax.lax, "ragged_dot", lambda lhs, rhs, group_sizes, **kw: kernel_like(lhs, rhs, group_sizes))
 
         weights = _layer_weights(jax.random.PRNGKey(9))
-        reference = {**weights, **{k: weights[k][8:12] for k in ("experts_gate", "experts_up", "experts_down")}}
+        held = slice(first, first + count)
+        reference = {**weights, **{k: weights[k][held] for k in ("experts_gate", "experts_up", "experts_down")}}
         f = family._layer_functions(
-            family.shape_of({**LAYER_CONFIG, "experts_held_first": 8, "n_routed_experts": 4}),
+            family.shape_of({**LAYER_CONFIG, "experts_held_first": first, "n_routed_experts": count}),
             "f32", None, *stream.shape[:2],
         )
 
         def program(x, w):
-            out, _, sown = _share(w, 8, 4, f["rms_norm"](x, w["norm2"]))
+            out, _, sown = _share(w, first, count, f["rms_norm"](x, w["norm2"]))
             return jnp.sum(jnp.square(x + out)), sown
 
         (got, sown), got_grads = jax.value_and_grad(program, argnums=(0, 1), has_aux=True)(stream, weights)
-        assert sown["expert_tokens"][0].sum() < 48 * 3  # there ARE rows past the last group
+        assert sown["buffer_rows"][0] == rows
+        assert sown["expert_tokens"][0].sum() < rows  # there ARE rows past the last group
         want, want_grads = jax.value_and_grad(
             lambda x, w: jnp.sum(jnp.square(f["moe"](x, w))), argnums=(0, 1)
         )(stream, reference)
         assert float(got) == pytest.approx(float(want), rel=1e-5)
         np.testing.assert_allclose(got_grads[0], want_grads[0], rtol=2e-4, atol=2e-4)
         for name in ("experts_gate", "experts_up", "experts_down"):
-            np.testing.assert_allclose(got_grads[1][name][8:12], want_grads[1][name], rtol=2e-4, atol=2e-4)
+            np.testing.assert_allclose(got_grads[1][name][held], want_grads[1][name], rtol=2e-4, atol=2e-4)
         np.testing.assert_allclose(got_grads[1]["router"], want_grads[1]["router"], rtol=2e-4, atol=2e-4)
 
     def test_gradients_of_the_share_match_the_reference(self, family, stream):
@@ -322,6 +348,60 @@ class TestExpertLayer:
         want = jax.grad(lambda x, w: jnp.sum(jnp.square(f["moe"](x, w))), argnums=(0, 1))(stream, weights)
         for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
             np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-4)
+
+    # -- the sorted buffer follows the rows held
+
+    @pytest.mark.parametrize("routing", sorted(planted.ROUTINGS))
+    def test_every_rung_computes_what_the_full_buffer_does(self, routing):
+        """Routings planted through the router's matrix (sigmoid scores): on
+        each rung, a row short of the short rung's length and on it, every
+        assignment held, all on one expert."""
+        assert buffer_rungs(planted.TOKENS, LADDER_SIZES) == planted.RUNGS
+        h, router = planted.planted_stream(routing, LADDER_SIZES.d_model)
+        sown = planted.assert_matches_full_buffer(
+            LADDER_SIZES, planted.layer_params(LADDER_SIZES, router), h, None,
+            rows=planted.ROUTINGS[routing][1], held=planted.held_assignments(routing),
+        )
+        if routing == "all-on-one-held-expert":
+            assert sown["expert_tokens"][0].tolist() == [planted.TOKENS - 1, 0, 0, 0]
+
+    def test_a_rung_too_short_is_counted_as_dropped(self, monkeypatch, fresh_traces):
+        """Were the choice of rung wrong, the counter says so: the products
+        are given only the rows that lie inside the rung that ran."""
+        monkeypatch.setattr(mla_moe, "buffer_rungs", lambda tokens, sizes: (128, 512))
+        h, router = planted.planted_stream("most-assignments", LADDER_SIZES.d_model)
+        layer = ExpertLayer(LADDER_SIZES, jnp.float32)
+        _, sown = layer.apply({"params": planted.layer_params(LADDER_SIZES, router)}, h, mutable=[ROUTING])
+        counters = MlaMoeLM.step_counters(sown[ROUTING])
+        assert counters["moe_buffer_rows"] == 512
+        assert counters["moe_tokens_dropped"] == planted.held_assignments("most-assignments") - 512 > 0
+
+    @pytest.mark.parametrize("held,branches", [((0, 32), False), (planted.HELD, True)], ids=["uncut", "share"])
+    def test_a_layer_that_holds_every_expert_has_no_branch(self, held, branches):
+        """All experts held: every row is held, one rung, the program without
+        a choice, forward and backward."""
+        sizes = dataclasses.replace(LADDER_SIZES, experts_held=held)
+        h, router = planted.planted_stream("short-rung", sizes.d_model)
+        params = planted.layer_params(sizes, router)
+        layer = ExpertLayer(sizes, jnp.float32)
+        loss = lambda h, p: jnp.sum(layer.apply({"params": p}, h))  # noqa: E731
+        text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(h, params))
+        assert ("cond[" in text) == branches
+        assert len(buffer_rungs(planted.TOKENS, sizes)) == (2 if branches else 1)
+
+    @pytest.mark.parametrize(
+        "tokens,sizes,rungs",
+        [
+            (8192, dict(n_experts=128, experts_per_token=6, experts_held=(0, 16)), (12288, 49152)),
+            (16384, dict(n_experts=64, experts_per_token=6, experts_held=(0, 8)), (24576, 98304)),
+            (48, dict(n_experts=16, experts_per_token=3, experts_held=(4, 4)), (128, 144)),
+            (48, dict(n_experts=16, experts_per_token=3, experts_held=(0, 8)), (144,)),
+            (100, dict(n_experts=64, experts_per_token=2, experts_held=(3, 5)), (128, 200)),
+        ],
+        ids=["kanana2-cell", "smallthinker-cell", "quarter", "half", "rounded-up"],
+    )
+    def test_rungs_come_from_the_shapes(self, tokens, sizes, rungs):
+        assert buffer_rungs(tokens, MlaMoeSizes(**sizes)) == rungs
 
 
 # ---------------------------------------------------------------------------
@@ -419,6 +499,9 @@ class TestNormalPath:
             assert args["moe_assignments_total"] == 4 * 32 * 2  # one expert layer
             assert 0 <= args["moe_assignments_held"] <= args["moe_assignments_total"]
             assert args["moe_expert_tokens_max"] >= args["moe_expert_tokens_mean"]
+            # 4 of 8 experts held: half the assignments expected, so one rung
+            assert args["moe_buffer_rows"] == args["moe_assignments_total"]
+        assert [a["expert_buffer"] for a in inits] == ["256", "256"]
         assert all(np.isfinite(r["eval_loss"]) for ctx in ctxs for r in ctx.reports)
 
     def test_example_runs_through_the_orchestrator(self, tmp_path):
@@ -446,7 +529,28 @@ class TestNormalPath:
         tracer.close()
         records = {r["name"]: r.get("args", {}) for r in tracing.read_journal(path)}
         assert records["trial.init"]["block"] == "gpt2"
+        assert "expert_buffer" not in records["trial.init"]
         assert not any(k.startswith("moe_") for k in records["trial.eval"])
+
+    def test_a_trial_of_a_small_share_runs_on_a_short_rung(self, tmp_path):
+        """1 of 8 experts held, 2 a token: the ladder is on ``trial.init``,
+        the rung that ran and the rows dropped on every ``trial.eval``."""
+        path = str(tmp_path / "trace.jsonl")
+        tracer = tracing.Tracer(path)
+        params = {**TRIAL, "seq_len": 128, "batch_size": 4, "experts_held": 1, "steps": 2}
+        with tracing.use_tracer(tracer):
+            transformer.transformer_trial(_Ctx(params))
+        tracer.close()
+        records = list(tracing.read_journal(path))
+        (init,) = [r["args"] for r in records if r["name"] == "trial.init"]
+        assert init["expert_buffer"] == "256 / 1024"
+        evals = [r["args"] for r in records if r["name"] == "trial.eval"]
+        assert len(evals) == 2
+        for args in evals:
+            assert args["moe_tokens_dropped"] == 0
+            assert args["moe_assignments_total"] == 1024
+            assert args["moe_buffer_rows"] in (256, 1024)
+            assert args["moe_assignments_held"] <= args["moe_buffer_rows"]
 
     @pytest.mark.parametrize(
         "bad,match",
@@ -468,3 +572,56 @@ class TestNormalPath:
 
         with pytest.raises(ValueError, match="'seq' axis"):
             transformer._mla_moe_model(dict(TRIAL), 64, Mesh())
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's reader of the buffer's counter
+# ---------------------------------------------------------------------------
+
+
+class TestBufferShareReader:
+    @pytest.fixture(scope="class")
+    def read(self):
+        path = os.path.join(REPO, "benchmark", "layer_metrics", "moe_buffer_share.py")
+        spec = importlib.util.spec_from_file_location("moe_buffer_share", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod.read
+
+    @staticmethod
+    def _ctx(*spans):
+        class Slice:
+            t0, t1 = 10.0, 20.0
+
+        return {"slice": Slice, "spans": list(spans)}
+
+    @staticmethod
+    def _eval(t0, **args):
+        return {"name": "trial.eval", "t0": t0, "t1": t0 + 0.1, "args": args}
+
+    def test_averages_the_reports_inside_the_slice(self, read):
+        ctx = self._ctx(
+            self._eval(5.0, moe_buffer_rows=400, moe_assignments_total=400),  # the warm-up trial
+            self._eval(11.0, moe_buffer_rows=100, moe_assignments_total=400),
+            self._eval(15.0, moe_buffer_rows=200, moe_assignments_total=400),
+            self._eval(19.95, moe_buffer_rows=400, moe_assignments_total=400),  # ends past the slice
+            {"name": "trial.init", "t0": 12.0, "t1": 12.1, "args": {"moe_buffer_rows": 400, "moe_assignments_total": 400}},
+        )
+        assert read(ctx) == pytest.approx(0.375)
+
+    @pytest.mark.parametrize(
+        "args",
+        [dict(moe_assignments_total=400, moe_tokens_dropped=0), dict(moe_buffer_rows=0, moe_assignments_total=0), dict(loss=1.0)],
+        ids=["the-parent", "no-assignments", "no-experts"],
+    )
+    def test_none_where_the_program_has_no_such_counter(self, read, args):
+        assert read(self._ctx(self._eval(11.0, **args))) is None
+
+    def test_the_entry_names_both_expert_cells(self):
+        with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+            bench = json.load(f)
+        assert bench["per_layer"][-1] == {
+            "name": "moe_buffer_share", "unit": "ratio", "better": "lower", "source": "program_counter",
+            "layer": "experts", "moves": "trials_per_hour",
+            "workloads": ["kanana2-ep8-lr4low-steps12", "smallthinker-ep8-lr4low-steps12"],
+        }
