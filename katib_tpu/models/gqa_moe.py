@@ -39,7 +39,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from katib_tpu.models.lm_head import LMHead
+from katib_tpu.models.lm_head import LMHead, lm_loss, next_token_objective
 from katib_tpu.models.mla_moe import ExpertLayer, RMSNorm, rotary, routing_counters
 
 
@@ -168,4 +168,6 @@ class GqaMoeLM(nn.Module):
         x = RMSNorm(z.eps, self.dtype, name="norm")(x)
         return LMHead(self.vocab_size, use_bias=False, name="head")(x, multiply_head)
 
+    training_loss = staticmethod(next_token_objective)
+    reported_loss = staticmethod(lm_loss)
     step_counters = staticmethod(routing_counters)

@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from katib_tpu.models.lm_head import LMHead
+from katib_tpu.models.lm_head import LMHead, lm_loss, next_token_objective
 
 #: the collection an expert layer sows a step's routing counts into
 ROUTING = "routing"
@@ -508,4 +508,6 @@ class MlaMoeLM(nn.Module):
         x = RMSNorm(z.eps, self.dtype, name="norm")(x)
         return LMHead(self.vocab_size, use_bias=False, name="head")(x, multiply_head)
 
+    training_loss = staticmethod(next_token_objective)
+    reported_loss = staticmethod(lm_loss)
     step_counters = staticmethod(routing_counters)

@@ -11,7 +11,8 @@ training on a sharded mesh with the same trial API as the CNN workloads.
 
 Tunable parameters understood by ``transformer_trial``: lr, d_model,
 n_heads, n_layers, seq_len, vocab_size, batch_size, n_seq, data_seed, steps,
-warmup_frac, attn(ring|ulysses), dropout, and block(gpt2|mla_moe|gqa_moe).
+warmup_frac, attn(ring|ulysses), dropout, and
+block(gpt2|mla_moe|gqa_moe|looped).
 With ``block: mla_moe`` (latent attention and sparse experts,
 ``katib_tpu.models.mla_moe``) also: first_dense_layers, qk_nope_dim,
 qk_rope_dim, v_head_dim, kv_lora_rank, dense_width, expert_width, n_experts,
@@ -23,8 +24,19 @@ attention in a period of layer kinds, experts routed from the layer's input,
 window_layout and rope_layout (a period of layer kinds as a string of 0 and
 1, one character a kind: ``"0111"``; 1 sees ``window`` keys / carries rotary
 positions), expert_width, n_experts, experts_per_token, rope_theta, eps,
-experts_held_first, experts_held.  Both expert blocks refuse dropout and a
-``seq`` mesh axis.
+experts_held_first, experts_held.  With ``block: looped`` (a stack of layers
+run ``ut_steps`` times over the same weights, an exit after every pass, a
+learned exit gate, ``katib_tpu.models.looped``) also: head_dim, mlp_width,
+ut_steps, exit_beta, rope_theta, eps.  Every block but ``gpt2`` refuses
+dropout and a ``seq`` mesh axis.
+
+**The loss belongs to the model.**  A model answers ``training_loss(outputs,
+tokens)`` with the loss a step differentiates and what a report may read of
+it (a tree; ``step_counters`` turns it into a span's attributes), and
+``reported_loss(outputs, tokens)`` with the ``eval_loss`` a trial reports;
+``outputs`` is what its ``apply`` returns.  For the first three blocks both
+are the next-token cross entropy (``lm_loss``); ``block: looped`` trains on
+its expected-exit objective and reports its last exit's cross entropy.
 
 The training task is a synthetic first-order Markov language-modelling
 problem: next-token structure is learnable (entropy well below uniform) and
@@ -49,7 +61,8 @@ import numpy as np
 import optax
 
 from katib_tpu.models.gqa_moe import GqaMoeLM, GqaMoeSizes
-from katib_tpu.models.lm_head import HeadInputs, LMHead, chunk_rows, head_loss, next_token_loss
+from katib_tpu.models.lm_head import LMHead, chunk_rows, lm_loss
+from katib_tpu.models.looped import LoopedLM, LoopedSizes
 from katib_tpu.models.mla_moe import ROUTING, MlaMoeLM, MlaMoeSizes, expert_buffer
 from katib_tpu.ops.flash_attention import (
     flash_attention,
@@ -139,6 +152,12 @@ class TransformerLM(nn.Module):
         x = nn.LayerNorm(dtype=self.dtype)(x)
         return LMHead(self.vocab_size, name="Dense_0")(x, multiply_head)
 
+    def training_loss(self, outputs, tokens):
+        return lm_loss(outputs, tokens), {}
+
+    def reported_loss(self, outputs, tokens):
+        return lm_loss(outputs, tokens)
+
 
 @lru_cache(maxsize=64)
 def _single_device_attention(kernel: bool, window: int | None = None):
@@ -193,19 +212,22 @@ def attn_tiles(model, seq_len: int) -> str:
 def attention_plan(model, batch: int, seq_len: int) -> tuple[dict, dict]:
     """The attention of a trial as the ``trial.init`` span carries it.
     Attributes: ``attn_layers`` (the kinds of layer and how many of each:
-    ``"full nope x1, window4096 rope x3"``) and ``attn_tiles``.  Counters,
-    where the kernel runs: ``attn_tiles_run`` (the tiles the three kernels'
-    loops walk in one step: forward, dq, dkv, every layer, head and batch row;
-    a rematerialised forward not counted again) and ``attn_tiles_needed`` (the
-    least: the tiles of the planned size that hold a visible pair)."""
+    ``"full nope x1, window4096 rope x3"``; a model that runs its layers
+    several times says so: ``"full rope x6, 4 passes"``, and ``passes``) and
+    ``attn_tiles``.  Counters, where the kernel runs: ``attn_tiles_run`` (the
+    tiles the three kernels' loops walk in one step: forward, dq, dkv, every
+    application of a layer, head and batch row; a rematerialised forward not
+    counted again) and ``attn_tiles_needed`` (the least: the tiles of the
+    planned size that hold a visible pair)."""
     kinds = model.attn_kinds
-    attrs = {
-        "attn_layers": ", ".join(
-            f"{'full' if window is None else f'window{window}'} {positions} x{n}"
-            for window, positions, n in kinds
-        ),
-        "attn_tiles": attn_tiles(model, seq_len),
-    }
+    passes = getattr(model, "passes", 1)
+    layers = ", ".join(
+        f"{'full' if window is None else f'window{window}'} {positions} x{n}"
+        for window, positions, n in kinds
+    )
+    attrs = {"attn_layers": layers, "attn_tiles": attn_tiles(model, seq_len)}
+    if passes > 1:
+        attrs.update(attn_layers=f"{layers}, {passes} passes", passes=passes)
     if not getattr(model.attn_fn, "kernel", False):
         return attrs, {}
     bq, bk = plan_tiles(seq_len, seq_len, *model.attn_widths, jnp.dtype(model.dtype))
@@ -213,18 +235,22 @@ def attention_plan(model, batch: int, seq_len: int) -> tuple[dict, dict]:
     for window, _positions, n in kinds:
         walked, least = tile_visits(seq_len, seq_len, bq, bk, True, window)
         run, needed = run + n * walked, needed + n * least
-    rows = batch * model.attn_heads
+    rows = batch * model.attn_heads * passes
     return attrs, {"attn_tiles_run": rows * run, "attn_tiles_needed": rows * needed}
 
 
 def loss_path(model, batch: int, seq_len: int, mesh) -> str:
     """How a trial's loss runs, for the ``trial.init`` span: ``fused`` on the
     dense logits a model on a mesh multiplies out, ``fused rows=<sequences a
-    chunk> x <chunks>`` where the head's product runs inside it."""
-    if mesh is not None:
-        return "fused"
-    rows = chunk_rows(batch, seq_len, model.vocab_size)
-    return f"fused rows={rows} x {batch // rows}"
+    chunk> x <chunks>`` where the head's product runs inside it; a model with
+    several exits sends every exit's sequences through the one chunk loop and
+    says how many (``fused rows=1 x 4, 4 exits``)."""
+    exits = getattr(model, "passes", 1)
+    path = "fused"
+    if mesh is None:
+        rows = chunk_rows(batch * exits, seq_len, model.vocab_size)
+        path = f"fused rows={rows} x {batch * exits // rows}"
+    return path if exits == 1 else f"{path}, {exits} exits"
 
 
 # ---------------------------------------------------------------------------
@@ -254,16 +280,6 @@ def markov_dataset(
 # ---------------------------------------------------------------------------
 
 
-def lm_loss(logits: jnp.ndarray | HeadInputs, tokens: jnp.ndarray) -> jnp.ndarray:
-    """Next-token cross entropy over [B, S, V] logits / [B, S] tokens.  In
-    place of dense logits it takes what the head would multiply (a model
-    called with ``multiply_head=False``); the product then runs inside the
-    loss, in row chunks (``models/lm_head.py``)."""
-    if isinstance(logits, HeadInputs):
-        return head_loss(logits, tokens)
-    return next_token_loss(logits, tokens)
-
-
 #: AdamW's decoupled weight decay, on every parameter
 WEIGHT_DECAY = 0.01
 
@@ -286,14 +302,15 @@ class TrialPrograms(NamedTuple):
 
     init: Callable  # (key, seq_len) -> TrainState
     # (state, tokens, dropout_key, lr, warmup_steps, steps) -> (state, loss,
-    # routing): what the model's expert layers sowed into ``ROUTING`` this
-    # step; an empty tree for a model that has none
+    # counters): the model's training loss, and what a report may read of the
+    # step: what the expert layers sowed into ``ROUTING`` and what the loss
+    # gave beside its value; an empty tree for a model that has neither
     step_fn: Callable
-    eval_fn: Callable  # (params, tokens) -> loss
+    eval_fn: Callable  # (params, tokens) -> the model's reported loss
 
 
 def _build_programs(
-    model: TransformerLM | MlaMoeLM | GqaMoeLM, grad_clip: float, weight_decay: float, mesh
+    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM, grad_clip: float, weight_decay: float, mesh
 ) -> TrialPrograms:
     # AdamW without its rate: ``step_fn`` scales the update by the schedule's
     # value, so lr, steps and warmup_frac are operands and not constants
@@ -312,8 +329,9 @@ def _build_programs(
 
     def loss_fn(params, tokens, dropout_key):
         dropout = {"deterministic": False, "rngs": {"dropout": dropout_key}} if use_dropout else {}
-        logits, sown = model.apply(params, tokens, mutable=[ROUTING], multiply_head=multiply_head, **dropout)
-        return lm_loss(logits, tokens), sown.get(ROUTING, {})
+        outputs, sown = model.apply(params, tokens, mutable=[ROUTING], multiply_head=multiply_head, **dropout)
+        loss, read = model.training_loss(outputs, tokens)
+        return loss, {**sown.get(ROUTING, {}), **read}
 
     # parameters and optimizer state in one program (the forward pass that
     # ``model.init`` traces is dead code in it), replicated over the mesh
@@ -340,7 +358,7 @@ def _build_programs(
 
     @jax.jit
     def eval_fn(params, tokens):
-        return lm_loss(model.apply(params, tokens, multiply_head=multiply_head), tokens)
+        return model.reported_loss(model.apply(params, tokens, multiply_head=multiply_head), tokens)
 
     return TrialPrograms(init, step_fn, eval_fn)
 
@@ -356,7 +374,7 @@ _PROGRAMS_LOCK = threading.Lock()
 
 
 def _programs_for(
-    model: TransformerLM | MlaMoeLM | GqaMoeLM, grad_clip: float, mesh
+    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM, grad_clip: float, mesh
 ) -> tuple[TrialPrograms, bool]:
     """The structure's programs, and whether the process had them already."""
     key = (model, float(grad_clip), WEIGHT_DECAY, mesh)
@@ -378,7 +396,7 @@ def _programs_for(
 
 
 def train_lm(
-    model: TransformerLM | MlaMoeLM | GqaMoeLM,
+    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM,
     data: np.ndarray,
     *,
     lr: float,
@@ -391,8 +409,9 @@ def train_lm(
     report=None,
     report_every: int = 10,
 ) -> float:
-    """Train on ``data`` [N, S]; returns final eval loss on a held-out tail.
-    Calls ``report(step, loss, eval_loss)`` every ``report_every`` steps."""
+    """Train on ``data`` [N, S]; returns the final eval loss (the model's
+    reported loss) on a held-out tail.  Calls ``report(step, loss, eval_loss)``
+    every ``report_every`` steps; ``loss`` is the model's training loss."""
     # everything up to the loop: the structure's programs, parameters and
     # optimizer state, the schedule's operands, placing the eval tokens
     with tracing.span("trial.init") as sp:
@@ -410,7 +429,7 @@ def train_lm(
         )
         for name, tiles in tile_counters.items():
             sp.add(name, tiles)
-        if hasattr(model, "step_counters"):
+        if hasattr(getattr(model, "sizes", None), "experts_held"):
             # a model with expert layers: the lengths their sorted buffer may take
             sp.set(expert_buffer=expert_buffer(model.sizes, batch_size * data.shape[1]))
         state = programs.init(jax.random.PRNGKey(seed), data.shape[1])
@@ -426,16 +445,17 @@ def train_lm(
 
         eval_tokens = place(heldout[:batch_size])
         eval_loss: float | None = None
-        routing = None
+        counters = None
         dkey = jax.random.PRNGKey(seed + 1)
 
     def evaluate(step: int, first: bool) -> float:
         with tracing.span("trial.eval", step=step, first=first) as sp:
             value = float(programs.eval_fn(state.params, eval_tokens))
-            if routing:
-                # the last step's routing counts, as the model with the expert
-                # layers reads them; fetched where the loss is: no wait of its own
-                sp.set(**model.step_counters(jax.device_get(routing)))
+            if counters:
+                # what the last step gave a report to read (an expert model's
+                # routing counts, a looped model's exits), as the model reads
+                # it; fetched where the loss is: no wait of its own
+                sp.set(**model.step_counters(jax.device_get(counters)))
             return value
 
     for s in range(steps):
@@ -446,7 +466,7 @@ def train_lm(
         # and where the process has not run this structure and shape yet,
         # trace, lower, cache lookup and executable load
         with tracing.span("trial.first_step") if s == 0 else nullcontext():
-            state, loss, routing = programs.step_fn(state, tokens, sub, *schedule)
+            state, loss, counters = programs.step_fn(state, tokens, sub, *schedule)
         eval_loss = None  # stale after this step's update
         if report is not None and (s % report_every == 0 or s == steps - 1):
             eval_loss = evaluate(s, first=s == 0)
@@ -460,15 +480,17 @@ def train_lm(
 # -- the white-box trial function -------------------------------------------
 
 
-def _expert_block_sizes(cls, block: str, p, mesh):
-    """An expert block's sizes (``MlaMoeSizes`` | ``GqaMoeSizes``) from a
-    trial's parameters: every field under its own name, a layout as a string
-    of 0 and 1, the experts held as two integers."""
+def _block_sizes(cls, block: str, p, mesh) -> dict:
+    """The fields of a block's sizes (``MlaMoeSizes`` | ``GqaMoeSizes`` |
+    ``LoopedSizes``) from a trial's parameters: every field under its own
+    name, a layout as a string of 0 and 1 (the experts held are two integers
+    of their own: ``_expert_block_sizes``).  These blocks have no dropout and
+    have not been run over a ``seq`` axis."""
     if mesh is not None and mesh.shape.get(SEQ_AXIS, 1) > 1:
         raise ValueError(
             f"transformer_trial: block {block!r} cannot run on a mesh with a 'seq' axis: the ring "
             "and all-to-all attention paths assume keys and values of one width and one head "
-            "count, and have no window"
+            "count, have no window, and have run block 'gpt2' alone"
         )
     if float(p.get("dropout", 0.0)) > 0.0:
         raise ValueError(f"transformer_trial: block {block!r} has no dropout")
@@ -485,6 +507,12 @@ def _expert_block_sizes(cls, block: str, p, mesh):
             sizes[f.name] = tuple(int(c) for c in raw)
         else:
             sizes[f.name] = type(f.default)(p.get(f.name, f.default))
+    return sizes
+
+
+def _expert_block_sizes(cls, block: str, p, mesh):
+    """An expert block's sizes, with the experts held as (first, count)."""
+    sizes = _block_sizes(cls, block, p, mesh)
     held = (
         int(p.get("experts_held_first", 0)),
         int(p.get("experts_held", sizes["n_experts"])),
@@ -524,34 +552,51 @@ def _gqa_moe_model(p, vocab: int, mesh) -> GqaMoeLM:
     )
 
 
+def _looped_model(p, vocab: int, mesh) -> LoopedLM:
+    """The ``block: looped`` model from a trial's parameters."""
+    sizes = LoopedSizes(**_block_sizes(LoopedSizes, LoopedLM.BLOCK, p, mesh))
+    if sizes.ut_steps < 1:
+        raise ValueError(f"transformer_trial: ut_steps {sizes.ut_steps}: the stack runs at least once")
+    return LoopedLM(vocab_size=vocab, sizes=sizes, attn_fn=make_attention_fn(mesh))
+
+
+def _gpt2_model(p, vocab: int, mesh) -> TransformerLM:
+    """The ``block: gpt2`` model from a trial's parameters."""
+    return TransformerLM(
+        vocab_size=vocab,
+        d_model=int(p.get("d_model", 128)),
+        n_heads=int(p.get("n_heads", 4)),
+        n_layers=int(p.get("n_layers", 2)),
+        max_seq_len=int(p.get("seq_len", 512)),
+        dropout=float(p.get("dropout", 0.0)),
+        attn_fn=make_attention_fn(mesh, strategy=str(p.get("attn", "ring"))),
+    )
+
+
+#: ``block`` -> (parameters, vocabulary, mesh) -> the model
+_BLOCKS = {
+    TransformerLM.BLOCK: _gpt2_model,
+    MlaMoeLM.BLOCK: _mla_moe_model,
+    GqaMoeLM.BLOCK: _gqa_moe_model,
+    LoopedLM.BLOCK: _looped_model,
+}
+
+
 def transformer_trial(ctx) -> None:
     """White-box trial: tunable long-context LM reporting train/eval loss."""
     p = ctx.params
     vocab = int(p.get("vocab_size", 256))
     seq_len = int(p.get("seq_len", 512))
     mesh = ctx.mesh
-    strategy = str(p.get("attn", "ring"))
 
     with tracing.span("trial.data"):
         block = str(p.get("block", "gpt2"))
-        if block == "gpt2":
-            model = TransformerLM(
-                vocab_size=vocab,
-                d_model=int(p.get("d_model", 128)),
-                n_heads=int(p.get("n_heads", 4)),
-                n_layers=int(p.get("n_layers", 2)),
-                max_seq_len=seq_len,
-                dropout=float(p.get("dropout", 0.0)),
-                attn_fn=make_attention_fn(mesh, strategy=strategy),
-            )
-        elif block == "mla_moe":
-            model = _mla_moe_model(p, vocab, mesh)
-        elif block == "gqa_moe":
-            model = _gqa_moe_model(p, vocab, mesh)
-        else:
+        if block not in _BLOCKS:
+            *known, last = map(repr, _BLOCKS)
             raise ValueError(
-                f"transformer_trial: block {block!r} is neither 'gpt2', 'mla_moe' nor 'gqa_moe'"
+                f"transformer_trial: block {block!r} is neither {', '.join(known)} nor {last}"
             )
+        model = _BLOCKS[block](p, vocab, mesh)
         data = markov_dataset(
             vocab, int(p.get("n_seq", 512)), seq_len, seed=int(p.get("data_seed", 0))
         )

@@ -99,6 +99,8 @@ ATTENTION_SHAPES = {
     # sees the whole prefix, and the three that see 4096 keys
     "smallthinker-21b-a3b-ep8-full": ((1, 28, 16384, 128), (1, 4, 16384, 128), None),
     "smallthinker-21b-a3b-ep8-window": ((1, 28, 16384, 128), (1, 4, 16384, 128), 4096),
+    # 16 heads of 128 over as many key-value heads: every application of a layer
+    "ouro-2.6b-l6": ((1, 16, 4096, 128), (1, 16, 4096, 128), None),
 }
 
 
@@ -129,6 +131,51 @@ def test_grouped_expert_product_is_a_kernel(one_chip):
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert len(kernels) >= 2 and all("operand_layout_constraints={s32[" in k for k in kernels)
     assert "bf16[16,49152" not in text  # no [experts, rows, ...] dense intermediate
+
+
+def test_looped_step_fits_one_chip_and_holds_the_stack_once(one_chip):
+    """The whole train step of ``ouro-2.6b-l6`` (``block: looped``: six layers
+    run four times, 510M parameters, 4096 positions) compiled for the
+    described chip, the attention kernel in it.  Its ``memory_analysis()`` is
+    what decides the configuration's depth (ISSUE 37: over 14 GB, cut a layer;
+    under 8 GB, say so): it read 11.25 GB at six layers.  The passes are a
+    loop: 24 attention kernels (forward, rematerialised forward, dq, dkv of
+    six layers), not 96."""
+    import json
+
+    from katib_tpu.models import transformer
+    from katib_tpu.models.looped import LoopedLM, LoopedSizes
+    from katib_tpu.ops.flash_attention import flash_attention
+
+    def kernel(q, k, v):  # what make_attention_fn gives on the chip
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    kernel.kernel, kernel.window = True, None
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark", "configs", "ouro-2.6b-l6.json")) as f:
+        cfg = json.load(f)
+    sizes = LoopedSizes(
+        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"], head_dim=cfg["head_dim"],
+        mlp_width=cfg["intermediate_size"], n_layers=cfg["num_hidden_layers"], ut_steps=cfg["total_ut_steps"],
+    )
+    model = LoopedLM(vocab_size=cfg["vocab_size"], sizes=sizes, attn_fn=kernel)
+    programs = transformer._build_programs(model, 1.0, transformer.WEIGHT_DECAY, None)
+    seq_len = cfg["seq_len"]
+
+    def placed(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    state = jax.tree.map(placed, jax.eval_shape(programs.init, jax.random.PRNGKey(0), seq_len))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)  # noqa: E731
+    compiled = programs.step_fn.lower(
+        state, placed(jnp.zeros((cfg["batch_size"], seq_len), jnp.int32)), placed(jnp.zeros((2,), jnp.uint32)),
+        scalar(jnp.float32), scalar(jnp.int32), scalar(jnp.int32),
+    ).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 4 * sizes.n_layers
+    ma = compiled.memory_analysis()
+    held = ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    total = held + ma.temp_size_in_bytes + ma.generated_code_size_in_bytes
+    assert 6.0e9 < held < 6.3e9  # parameters and two moments, float32, updated in place
+    assert 8e9 < total < 14e9, total  # the rule's two ends; under the chip's 16 GiB
 
 
 def _mnist_cohort_step_avals(k, member_sharding, shared_sharding, mesh=None):
