@@ -25,8 +25,9 @@ For layer ``l`` and residual stream ``x``:
   float32.
 
 Departures from the published model: no auxiliary loss; flax's default
-initialisers; every block rematerialised.  Activations and products in
-bfloat16, parameters, router logits, logits and loss in float32.
+initialisers; every block rematerialised (it keeps its input and, where the
+attention kernel runs, the kernel's output and logsumexp).  Activations and
+products in bfloat16, parameters, router logits, logits and loss in float32.
 """
 
 from __future__ import annotations
@@ -41,6 +42,7 @@ import jax.numpy as jnp
 
 from katib_tpu.models.lm_head import LMHead, lm_loss, next_token_objective
 from katib_tpu.models.mla_moe import ExpertLayer, RMSNorm, rotary, routing_counters
+from katib_tpu.ops.flash_attention import remat_block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,9 +122,11 @@ class GqaMoeBlock(nn.Module):
 
 class GqaMoeLM(nn.Module):
     """Decoder-only LM of ``GqaMoeBlock``s; every block is rematerialised in
-    the backward pass."""
+    the backward pass and keeps its input and, where the attention kernel
+    runs, the kernel's output and logsumexp (``remat_block``)."""
 
     BLOCK = "gqa_moe"  # the block family's name, as ``transformer_trial`` takes it
+    REMAT_BLOCKS = True  # every block runs under ``remat_block``
 
     vocab_size: int
     sizes: GqaMoeSizes = GqaMoeSizes()
@@ -162,7 +166,7 @@ class GqaMoeLM(nn.Module):
         x = nn.Embed(self.vocab_size, z.d_model, dtype=self.dtype, name="embed")(tokens)
         for i in range(z.n_layers):
             in_window, rope = z.layer_kind(i)
-            x = nn.remat(GqaMoeBlock)(
+            x = remat_block(GqaMoeBlock)(
                 z, rope, windowed if in_window else full, self.dtype, name=f"layer_{i}"
             )(x)
         x = RMSNorm(z.eps, self.dtype, name="norm")(x)

@@ -33,8 +33,10 @@ what is assumed).  With ``T`` = ``ut_steps`` and ``L`` = ``n_layers``:
   last exit answers.
 
 Departures from the published model: no early exit at evaluation; flax's
-default initialisers; every block rematerialised.  Activations and products in
-bfloat16, parameters, gate, logits and losses in float32.
+default initialisers; every block rematerialised (it keeps its input and,
+where the attention kernel runs, the kernel's output and logsumexp of every
+pass).  Activations and products in bfloat16, parameters, gate, logits and
+losses in float32.
 """
 
 from __future__ import annotations
@@ -51,6 +53,7 @@ import numpy as np
 
 from katib_tpu.models.lm_head import HeadInputs, LMHead, lm_loss, weighted_token_losses
 from katib_tpu.models.mla_moe import RMSNorm, SwiGLU, rotary
+from katib_tpu.ops.flash_attention import remat_block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,7 +112,9 @@ class LoopedBlock(nn.Module):
 class LoopedPass(nn.Module):
     """One pass of the stack, as the body of the scan over the passes: the
     carry is the pass's input, and every pass's output is kept (its exit).
-    Every block is rematerialised in the backward pass."""
+    Every block is rematerialised in the backward pass and keeps its input
+    and, where the attention kernel runs, the kernel's output and logsumexp
+    (``remat_block``): the backward loop's body holds no forward kernel."""
 
     sizes: LoopedSizes
     attn_fn: Callable
@@ -119,7 +124,7 @@ class LoopedPass(nn.Module):
     def __call__(self, x, _):
         z = self.sizes
         for i in range(z.n_layers):
-            x = nn.remat(LoopedBlock)(z, self.attn_fn, self.dtype, name=f"layer_{i}")(x)
+            x = remat_block(LoopedBlock)(z, self.attn_fn, self.dtype, name=f"layer_{i}")(x)
         x = RMSNorm(z.eps, self.dtype, name="norm")(x)
         return x, x
 
@@ -154,6 +159,7 @@ class LoopedLM(nn.Module):
     weights, with an exit after every pass."""
 
     BLOCK = "looped"  # the block family's name, as ``transformer_trial`` takes it
+    REMAT_BLOCKS = True  # every block runs under ``remat_block``
 
     vocab_size: int
     sizes: LoopedSizes = LoopedSizes()

@@ -54,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from katib_tpu.models.lm_head import LMHead, lm_loss, next_token_objective
+from katib_tpu.ops.flash_attention import remat_block
 
 #: the collection an expert layer sows a step's routing counts into
 ROUTING = "routing"
@@ -470,9 +471,12 @@ class MlaMoeBlock(nn.Module):
 
 class MlaMoeLM(nn.Module):
     """Decoder-only LM of ``MlaMoeBlock``s; every block is rematerialised in
-    the backward pass (one layer's activations at 8192 tokens are over 1 GB)."""
+    the backward pass (one layer's activations at 8192 tokens are over 1 GB)
+    and keeps its input and, where the attention kernel runs, the kernel's
+    output and logsumexp (``remat_block``)."""
 
     BLOCK = "mla_moe"  # the block family's name, as ``transformer_trial`` takes it
+    REMAT_BLOCKS = True  # every block runs under ``remat_block``
 
     vocab_size: int
     sizes: MlaMoeSizes = MlaMoeSizes()
@@ -502,7 +506,7 @@ class MlaMoeLM(nn.Module):
             from katib_tpu.models.transformer import _dense_causal_attention as attn
         x = nn.Embed(self.vocab_size, z.d_model, dtype=self.dtype, name="embed")(tokens)
         for i in range(z.n_layers):
-            x = nn.remat(MlaMoeBlock)(
+            x = remat_block(MlaMoeBlock)(
                 z, i < z.first_dense_layers, attn, self.dtype, name=f"layer_{i}"
             )(x)
         x = RMSNorm(z.eps, self.dtype, name="norm")(x)

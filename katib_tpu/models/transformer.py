@@ -109,6 +109,7 @@ class Block(nn.Module):
 
 class TransformerLM(nn.Module):
     BLOCK = "gpt2"  # the block family's name, as ``transformer_trial`` takes it
+    REMAT_BLOCKS = False  # the backward pass reads every block's kept activations
 
     vocab_size: int
     d_model: int = 128
@@ -213,22 +214,29 @@ def attention_plan(model, batch: int, seq_len: int) -> tuple[dict, dict]:
     """The attention of a trial as the ``trial.init`` span carries it.
     Attributes: ``attn_layers`` (the kinds of layer and how many of each:
     ``"full nope x1, window4096 rope x3"``; a model that runs its layers
-    several times says so: ``"full rope x6, 4 passes"``, and ``passes``) and
-    ``attn_tiles``.  Counters, where the kernel runs: ``attn_tiles_run`` (the
-    tiles the three kernels' loops walk in one step: forward, dq, dkv, every
-    application of a layer, head and batch row; a rematerialised forward not
-    counted again) and ``attn_tiles_needed`` (the least: the tiles of the
-    planned size that hold a visible pair)."""
+    several times says so: ``"full rope x6, 4 passes"``, and ``passes``),
+    ``attn_tiles`` and ``remat``: what the backward pass computes again
+    (``"none"``: every activation is kept; ``"blocks"``: every block from its
+    input; ``"blocks, keeps attn out+lse"``: but for the attention kernel's
+    results, which ``remat_block`` keeps).  Counters, where the kernel runs:
+    ``attn_tiles_run`` (the tiles the three kernels' loops walk in one step:
+    forward, dq, dkv, every application of a layer, head and batch row) and
+    ``attn_tiles_needed`` (the least: the tiles of the planned size that hold
+    a visible pair)."""
     kinds = model.attn_kinds
     passes = getattr(model, "passes", 1)
+    kernel = bool(getattr(model.attn_fn, "kernel", False))
     layers = ", ".join(
         f"{'full' if window is None else f'window{window}'} {positions} x{n}"
         for window, positions, n in kinds
     )
-    attrs = {"attn_layers": layers, "attn_tiles": attn_tiles(model, seq_len)}
+    remat = "none"
+    if model.REMAT_BLOCKS:
+        remat = "blocks, keeps attn out+lse" if kernel else "blocks"
+    attrs = {"attn_layers": layers, "attn_tiles": attn_tiles(model, seq_len), "remat": remat}
     if passes > 1:
         attrs.update(attn_layers=f"{layers}, {passes} passes", passes=passes)
-    if not getattr(model.attn_fn, "kernel", False):
+    if not kernel:
         return attrs, {}
     bq, bk = plan_tiles(seq_len, seq_len, *model.attn_widths, jnp.dtype(model.dtype))
     run = needed = 0

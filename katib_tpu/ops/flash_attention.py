@@ -17,7 +17,11 @@ Design (FlashAttention-2 style, adapted to the TPU memory hierarchy):
   score matrix (rematerialisation trades FLOPs for HBM, the TPU-native
   default).
 - both are exposed through one ``jax.custom_vjp`` so ``jax.grad`` composes
-  with jit/shard_map/scan.
+  with jit/shard_map/scan.  Its forward rule names the kernel's two results
+  (``KERNEL_RESULTS``), and ``remat_block`` rematerialises a flax module
+  under the policy that keeps arrays of those names: the backward kernels and
+  the output projection's weight gradient read the output and logsumexp the
+  forward wrote, and the rematerialised forward holds no attention kernel.
 
 Precision follows the inputs' dtype.  Every product takes its operands as
 they come (q, k, v, dO) or cast to that dtype (the probabilities ``p`` and
@@ -51,9 +55,11 @@ from __future__ import annotations
 import functools
 import math
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -71,6 +77,11 @@ VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES // 2
 #: k tile loaded), then k tiles (the width of a score tile)
 _Q_TILES = (512, 256, 128)
 _K_TILES = (512, 256, 128)
+
+#: the names ``_vjp_fwd`` gives the forward kernel's output and logsumexp
+KERNEL_RESULTS = ("flash_out", "flash_lse")
+# one policy object for every rematerialised block: a block's program names it
+_KEEP_KERNEL_RESULTS = jax.checkpoint_policies.save_only_these_names(*KERNEL_RESULTS)
 
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
@@ -544,6 +555,10 @@ def _vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window):
     o, lse = flash_attention_with_lse(
         q, k, v, causal, sm_scale, block_q, block_k, interpret, window
     )
+    # named here, on the arrays that are both the primal results and the
+    # residuals: a name given outside the custom_vjp would leave the residuals
+    # unnamed, and a rematerialised block would run the kernel again for them
+    o, lse = map(checkpoint_name, (o, lse), KERNEL_RESULTS)
     return (o, lse), (q, k, v, o, lse)
 
 
@@ -560,6 +575,15 @@ def _vjp_bwd(causal, sm_scale, block_q, block_k, interpret, window, res, cts):
 
 
 flash_attention_with_lse.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+def remat_block(block: type[nn.Module]) -> type[nn.Module]:
+    """``nn.remat`` of a block that keeps the attention kernel's output and
+    logsumexp (bfloat16 ``[B, H, S, Dv]`` and float32 ``[B, H, 1, S]`` a
+    layer application: linear in the context to hold, quadratic to compute
+    again) and computes everything else again in the backward pass.  Where
+    attention is dense nothing carries the names and nothing is kept."""
+    return nn.remat(block, policy=_KEEP_KERNEL_RESULTS)
 
 
 def flash_attention(

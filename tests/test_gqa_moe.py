@@ -617,7 +617,10 @@ class TestNormalPath:
             attn_fn=kernel(True), window_attn_fn=kernel(True, 4096),
         )
         attrs, counters = transformer.attention_plan(cell, 1, 16384)
-        assert attrs == {"attn_layers": "full nope x1, window4096 rope x3", "attn_tiles": "bfloat16 q512 k512"}
+        assert attrs == {
+            "attn_layers": "full nope x1, window4096 rope x3", "attn_tiles": "bfloat16 q512 k512",
+            "remat": "blocks, keeps attn out+lse",
+        }
         assert counters == {"attn_tiles_run": 28 * 3 * (528 + 3 * 252), "attn_tiles_needed": 28 * 3 * (528 + 3 * 252)}
         small = transformer.TransformerLM(vocab_size=50257, d_model=768, n_heads=12, n_layers=12, attn_fn=kernel(True))
         attrs, counters = transformer.attention_plan(small, 8, 1024)
