@@ -423,23 +423,26 @@ def train_lm(
     # everything up to the loop: the structure's programs, parameters and
     # optimizer state, the schedule's operands, placing the eval tokens
     with tracing.span("trial.init") as sp:
-        rng = np.random.default_rng(seed)
-        n_eval = max(batch_size, len(data) // 10)
-        train, heldout = data[:-n_eval], data[-n_eval:]
+        # host work alone, up to the first thing handed to the device
+        # (``init``): where a trial's hand-over from the last one ends
+        with tracing.span("trial.programs"):
+            rng = np.random.default_rng(seed)
+            n_eval = max(batch_size, len(data) // 10)
+            train, heldout = data[:-n_eval], data[-n_eval:]
 
-        programs, reused = _programs_for(model, grad_clip, mesh)
-        attention, tile_counters = attention_plan(model, batch_size, data.shape[1])
-        sp.set(
-            programs="reused" if reused else "built",
-            block=model.BLOCK,
-            **attention,
-            loss=loss_path(model, batch_size, data.shape[1], mesh),
-        )
-        for name, tiles in tile_counters.items():
-            sp.add(name, tiles)
-        if hasattr(getattr(model, "sizes", None), "experts_held"):
-            # a model with expert layers: the lengths their sorted buffer may take
-            sp.set(expert_buffer=expert_buffer(model.sizes, batch_size * data.shape[1]))
+            programs, reused = _programs_for(model, grad_clip, mesh)
+            attention, tile_counters = attention_plan(model, batch_size, data.shape[1])
+            sp.set(
+                programs="reused" if reused else "built",
+                block=model.BLOCK,
+                **attention,
+                loss=loss_path(model, batch_size, data.shape[1], mesh),
+            )
+            for name, tiles in tile_counters.items():
+                sp.add(name, tiles)
+            if hasattr(getattr(model, "sizes", None), "experts_held"):
+                # a model with expert layers: the lengths their sorted buffer may take
+                sp.set(expert_buffer=expert_buffer(model.sizes, batch_size * data.shape[1]))
         state = programs.init(jax.random.PRNGKey(seed), data.shape[1])
         schedule = (
             jnp.float32(lr),

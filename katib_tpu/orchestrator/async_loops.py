@@ -71,6 +71,7 @@ import copy
 import statistics
 import threading
 import traceback
+from contextlib import ExitStack
 
 from katib_tpu.analysis import guarded_by, make_lock
 from katib_tpu.utils.clock import get_clock
@@ -146,7 +147,7 @@ class AsyncLoops:
     # the harvest thread iterates, including the speculation bookkeeping.
     _GUARDS = guarded_by(
         _queue_lock=(
-            "_ready", "_packing", "_pack_ts", "_dispatchq",
+            "_ready", "_packing", "_pack_ts", "_dispatchq", "_unit_ts",
             "_dispatched_total", "_consumed_last_call",
         ),
         _futures_lock=(
@@ -192,6 +193,13 @@ class AsyncLoops:
         self._pack_ts: dict[str, float] = {}
         #: flushed units awaiting a free slot (schedule -> pool hand-off)
         self._dispatchq: collections.deque[list[Trial]] = collections.deque()
+        #: first member's name -> when its unit joined the dispatch queue
+        self._unit_ts: dict[str, float] = {}
+        #: when each slot came free and has not been given away again (a
+        #: future's done-callback appends, a dispatch takes the oldest); an
+        #: empty deque is a slot free since the loops started (``_started_at``)
+        self._slot_freed: collections.deque[float] = collections.deque()
+        self._started_at = get_clock().monotonic()
 
         self._halt = threading.Event()       # internal: stop all three loops
         self._exhausted = threading.Event()  # suggester returned exhausted
@@ -632,7 +640,7 @@ class AsyncLoops:
                 trial = self._ready.popleft()
                 key = self._cohort_key_for(trial)
                 if key is None:
-                    self._dispatchq.append([trial])
+                    self._enqueue([trial])
                 else:
                     bucket = self._packing.setdefault(key, [])
                     if not bucket:
@@ -669,7 +677,7 @@ class AsyncLoops:
             for key in list(self._packing):
                 bucket = self._packing[key]
                 while len(bucket) >= self.width:
-                    self._dispatchq.append(bucket[: self.width])
+                    self._enqueue(bucket[: self.width])
                     del bucket[: self.width]
                     self._pack_ts[key] = now
                     flushed += 1
@@ -687,11 +695,15 @@ class AsyncLoops:
                     and not self._ready
                 )
                 if deadline_hit or starved:
-                    self._dispatchq.append(list(bucket))
+                    self._enqueue(list(bucket))
                     del self._packing[key]
                     self._pack_ts.pop(key, None)
                     flushed += 1
         return flushed
+
+    def _enqueue(self, unit: list[Trial]) -> None:  # lint: holds(_queue_lock)
+        self._dispatchq.append(unit)
+        self._unit_ts[unit[0].name] = get_clock().monotonic()
 
     def _undone_members(self) -> int:  # lint: holds(_futures_lock)
         return sum(
@@ -725,18 +737,39 @@ class AsyncLoops:
                     undone = self._undone_members()
                 if undone > 0 and undone + len(unit) > self.member_limit:
                     return n
-            # early-stopping rules snapshot at DISPATCH time, not propose
-            # time: lookahead materializes trials before any history
-            # exists, so a rule frozen at _materialize would be
-            # permanently empty.  Outside the queue lock (state > queue
-            # ordering); the head is stable because this thread is the
-            # only popper while the loops run.
-            self._refresh_rules(unit)
-            with self._queue_lock:
-                if not self._dispatchq or self._dispatchq[0] is not unit:
-                    continue
-                self._dispatchq.popleft()
-                self._submit(unit)
+                queued_at = self._unit_ts.get(unit[0].name, self._started_at)
+            # ``orch.dispatch``: from here to ``pool.submit`` returned, where
+            # ``_submit`` closes it (the trial starts there: what follows is
+            # bookkeeping beside the running trial); this block closes it on
+            # any other way out
+            with ExitStack() as dispatch:
+                sp = dispatch.enter_context(
+                    orch._span("orch.dispatch", trial=unit[0].name, members=len(unit))
+                )
+                # the loop's reaction time: for how long the unit was queued
+                # AND a slot was free before this turn took it (the poll, and
+                # the wait for the locks above: a harvest that holds them
+                # shows as an ``orch.settle`` span lying over the stretch)
+                freed_at = self._slot_freed[0] if self._slot_freed else self._started_at
+                sp.add(
+                    "slot_free_s",
+                    max(0.0, get_clock().monotonic() - max(queued_at, freed_at)),
+                )
+                # early-stopping rules snapshot at DISPATCH time, not propose
+                # time: lookahead materializes trials before any history
+                # exists, so a rule frozen at _materialize would be
+                # permanently empty.  Outside the queue lock (state > queue
+                # ordering); the head is stable because this thread is the
+                # only popper while the loops run.
+                self._refresh_rules(unit)
+                with self._queue_lock:
+                    if not self._dispatchq or self._dispatchq[0] is not unit:
+                        continue
+                    self._dispatchq.popleft()
+                    self._unit_ts.pop(unit[0].name, None)
+                    if self._slot_freed:
+                        self._slot_freed.popleft()
+                    self._submit(unit, dispatch.close)
             n += 1
         return n
 
@@ -756,7 +789,8 @@ class AsyncLoops:
             if not t.spec.early_stopping_rules:
                 t.spec.early_stopping_rules = rules
 
-    def _submit(self, unit: list[Trial]) -> None:  # lint: holds(_queue_lock)
+    def _submit(self, unit: list[Trial], submitted) -> None:  # lint: holds(_queue_lock)
+        """``submitted()`` is called the moment the pool has the unit."""
         orch, exp = self.orch, self.exp
         orch._submit_prewarm(self.spec, unit, self.mesh)
         now = get_clock().time()
@@ -770,9 +804,13 @@ class AsyncLoops:
         else:
             fut = get_clock().submit(self.pool, orch._execute_cohort, exp, unit, self.mesh)
             owner = unit
+        submitted()
         with self._futures_lock:
             self.futures[fut] = owner
             self._fut_meta[fut] = get_clock().monotonic()
+        # the slot comes free where the future ends, on the trial's thread:
+        # ``orch.dispatch`` measures ``slot_free_s`` from this stamp
+        fut.add_done_callback(lambda _f: self._slot_freed.append(get_clock().monotonic()))
         self._dispatched_total += len(unit)
         self._last_activity = get_clock().monotonic()
         # the harvest loop republishes status.json soon after: without
@@ -1037,6 +1075,7 @@ class AsyncLoops:
             for unit in self._dispatchq:
                 leftovers.extend(unit)
             self._dispatchq.clear()
+            self._unit_ts.clear()
         return leftovers
 
     def _stop_loops(self) -> None:

@@ -28,7 +28,9 @@ import os
 import secrets
 import shutil
 import threading
+import time
 import traceback
+from contextlib import contextmanager
 
 from katib_tpu.core.types import (
     COHORT_KEY_LABEL as _COHORT_KEY_LABEL,
@@ -534,25 +536,31 @@ class Orchestrator:
                                 outcome=outcome,
                             )
                         for group in self._group_proposals(spec, proposals, mesh):
-                            trials = [
-                                self._materialize(exp, p, early_stopper, suggester)
-                                for p in group
-                            ]
-                            # queue the group's compile signature on the
-                            # prewarm worker: while the pool is busy with
-                            # earlier cohorts, this group's program compiles
-                            # in the background so its first step is warm
-                            self._submit_prewarm(spec, trials, mesh)
-                            if len(trials) == 1:
-                                futures[
-                                    get_clock().submit(pool, self._execute, exp, trials[0], mesh)
-                                ] = trials[0]
-                            else:
-                                # one pool slot runs the whole cohort; the
-                                # member list keeps _shortfall's budget honest
-                                futures[
-                                    get_clock().submit(pool, self._execute_cohort, exp, trials, mesh)
-                                ] = trials
+                            # the async engine's span of the same name; this
+                            # loop proposes and submits in one turn and cannot
+                            # know for how long a slot stood free
+                            with self._span("orch.dispatch", members=len(group)) as sp:
+                                sp.add("slot_free_s", 0.0)
+                                trials = [
+                                    self._materialize(exp, p, early_stopper, suggester)
+                                    for p in group
+                                ]
+                                sp.set(trial=trials[0].name)
+                                # queue the group's compile signature on the
+                                # prewarm worker: while the pool is busy with
+                                # earlier cohorts, this group's program compiles
+                                # in the background so its first step is warm
+                                self._submit_prewarm(spec, trials, mesh)
+                                if len(trials) == 1:
+                                    futures[
+                                        get_clock().submit(pool, self._execute, exp, trials[0], mesh)
+                                    ] = trials[0]
+                                else:
+                                    # one pool slot runs the whole cohort; the
+                                    # member list keeps _shortfall's budget honest
+                                    futures[
+                                        get_clock().submit(pool, self._execute_cohort, exp, trials, mesh)
+                                    ] = trials
                         if proposals:
                             self._persist_suggester(exp, suggester)
                             # journal the newly in-flight trials so a crash here
@@ -659,6 +667,28 @@ class Orchestrator:
             "optimal_history": list(exp.optimal_history),
         }
 
+    @contextmanager
+    def _span(self, name: str, **attrs):
+        """A span on the experiment's tracer from whichever thread calls.  The
+        async engine's loop threads have no ambient tracer, so the tracer is
+        theirs while the span is open: what runs inside finds the span
+        (``tracing.current_span``) as it does on a trial's thread.  The null
+        span with tracing off."""
+        with tracing.use_tracer(self._tracer), tracing.span(name, **attrs) as sp:
+            yield sp
+
+    @staticmethod
+    def _journal_write(write, *args, **kwargs):
+        """One ``Journal.append`` / ``append_group`` call.  Its seconds
+        (encode, write, flush, fsync) are ``journal_s`` of the span open on
+        the calling thread: ``orch.dispatch``, ``orch.settle``, or ``trial``
+        for a ``retried`` record."""
+        t0 = time.perf_counter()
+        try:
+            return write(*args, **kwargs)
+        finally:
+            tracing.current_span().add("journal_s", time.perf_counter() - t0)
+
     def _jappend(
         self,
         event: str,
@@ -681,7 +711,8 @@ class Orchestrator:
                 data["trial"] = trial_to_dict(trial)
             if extra:
                 data.update(extra)
-            j.append(
+            self._journal_write(
+                j.append,
                 event,
                 trial=trial.name if trial is not None else None,
                 epoch=trial.retry_count if trial is not None else 0,
@@ -703,7 +734,8 @@ class Orchestrator:
             from katib_tpu.orchestrator.status import trial_to_dict
 
             exp_state = self._journal_exp_state(exp)
-            j.append_group(
+            self._journal_write(
+                j.append_group,
                 [
                     (
                         event,
@@ -1352,146 +1384,8 @@ class Orchestrator:
             # cohort futures resolve to a {name: TrialResult} dict.
             owner = futures.pop(f)
             members = owner if isinstance(owner, list) else [owner]
-            if f.cancelled():
-                for trial in members:
-                    if drain:
-                        # never started: back to PENDING so the resumed run
-                        # submits it fresh (no budget slot consumed)
-                        trial.condition = TrialCondition.PENDING
-                        trial.message = "drained before start; resubmitted on resume"
-                        self._jappend("drained", exp, trial=trial)
-                        continue
-                    trial.condition = TrialCondition.KILLED
-                    trial.completion_time = get_clock().time()
-                    obs.trials_killed.inc()
-                    self._jappend("settled", exp, trial=trial)
-                    self._observe_trial_duration(trial)
-                continue
-            try:
-                result = f.result()  # _execute / _execute_cohort never raise
-            except Exception as exc:
-                # the contract above is defense-in-depth, not a certainty: a
-                # pool-level failure for ONE future must settle its members
-                # as failed (classified through FailureKind), never raise
-                # out of the harvest loop and kill the whole experiment
-                kind = faults.classify_exception(exc)
-                result = TrialResult(
-                    TrialCondition.FAILED,
-                    f"settle failed: {exc!r}",
-                    failure_kind=kind,
-                )
-            results = (
-                result if isinstance(result, dict) else {members[0].name: result}
-            )
-            settled: list[Trial] = []
-            for trial in members:
-                live = exp.trials.get(trial.name)
-                if (live is not None and live is not trial) or (
-                    trial.condition.is_terminal()
-                ):
-                    # speculative first-settle-wins: a rival already settled
-                    # this member (the winner's object owns exp.trials[name])
-                    # — the loser's result is discarded, never re-journaled
-                    continue
-                try:
-                    res = results.get(trial.name)
-                    if res is None:  # defense: _execute_cohort backfills missing
-                        res = TrialResult(
-                            TrialCondition.FAILED,
-                            "cohort returned no result for member",
-                            failure_kind=faults.FailureKind.PERMANENT,
-                        )
-                    trial.condition = res.condition
-                    trial.message = res.message
-                    fk = getattr(res, "failure_kind", None)
-                    if fk is not None:
-                        trial.failure_kind = fk.value
-                    elif not trial.retry_count:
-                        # keep the last failure's classification on a recovered
-                        # retry (journal answers "what did this trial survive?");
-                        # clean first-attempt results clear any resumed leftover
-                        trial.failure_kind = None
-                    trial.completion_time = get_clock().time()
-                    if trial.condition in (
-                        TrialCondition.SUCCEEDED,
-                        TrialCondition.EARLY_STOPPED,
-                    ):
-                        trial.observation = self.store.observation_for(
-                            trial.name, exp.spec.objective
-                        )
-                        if trial.observation is None:
-                            trial.condition = TrialCondition.METRICS_UNAVAILABLE
-                    counter = self._TRIAL_COUNTERS.get(trial.condition)
-                    if counter is not None:
-                        counter.inc()
-                    self._observe_trial_duration(trial)
-                    self._cleanup_trial(trial)
-                except Exception as exc:
-                    # per-member isolation: a bad metrics read / cleanup for
-                    # one member fails THAT member, classified, and the rest
-                    # of the cohort still settles normally
-                    kind = faults.classify_exception(exc)
-                    trial.condition = TrialCondition.FAILED
-                    trial.message = f"settle failed: {exc!r}"
-                    trial.failure_kind = kind.value
-                    if not trial.completion_time:
-                        trial.completion_time = get_clock().time()
-                    obs.trials_failed.inc()
-                settled.append(trial)
-            members = settled
-            # incremental: fold only this settle batch into the optimal —
-            # the full recompute per batch is quadratic at sweep scale
-            exp.update_optimal(members)
-            # durably journal each member's outcome: terminal conditions are
-            # exactly-once settlements keyed by (trial, attempt epoch);
-            # Drained stays non-terminal (resubmitted on resume).  The
-            # "reported" record carries the reduced observation separately
-            # so replay can restore metrics for trials the settle record of
-            # which is ever lost to a torn tail.  The whole batch goes
-            # through one append_group — record content and order are
-            # identical to per-trial appends, but the batch pays a single
-            # durability barrier instead of two per member.
-            if self._journal is not None:
-                try:
-                    from katib_tpu.orchestrator.status import (
-                        _observation_to_dict,
-                        trial_to_dict,
-                    )
-
-                    exp_state = self._journal_exp_state(exp)
-                    records = []
-                    for trial in members:
-                        tdict = trial_to_dict(trial)
-                        if trial.condition is TrialCondition.DRAINED:
-                            records.append((
-                                "drained",
-                                trial.name,
-                                trial.retry_count,
-                                {"exp": exp_state, "trial": tdict},
-                            ))
-                            continue
-                        if trial.observation is not None:
-                            records.append((
-                                "reported",
-                                trial.name,
-                                trial.retry_count,
-                                {
-                                    "exp": exp_state,
-                                    "trial": tdict,
-                                    "observation": _observation_to_dict(
-                                        trial.observation
-                                    ),
-                                },
-                            ))
-                        records.append((
-                            "settled",
-                            trial.name,
-                            trial.retry_count,
-                            {"exp": exp_state, "trial": tdict},
-                        ))
-                    self._journal.append_group(records)
-                except (OSError, ValueError):
-                    pass
+            with self._span("orch.settle", trial=members[0].name, members=len(members)):
+                self._settle(exp, f, members, drain)
         if done:
             if self._journal is not None:
                 try:
@@ -1501,6 +1395,151 @@ class Orchestrator:
                 except (OSError, ValueError):
                     pass
             self._publish(exp)
+
+    def _settle(self, exp: Experiment, f: cf.Future, members: list[Trial], drain: bool) -> None:
+        """Everything ``_harvest`` does for one finished future: its result,
+        the members' observations from the store, counters, clean-up, and
+        their outcome in the journal (the ``orch.settle`` span)."""
+        if f.cancelled():
+            for trial in members:
+                if drain:
+                    # never started: back to PENDING so the resumed run
+                    # submits it fresh (no budget slot consumed)
+                    trial.condition = TrialCondition.PENDING
+                    trial.message = "drained before start; resubmitted on resume"
+                    self._jappend("drained", exp, trial=trial)
+                    continue
+                trial.condition = TrialCondition.KILLED
+                trial.completion_time = get_clock().time()
+                obs.trials_killed.inc()
+                self._jappend("settled", exp, trial=trial)
+                self._observe_trial_duration(trial)
+            return
+        try:
+            result = f.result()  # _execute / _execute_cohort never raise
+        except Exception as exc:
+            # the contract above is defense-in-depth, not a certainty: a
+            # pool-level failure for ONE future must settle its members
+            # as failed (classified through FailureKind), never raise
+            # out of the harvest loop and kill the whole experiment
+            kind = faults.classify_exception(exc)
+            result = TrialResult(
+                TrialCondition.FAILED,
+                f"settle failed: {exc!r}",
+                failure_kind=kind,
+            )
+        results = (
+            result if isinstance(result, dict) else {members[0].name: result}
+        )
+        settled: list[Trial] = []
+        for trial in members:
+            live = exp.trials.get(trial.name)
+            if (live is not None and live is not trial) or (
+                trial.condition.is_terminal()
+            ):
+                # speculative first-settle-wins: a rival already settled
+                # this member (the winner's object owns exp.trials[name])
+                # — the loser's result is discarded, never re-journaled
+                continue
+            try:
+                res = results.get(trial.name)
+                if res is None:  # defense: _execute_cohort backfills missing
+                    res = TrialResult(
+                        TrialCondition.FAILED,
+                        "cohort returned no result for member",
+                        failure_kind=faults.FailureKind.PERMANENT,
+                    )
+                trial.condition = res.condition
+                trial.message = res.message
+                fk = getattr(res, "failure_kind", None)
+                if fk is not None:
+                    trial.failure_kind = fk.value
+                elif not trial.retry_count:
+                    # keep the last failure's classification on a recovered
+                    # retry (journal answers "what did this trial survive?");
+                    # clean first-attempt results clear any resumed leftover
+                    trial.failure_kind = None
+                trial.completion_time = get_clock().time()
+                if trial.condition in (
+                    TrialCondition.SUCCEEDED,
+                    TrialCondition.EARLY_STOPPED,
+                ):
+                    trial.observation = self.store.observation_for(
+                        trial.name, exp.spec.objective
+                    )
+                    if trial.observation is None:
+                        trial.condition = TrialCondition.METRICS_UNAVAILABLE
+                counter = self._TRIAL_COUNTERS.get(trial.condition)
+                if counter is not None:
+                    counter.inc()
+                self._observe_trial_duration(trial)
+                self._cleanup_trial(trial)
+            except Exception as exc:
+                # per-member isolation: a bad metrics read / cleanup for
+                # one member fails THAT member, classified, and the rest
+                # of the cohort still settles normally
+                kind = faults.classify_exception(exc)
+                trial.condition = TrialCondition.FAILED
+                trial.message = f"settle failed: {exc!r}"
+                trial.failure_kind = kind.value
+                if not trial.completion_time:
+                    trial.completion_time = get_clock().time()
+                obs.trials_failed.inc()
+            settled.append(trial)
+        members = settled
+        # incremental: fold only this settle batch into the optimal —
+        # the full recompute per batch is quadratic at sweep scale
+        exp.update_optimal(members)
+        # durably journal each member's outcome: terminal conditions are
+        # exactly-once settlements keyed by (trial, attempt epoch);
+        # Drained stays non-terminal (resubmitted on resume).  The
+        # "reported" record carries the reduced observation separately
+        # so replay can restore metrics for trials the settle record of
+        # which is ever lost to a torn tail.  The whole batch goes
+        # through one append_group — record content and order are
+        # identical to per-trial appends, but the batch pays a single
+        # durability barrier instead of two per member.
+        if self._journal is not None:
+            try:
+                from katib_tpu.orchestrator.status import (
+                    _observation_to_dict,
+                    trial_to_dict,
+                )
+
+                exp_state = self._journal_exp_state(exp)
+                records = []
+                for trial in members:
+                    tdict = trial_to_dict(trial)
+                    if trial.condition is TrialCondition.DRAINED:
+                        records.append((
+                            "drained",
+                            trial.name,
+                            trial.retry_count,
+                            {"exp": exp_state, "trial": tdict},
+                        ))
+                        continue
+                    if trial.observation is not None:
+                        records.append((
+                            "reported",
+                            trial.name,
+                            trial.retry_count,
+                            {
+                                "exp": exp_state,
+                                "trial": tdict,
+                                "observation": _observation_to_dict(
+                                    trial.observation
+                                ),
+                            },
+                        ))
+                    records.append((
+                        "settled",
+                        trial.name,
+                        trial.retry_count,
+                        {"exp": exp_state, "trial": tdict},
+                    ))
+                self._journal_write(self._journal.append_group, records)
+            except (OSError, ValueError):
+                pass
 
     def _cleanup_trial(self, trial: Trial) -> None:
         """Honor ``retain`` (the reference deletes the trial job on

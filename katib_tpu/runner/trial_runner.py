@@ -29,6 +29,7 @@ import signal
 import subprocess
 import threading
 import traceback
+from contextlib import ExitStack
 
 from katib_tpu.utils.clock import get_clock
 from katib_tpu.core.types import (
@@ -170,16 +171,21 @@ def run_trial(
         if injector is not None:
             injector.on_trial_attempt(trial)
             injector.apply_metrics_delay(trial, stop_event)
-        if trial.spec.train_fn is not None:
-            return _run_whitebox(
-                trial, store, evaluator, objective, mesh, stop_event,
-                injector=injector, watchdog=watchdog, drain_event=drain_event,
-            )
-        if trial.spec.command:
-            return _run_blackbox(
-                trial, store, evaluator, objective, stop_event,
-                watchdog=watchdog, drain_event=drain_event,
-            )
+        # ``trial.setup``: a runner's entry up to where the trial's own work
+        # starts.  The runner closes it there (``end_setup``); this block
+        # closes it on any earlier way out.
+        with ExitStack() as setup:
+            setup.enter_context(tracing.span("trial.setup"))
+            if trial.spec.train_fn is not None:
+                return _run_whitebox(
+                    trial, store, evaluator, objective, mesh, stop_event, setup.close,
+                    injector=injector, watchdog=watchdog, drain_event=drain_event,
+                )
+            if trial.spec.command:
+                return _run_blackbox(
+                    trial, store, evaluator, objective, stop_event, setup.close,
+                    watchdog=watchdog, drain_event=drain_event,
+                )
         return TrialResult(
             TrialCondition.FAILED,
             "trial has neither train_fn nor command",
@@ -196,8 +202,10 @@ def run_trial(
 def _finalize(trial: Trial, store: ObservationStore, objective) -> TrialResult:
     """Post-run observation check: succeeded-but-no-objective-metric becomes
     MetricsUnavailable (reference ``newObservationLog`` +
-    ``trial_controller.go:249-252``)."""
-    obs = store.observation_for(trial.name, objective)
+    ``trial_controller.go:249-252``).  The ``trial.finalize`` span: the
+    store's read-back of a trial that ended by itself."""
+    with tracing.span("trial.finalize"):
+        obs = store.observation_for(trial.name, objective)
     if obs is None:
         return TrialResult(
             TrialCondition.METRICS_UNAVAILABLE,
@@ -213,10 +221,14 @@ def _run_whitebox(
     objective,
     mesh,
     stop_event: threading.Event | None,
+    end_setup,
     injector=None,
     watchdog=None,
     drain_event: threading.Event | None = None,
 ) -> TrialResult:
+    """``end_setup()`` closes the ``trial.setup`` span the caller opened:
+    called just before the ``train_fn`` span opens (watchdogs, the compile
+    signature, the context and the chaos seams lie behind it)."""
     hang_event = threading.Event()
     compile_hang_event = threading.Event()
     heartbeat = None
@@ -366,6 +378,7 @@ def _run_whitebox(
         costmodel.clear_active()
         started_holder[0] = get_clock().perf_counter()  # first-step clock starts here
         last_beat[0] = started_holder[0]
+        end_setup()
         with tracing.span("train_fn", trial=trial.name) as sp:
             # the trial's compile totals, 0 where it built nothing
             for counter in tracing.JIT_COUNTERS:
@@ -606,9 +619,13 @@ def _run_blackbox(
     evaluator: RuleEvaluator,
     objective,
     stop_event: threading.Event | None,
+    end_setup,
     watchdog=None,
     drain_event: threading.Event | None = None,
 ) -> TrialResult:
+    """``end_setup()`` closes the ``trial.setup`` span the caller opened:
+    called once the subprocess is launched (the command and the collector
+    rendered, the process started)."""
     collector = trial.spec.metrics_collector
     # the collector path renders like the command (per-trial file paths via
     # ${trialSpec.Name} keep parallel trials from clobbering each other's
@@ -678,6 +695,7 @@ def _run_blackbox(
             failure_kind=classify_exception(e),
         )
     launched_at = get_clock().perf_counter()
+    end_setup()
 
     # metrics come from exactly one source: the file when configured, else
     # stdout (no double-reporting); stdout is always drained to avoid blocking

@@ -23,9 +23,11 @@ trial runner, NAS loops) records spans into it:
   add what jax says it traced, lowered, loaded and compiled to every span
   open on the compiling thread, and journal the long ones as ``jit.*`` spans.
 - while jax is imported an open span is also a
-  ``jax.profiler.TraceAnnotation``: a profiler capture shows the program's
-  spans on the host plane, on the clock of the device operations.  This
-  module never imports jax itself (the simulator runs without it).
+  ``jax.profiler.TraceAnnotation`` that carries the record's ``id`` (and its
+  trial's name): a profiler capture shows the program's spans on the host
+  plane, on the clock of the device operations, and each event names its
+  journal line.  This module never imports jax itself (the simulator runs
+  without it).
 
 Layers below the orchestrator don't hold a Tracer reference; they use the
 ambient per-thread tracer (``activate``/``use_tracer`` set it, the
@@ -249,10 +251,16 @@ class Tracer:
         stack = _active.__dict__.setdefault("stack", [])
         stack.append(sp)
         jax = _hook_jax()
+        annotation = nullcontext()
+        if jax is not None:
+            # the same interval on the profiler's clock, in any capture; ``id``
+            # (and the trial) join the host-plane event to this record.  jax
+            # encodes the keywords into the event only while a capture runs.
+            ident = {"trial": str(sp.attrs["trial"])} if "trial" in sp.attrs else {}
+            annotation = jax.profiler.TraceAnnotation(name, id=sp.id, **ident)
         start = self.elapsed()
         try:
-            # the same interval on the profiler's clock, in any capture
-            with jax.profiler.TraceAnnotation(name) if jax is not None else nullcontext():
+            with annotation:
                 yield sp
         except BaseException as e:
             sp.attrs.setdefault("error", type(e).__name__)
@@ -312,6 +320,14 @@ def span(name: str, **attrs: Any) -> Iterator[Span]:
         return
     with tracer.span(name, **attrs) as sp:
         yield sp
+
+
+def current_span() -> Span:
+    """The innermost span of the ambient tracer open on the calling thread,
+    for code that counts into whatever span it runs inside (``Span.add``);
+    the null span where there is none."""
+    tracer = current_tracer()
+    return (tracer._enclosing() if tracer is not None else None) or _NULL_SPAN
 
 
 def record_span(name: str, dur_s: float, **attrs: Any) -> None:

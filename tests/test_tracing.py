@@ -375,14 +375,16 @@ class TestJaxListeners:
         assert monitoring.get_event_listeners().count(tracing._on_jax_event) == 1
 
     def test_open_span_is_a_profiler_annotation(self, tmp_path, monkeypatch):
-        """While jax is imported a span is also a TraceAnnotation of its name."""
+        """While jax is imported a span is also a TraceAnnotation of its name
+        that carries the journal record's ``id`` and the trial's name."""
         import jax
 
         seen = []
 
         class Annotation:
-            def __init__(self, name):
+            def __init__(self, name, **kwargs):
                 self.name = name
+                seen.append(("made", name, kwargs))
 
             def __enter__(self):
                 seen.append(("enter", self.name))
@@ -391,13 +393,34 @@ class TestJaxListeners:
                 seen.append(("exit", self.name))
 
         monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
-        tracer = tracing.Tracer(str(tmp_path / "t.jsonl"))
-        with tracer.span("trial.init"), tracer.span("inner"):
+        path = str(tmp_path / "t.jsonl")
+        tracer = tracing.Tracer(path)
+        with tracer.span("trial.init", trial="t-1"), tracer.span("inner"):
+            pass
+        with tracer.span("orch.settle"):
             pass
         tracer.close()
+        ids = {r["name"]: r["id"] for r in tracing.read_journal(path)}
         assert seen == [
-            ("enter", "trial.init"), ("enter", "inner"), ("exit", "inner"), ("exit", "trial.init")
+            ("made", "trial.init", {"id": ids["trial.init"], "trial": "t-1"}),
+            ("enter", "trial.init"),
+            # a child takes its parent's trial before the annotation is made
+            ("made", "inner", {"id": ids["inner"], "trial": "t-1"}),
+            ("enter", "inner"), ("exit", "inner"), ("exit", "trial.init"),
+            ("made", "orch.settle", {"id": ids["orch.settle"]}),
+            ("enter", "orch.settle"), ("exit", "orch.settle"),
         ]
+
+    def test_current_span_is_the_innermost_of_the_ambient_tracer(self, tmp_path):
+        tracer = tracing.Tracer(str(tmp_path / "t.jsonl"))
+        assert tracing.current_span() is tracing._NULL_SPAN  # no ambient tracer
+        with tracing.use_tracer(tracer):
+            assert tracing.current_span() is tracing._NULL_SPAN  # nothing open
+            with tracing.span("outer") as outer, tracing.span("inner") as inner:
+                assert tracing.current_span() is inner
+                tracing.current_span().add("journal_s", 0.25)
+            assert (outer.counters, inner.counters) == ({"journal_s": 0.25},) * 2
+        tracer.close()
 
 
 class TestChromeTraceExport:
@@ -605,3 +628,67 @@ class TestOrchestratorTracing:
                 assert init["args"]["programs"] == "reused"
                 assert train["args"]["jit_programs"] == 0
                 assert not any(r["name"].startswith("jit.") for r in mine)
+
+    @pytest.mark.parametrize("async_orch", [True, False], ids=["async-loops", "sync-loop"])
+    def test_the_hand_over_between_two_trials_has_spans(self, tmp_path, async_orch):
+        """Both loops journal, once a trial and under the trial's name:
+        ``orch.dispatch`` (``slot_free_s``, ``journal_s``), ``orch.settle``
+        (``journal_s``), ``trial.setup`` and ``trial.finalize`` below
+        ``trial``, ``trial.programs`` below ``trial.init``."""
+        from katib_tpu.models.transformer import transformer_trial
+        from katib_tpu.orchestrator.orchestrator import Orchestrator
+
+        sizes = dict(
+            d_model=16, n_heads=2, n_layers=1, seq_len=8, vocab_size=16,
+            n_seq=32, batch_size=2, steps=3, lr=0.001,
+        )
+        spec = ExperimentSpec(
+            name="trace-handover",
+            algorithm=AlgorithmSpec(name="random"),
+            objective=ObjectiveSpec(
+                type=ObjectiveType.MINIMIZE, objective_metric_name="eval_loss"
+            ),
+            parameters=[
+                ParameterSpec(k, ParameterType.DISCRETE, FeasibleSpace(list=[str(v)]))
+                for k, v in sizes.items()
+            ],
+            max_trial_count=3,
+            parallel_trial_count=1,
+            train_fn=transformer_trial,
+            async_orch=async_orch,
+        )
+        exp = Orchestrator(workdir=str(tmp_path)).run(spec)
+        assert [t.condition.value for t in exp.trials.values()] == ["Succeeded"] * 3
+
+        recs = tracing.read_journal(tracing.trace_path(str(tmp_path), "trace-handover"))
+        by_id = {r["id"]: r for r in recs}
+        parent = lambda r: by_id[r["parent"]]["name"] if "parent" in r else None  # noqa: E731
+        for trial in exp.trials:
+            mine = {}
+            for r in recs:
+                if r.get("args", {}).get("trial") == trial:
+                    mine.setdefault(r["name"], []).append(r)
+            for name in ("orch.dispatch", "orch.settle", "trial.setup", "trial.finalize", "trial.programs"):
+                assert len(mine[name]) == 1, (trial, name)
+            (dispatch,), (settle,) = mine["orch.dispatch"], mine["orch.settle"]
+            assert dispatch["args"]["members"] == settle["args"]["members"] == 1
+            assert dispatch["args"]["slot_free_s"] >= 0
+            assert dispatch["args"]["journal_s"] > 0 and settle["args"]["journal_s"] > 0
+            assert parent(dispatch) is None and parent(settle) is None
+            (span,) = mine["trial"]
+            assert parent(mine["trial.setup"][0]) == parent(mine["trial.finalize"][0]) == "trial"
+            assert parent(mine["trial.programs"][0]) == "trial.init"
+            # in order: dispatched, set up, trained, read back, settled
+            (train,) = mine["train_fn"]
+            end = lambda r: r["ts"] + r["dur"]  # noqa: E731
+            assert dispatch["ts"] <= span["ts"] <= mine["trial.setup"][0]["ts"]
+            assert end(mine["trial.setup"][0]) <= train["ts"] + 1e-6
+            assert end(train) <= mine["trial.finalize"][0]["ts"] + 1e-6
+            assert end(mine["trial.finalize"][0]) <= end(span) + 1e-6 <= end(settle) + 2e-6
+            assert end(mine["trial.programs"][0]) <= end(mine["trial.init"][0]) + 1e-6
+        # ``trace summary``: the two new children count against ``trial``'s
+        # own time, which is what no span below it accounts for
+        rows = {row["name"]: row for row in tracing.summarize(recs)}
+        below = sum(rows[n]["total_s"] for n in ("trial.setup", "train_fn", "trial.finalize"))
+        assert rows["trial"]["self_s"] == pytest.approx(rows["trial"]["total_s"] - below, abs=1e-5)
+        assert rows["trial.setup"]["total_s"] > 0 and rows["trial.finalize"]["total_s"] > 0
