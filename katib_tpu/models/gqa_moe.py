@@ -141,8 +141,8 @@ class GqaMoeLM(nn.Module):
 
     @property
     def attn_heads(self) -> int:
-        """Query heads: the kernels' grids walk one at a time (the dkv kernel
-        a key-value head's query heads in turn)."""
+        """Query heads: the kernels' grids walk one at a time (the backward a
+        key-value head's query heads in turn)."""
         return self.sizes.n_heads
 
     @property

@@ -66,6 +66,7 @@ from katib_tpu.models.looped import LoopedLM, LoopedSizes
 from katib_tpu.models.mla_moe import ROUTING, MlaMoeLM, MlaMoeSizes, expert_buffer
 from katib_tpu.ops.flash_attention import (
     flash_attention,
+    one_walk,
     plan_tiles,
     reference_attention,
     tile_visits,
@@ -197,17 +198,27 @@ def make_attention_fn(mesh=None, strategy: str = "ring", window: int | None = No
     return _sequence_parallel_attention(mesh, strategy)
 
 
+def _planned_attention(model, seq_len: int) -> tuple[int, int, bool]:
+    """The tiles the kernel plans for a trial's shapes, and whether its
+    backward is the single walk there (``flash_attention.one_walk``)."""
+    shape = (seq_len, seq_len, *model.attn_widths, jnp.dtype(model.dtype))
+    bq, bk = plan_tiles(*shape)
+    return bq, bk, one_walk(*shape, bq, bk)
+
+
 def attn_tiles(model, seq_len: int) -> str:
     """What a trial's attention runs, for the ``trial.init`` span: the flash
-    kernel's operand dtype and the tiles it plans for these shapes, ``dense``
-    where no kernel runs, ``seq-parallel`` over a mesh's ``seq`` axis."""
+    kernel's operand dtype, the tiles it plans for these shapes and the
+    backward it takes there (``"bfloat16 q512 k512, backward one walk"``;
+    ``"..., backward dq+dkv"`` where a head's accumulators do not fit);
+    ``dense`` where no kernel runs, ``seq-parallel`` over a mesh's ``seq``
+    axis."""
     if model.attn_fn is None or getattr(model.attn_fn, "kernel", None) is False:
         return "dense"
     if not hasattr(model.attn_fn, "kernel"):
         return "seq-parallel"
-    dtype = jnp.dtype(model.dtype)
-    bq, bk = plan_tiles(seq_len, seq_len, *model.attn_widths, dtype)
-    return f"{dtype.name} q{bq} k{bk}"
+    bq, bk, walk = _planned_attention(model, seq_len)
+    return f"{jnp.dtype(model.dtype).name} q{bq} k{bk}, backward {'one walk' if walk else 'dq+dkv'}"
 
 
 def attention_plan(model, batch: int, seq_len: int) -> tuple[dict, dict]:
@@ -219,10 +230,11 @@ def attention_plan(model, batch: int, seq_len: int) -> tuple[dict, dict]:
     (``"none"``: every activation is kept; ``"blocks"``: every block from its
     input; ``"blocks, keeps attn out+lse"``: but for the attention kernel's
     results, which ``remat_block`` keeps).  Counters, where the kernel runs:
-    ``attn_tiles_run`` (the tiles the three kernels' loops walk in one step:
-    forward, dq, dkv, every application of a layer, head and batch row) and
+    ``attn_tiles_run`` (the tiles the kernels' loops walk in one step: the
+    forward and the backward's one walk, or forward, dq and dkv where those
+    run; every application of a layer, head and batch row) and
     ``attn_tiles_needed`` (the least: the tiles of the planned size that hold
-    a visible pair)."""
+    a visible pair, once a walk)."""
     kinds = model.attn_kinds
     passes = getattr(model, "passes", 1)
     kernel = bool(getattr(model.attn_fn, "kernel", False))
@@ -238,10 +250,10 @@ def attention_plan(model, batch: int, seq_len: int) -> tuple[dict, dict]:
         attrs.update(attn_layers=f"{layers}, {passes} passes", passes=passes)
     if not kernel:
         return attrs, {}
-    bq, bk = plan_tiles(seq_len, seq_len, *model.attn_widths, jnp.dtype(model.dtype))
+    bq, bk, walk = _planned_attention(model, seq_len)
     run = needed = 0
     for window, _positions, n in kinds:
-        walked, least = tile_visits(seq_len, seq_len, bq, bk, True, window)
+        walked, least = tile_visits(seq_len, seq_len, bq, bk, True, window, walk=walk)
         run, needed = run + n * walked, needed + n * least
     rows = batch * model.attn_heads * passes
     return attrs, {"attn_tiles_run": rows * run, "attn_tiles_needed": rows * needed}

@@ -12,10 +12,17 @@ Design (FlashAttention-2 style, adapted to the TPU memory hierarchy):
   output) accumulators in f32.  Emits the per-row logsumexp so
   sequence-parallel ring attention (``katib_tpu.parallel.ring_attention``)
   can merge partial results from other sequence shards.
-- backward: two kernels — dq over q tiles, dk/dv over k tiles — that
-  recompute probabilities from the saved logsumexp instead of storing the
-  score matrix (rematerialisation trades FLOPs for HBM, the TPU-native
-  default).
+- backward: ONE kernel that walks every visible (q tile, k tile) pair once
+  (``_walk_kernel``): it recomputes the probabilities from the saved
+  logsumexp instead of storing the score matrix (rematerialisation trades
+  FLOPs for HBM, the TPU-native default), forms ``dS`` once and takes dq, dk
+  and dv from them: five products a pair.  It walks as the dq kernel does (a
+  q tile against a head's K and V) and dk, dv add up in two float32
+  accumulators a head long, in VMEM.  Where those do not fit (``one_walk``:
+  the estimate of ``vmem_bytes`` against ``VMEM_LIMIT_BYTES``, from the
+  call's shapes, dtype and tiles alone; a 16384-key head of width 128 fits, a
+  32768-key one does not) two kernels run instead, dq over q tiles and dk/dv
+  over k tiles, each walking the pairs: seven products a pair.
 - both are exposed through one ``jax.custom_vjp`` so ``jax.grad`` composes
   with jit/shard_map/scan.  Its forward rule names the kernel's two results
   (``KERNEL_RESULTS``), and ``remat_block`` rematerialises a flax module
@@ -40,8 +47,9 @@ budget.  The row statistics (logsumexp, delta) cross HBM lane-dense,
 
 Keys and values may have fewer heads than the queries (grouped-query
 attention: query head ``j`` reads key-value head ``j // group``); ``dk`` and
-``dv`` are then summed over a group's query heads inside the dkv kernel, in
-float32, over one more grid axis.  ``window``: a query sees the ``window``
+``dv`` are then summed over a group's query heads inside the kernel, in
+float32: the walk's accumulators take every head of the group, the dkv kernel
+sums them over one more grid axis.  ``window``: a query sees the ``window``
 newest keys up to itself; tiles wholly outside the band are skipped by the
 loop bounds as tiles wholly above the diagonal are (``tile_visits`` counts
 what the loops walk against what holds a visible pair).
@@ -68,8 +76,10 @@ _MASK_VALUE = -1e30  # large-negative instead of -inf inside kernels (no NaNs)
 
 #: what a kernel may hold in VMEM (``vmem_limit_bytes``; the compiler's own
 #: default is 16 MiB of a v5e core's 128 MiB).  ``plan_tiles`` keeps its
-#: estimate of a kernel's blocks under half of it: the other half is the
-#: compiler's, for the temporaries of a tile's elementwise work.
+#: estimate of the streaming kernels' blocks under half of it: the other half
+#: is the compiler's, for the temporaries of a tile's elementwise work.  The
+#: single walk's blocks are a head long whatever the tiles, and it runs where
+#: its estimate is within the limit itself (``one_walk``).
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES // 2
 #: tiles in order of preference, read on the chip at the benchmark's shapes
@@ -98,44 +108,70 @@ def _padded(rows: int, cols: int, itemsize: int) -> int:
     return -(-rows // sublanes) * sublanes * -(-cols // 128) * 128 * itemsize
 
 
-def vmem_bytes(seq_q: int, seq_k: int, d_k: int, d_v: int, dtype, bq: int, bk: int) -> int:
-    """Estimate of the largest of the three kernels' VMEM blocks at tiles
-    ``(bq, bk)``: pipelined blocks twice (double-buffered), the float32
-    accumulators, and four float32 tiles of scores (s, p, dp, ds)."""
+def _kernel_bytes(seq_q, seq_k, d_k, d_v, dtype, bq, bk) -> dict[str, int]:
+    """Estimate of each kernel's VMEM blocks at tiles ``(bq, bk)``, the score
+    tiles aside: pipelined blocks twice (double-buffered) and the float32
+    accumulators."""
     size = jnp.dtype(dtype).itemsize
 
     def row(n):  # a lane-dense row of statistics
         return _padded(1, n, 4)
 
-    scores = 4 * _padded(bq, bk, 4)
-    forward = 2 * (
-        _padded(bq, d_k, size) + _padded(seq_k, d_k, size) + _padded(seq_k, d_v, size)
-        + _padded(bq, d_v, size) + row(bq)
-    ) + _padded(d_v, bq, 4)
-    dq = 2 * (
-        2 * _padded(bq, d_k, size) + _padded(seq_k, d_k, size) + _padded(seq_k, d_v, size)
-        + _padded(bq, d_v, size) + 2 * row(bq)
-    ) + _padded(d_k, bq, 4)
-    dkv = 2 * (
-        _padded(seq_q, d_k, size) + _padded(seq_q, d_v, size) + 2 * row(seq_q)
-        + 2 * _padded(bk, d_k, size) + 2 * _padded(bk, d_v, size)
-    ) + _padded(bk, d_k, 4) + _padded(bk, d_v, 4)
-    return scores + max(forward, dq, dkv)
+    kv_head = _padded(seq_k, d_k, size) + _padded(seq_k, d_v, size)  # a head's K and V, or dk and dv
+    q_tiles = 2 * _padded(bq, d_k, size) + _padded(bq, d_v, size) + 2 * row(bq)  # q, dq, dO, lse, dmd
+    return {
+        "forward": 2 * (_padded(bq, d_k, size) + kv_head + _padded(bq, d_v, size) + row(bq))
+        + _padded(d_v, bq, 4),
+        "dq": 2 * (q_tiles + kv_head) + _padded(d_k, bq, 4),
+        "dkv": 2 * (
+            _padded(seq_q, d_k, size) + _padded(seq_q, d_v, size) + 2 * row(seq_q)
+            + 2 * _padded(bk, d_k, size) + 2 * _padded(bk, d_v, size)
+        ) + _padded(bk, d_k, 4) + _padded(bk, d_v, 4),
+        # the dq kernel's blocks, a head's dk and dv on the way out, and the
+        # two whole-head accumulators
+        "walk": 2 * (q_tiles + 2 * kv_head) + _padded(d_k, bq, 4)
+        + _padded(seq_k, d_k, 4) + _padded(seq_k, d_v, 4),
+    }
+
+
+def one_walk(seq_q: int, seq_k: int, d_k: int, d_v: int, dtype, bq: int, bk: int) -> bool:
+    """Whether the backward at these shapes and tiles is the single walk: where
+    its blocks (a head's K, V, dk, dv twice and two float32 accumulators as
+    long as the keys) and the score tiles are within ``VMEM_LIMIT_BYTES``, what
+    the kernel asks of the compiler; where not, the dq and dkv kernels run.
+    The estimate counts everything the kernel allocates, so it is held to the
+    limit, not to the budget the tiles are planned under: the v5e's compiler
+    accepts the walk up to an estimate of 77 MiB under this limit and refuses
+    it at 89 (PERF.md section 6, PR 40; ``tests/test_chip_compile.py``
+    compiles the rule's edge in bfloat16 and float32, at 512 and 1024 tiles)."""
+    return vmem_bytes(seq_q, seq_k, d_k, d_v, dtype, bq, bk, "walk") <= VMEM_LIMIT_BYTES
+
+
+def vmem_bytes(seq_q: int, seq_k: int, d_k: int, d_v: int, dtype, bq: int, bk: int, backward: str) -> int:
+    """Estimate of the largest VMEM need of the kernels that run at tiles
+    ``(bq, bk)`` where the backward is ``backward`` (``"walk"`` or
+    ``"dq+dkv"``): four float32 tiles of scores (s, p, dp, ds) and the largest
+    kernel's blocks, the forward's or the backward's (the larger of dq's and
+    dkv's where those run)."""
+    blocks = _kernel_bytes(seq_q, seq_k, d_k, d_v, dtype, bq, bk)
+    largest = blocks["walk"] if backward == "walk" else max(blocks["dq"], blocks["dkv"])
+    return 4 * _padded(bq, bk, 4) + max(blocks["forward"], largest)
 
 
 def plan_tiles(seq_q: int, seq_k: int, d_k: int, d_v: int, dtype) -> tuple[int, int]:
     """The (q tile, k tile) the kernels run where the caller names none, from
     what they can observe: the lengths, the widths and the operand dtype.
     The first pair of ``_Q_TILES`` x ``_K_TILES`` that divides the lengths and
-    whose ``vmem_bytes`` is within ``VMEM_BUDGET_BYTES``; a length that none
-    divides (or shorter than 128) is one tile."""
+    at which the blocks of the kernels that stream tiles (forward, dq, dkv:
+    ``vmem_bytes`` of that backward) are within ``VMEM_BUDGET_BYTES``; a length
+    that none divides (or shorter than 128) is one tile."""
 
     def candidates(seq, tiles):
         return [t for t in tiles if seq % t == 0] or [seq]
 
     pairs = [(bq, bk) for bq in candidates(seq_q, _Q_TILES) for bk in candidates(seq_k, _K_TILES)]
     for bq, bk in pairs:
-        if vmem_bytes(seq_q, seq_k, d_k, d_v, dtype, bq, bk) <= VMEM_BUDGET_BYTES:
+        if vmem_bytes(seq_q, seq_k, d_k, d_v, dtype, bq, bk, "dq+dkv") <= VMEM_BUDGET_BYTES:
             return bq, bk
     return pairs[-1]
 
@@ -156,14 +192,15 @@ def _block_sizes(q, k, v, block_q: int | None, block_k: int | None):
     return bq, bk
 
 
-def _pallas_call(kernel, *, grid, **kwargs):
-    """Batch, head and tile are parallel; a fourth grid axis (the query heads
-    of a key-value head, in the dkv kernel) accumulates into one output block."""
+def _pallas_call(kernel, *, grid, parallel=3, **kwargs):
+    """The first ``parallel`` grid axes are parallel (batch, head, tile); the
+    rest accumulate into blocks that stay in VMEM across them: the query heads
+    of a key-value head in the dkv kernel, they and the q tiles in the walk."""
     return pl.pallas_call(
         kernel,
         grid=grid,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * 3 + ("arbitrary",) * (len(grid) - 3),
+            dimension_semantics=("parallel",) * parallel + ("arbitrary",) * (len(grid) - parallel),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         **kwargs,
@@ -235,20 +272,22 @@ def _visible(shape, k0, q0, window):
 
 @functools.lru_cache(maxsize=64)
 def tile_visits(
-    seq_q: int, seq_k: int, bq: int, bk: int, causal: bool = True, window: int | None = None
+    seq_q: int, seq_k: int, bq: int, bk: int, causal: bool = True, window: int | None = None, *, walk: bool
 ) -> tuple[int, int]:
-    """For one batch row and query head: the (q tile, k tile) pairs the three
-    kernels' loops walk (forward and dq ``_k_tile_range`` of every q tile, dkv
-    ``_q_tile_range`` of every k tile: the kernels' own bounds, evaluated),
-    and three times the tiles of this size that hold a visible pair, the least
-    any walk can make."""
+    """For one batch row and query head: the (q tile, k tile) pairs the
+    kernels' loops walk in a forward and a backward (their own bounds,
+    evaluated), and the least any such walks can make: the tiles of this size
+    that hold a visible pair, once a walk.  Two walks where the backward is
+    the single ``walk`` (forward and it: ``_k_tile_range`` of every q tile,
+    twice), three where dq and dkv run (dkv: ``_q_tile_range`` of every k
+    tile)."""
     n_qb, n_kb, shift = seq_q // bq, seq_k // bk, seq_k - seq_q
 
     def walked(tile_range, n_tiles, n_across):  # a bound may be one number for all tiles
         first, end = tile_range(jnp.arange(n_tiles), bq, bk, n_across, shift, causal, window)
         return int(jnp.sum(jnp.broadcast_to(jnp.maximum(end - first, 0), (n_tiles,))))
 
-    per_q_tile, per_k_tile = walked(_k_tile_range, n_qb, n_kb), walked(_q_tile_range, n_kb, n_qb)
+    per_q_tile = walked(_k_tile_range, n_qb, n_kb)
     # a tile holds a visible pair when its last row reaches its first key and
     # its first row's window still reaches its last key
     last_row = (np.arange(n_qb)[:, None] + 1) * bq - 1 + shift
@@ -259,7 +298,9 @@ def tile_visits(
         holds = first_key <= last_row
         if window is not None:
             holds &= last_key > first_row - window
-    return 2 * per_q_tile + per_k_tile, 3 * int(holds.sum())
+    if walk:
+        return 2 * per_q_tile, 2 * int(holds.sum())
+    return 2 * per_q_tile + walked(_q_tile_range, n_kb, n_qb), 3 * int(holds.sum())
 
 
 # ---------------------------------------------------------------------------
@@ -442,21 +483,118 @@ def _dkv_kernel(
         write(dk_sum[...], dv_sum[...])
 
 
+def _walk_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dq_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+    *, sm_scale, causal, block_k, shift, window,
+):
+    """The whole backward in one walk: one q tile of one query head against
+    the k tiles it sees, as the dq kernel (its scores, transposed, and its
+    ``dmd``), and ``p`` and ``dS`` of every pair feed all three gradients.
+    dq stays in registers over the k tiles; dk and dv add into two whole-head
+    float32 accumulators (VMEM scratch, ``[S_k, d]``) that every q tile and
+    every query head of the key-value head share: cleared at the head's first
+    grid step, written out at its last."""
+    bq, d = q_ref.shape[-2], q_ref.shape[-1]
+    n_kb = k_ref.shape[-2] // block_k
+    member, qi = pl.program_id(2), pl.program_id(3)
+
+    @pl.when((member == 0) & (qi == 0))
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    q = q_ref[0, 0, :, :]
+    do = do_ref[0, 0, :, :]
+    lse = lse_ref[0, 0, :, :]  # [1, bq]
+    dmd = dmd_ref[0, 0, :, :]
+
+    def body(j, dq_acc):
+        start = pl.multiple_of(j * block_k, block_k)
+        rows = pl.ds(start, block_k)
+        k = k_ref[0, 0, rows, :]
+        v = v_ref[0, 0, rows, :]
+        s = sm_scale * jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+        e = s - lse
+        if causal:
+            e = jnp.where(_visible(e.shape, start, qi * bq + shift, window), e, _MASK_VALUE)
+        p = jnp.exp(e)  # [block_k, bq]
+        # dv's product before dp's, as the dkv kernel has them: the other
+        # order read 0.4-1.0% slower a step on the chip (PERF.md section 6, PR 40)
+        dv_acc[rows, :] += jnp.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+        ds = (p * (dp - dmd)).astype(k.dtype)
+        dk_acc[rows, :] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        return dq_acc + jax.lax.dot_general(k, ds, _TN, preferred_element_type=jnp.float32)
+
+    first, end = _k_tile_range(qi, bq, block_k, n_kb, shift, causal, window)
+    dq = jax.lax.fori_loop(first, end, body, jnp.zeros((d, bq), jnp.float32))
+    dq_ref[0, 0, :, :] = (sm_scale * dq).T.astype(dq_ref.dtype)
+
+    @pl.when((member == pl.num_programs(2) - 1) & (qi == pl.num_programs(3) - 1))
+    def _():
+        dk_ref[0, 0, :, :] = (sm_scale * dk_acc[...]).astype(dk_ref.dtype)
+        dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
+
+
 def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, interpret, window):
     b, h, sq, d = q.shape
-    h_kv, sk, d_v = k.shape[1], k.shape[2], v.shape[3]
+    sk, d_v = k.shape[2], v.shape[3]
     bq, bk = _block_sizes(q, k, v, block_q, block_k)
-    group = _group_size(q, k)
-    kv = _kv_head(group)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     dmd = delta - dlse.astype(jnp.float32)  # [b, h, sq]
-    lse4 = lse[:, :, None, :]
-    dmd4 = dmd[:, :, None, :]
+    operands = (q, k, v, do, lse[:, :, None, :], dmd[:, :, None, :])
+    kernel = dict(sm_scale=sm_scale, causal=causal, shift=sk - sq, window=window)
+    backward = _bwd_walk if one_walk(sq, sk, d, d_v, q.dtype, bq, bk) else _bwd_split
+    return backward(operands, kernel, bq, bk, interpret)
 
+
+def _bwd_walk(operands, kernel, bq, bk, interpret):
+    """dq, dk, dv from one kernel.  Grid (batch, key-value head, query head of
+    it, q tile), the last two in order: a key-value head's K, V, dk and dv
+    blocks and the accumulators stay while its query heads' q tiles pass."""
+    q, k, v = operands[:3]
+    b, _, sq, d = q.shape
+    h_kv, sk, d_v = k.shape[1], k.shape[2], v.shape[3]
+    group = _group_size(q, k)
+    q_tile = lambda i, j, m, l: (i, j * group + m, l, 0)  # noqa: E731
+    q_row = lambda i, j, m, l: (i, j * group + m, 0, l)  # noqa: E731
+    kv_head = lambda i, j, m, l: (i, j, 0, 0)  # noqa: E731
+    return _pallas_call(
+        functools.partial(_walk_kernel, block_k=bk, **kernel),
+        grid=(b, h_kv, group, sq // bq),
+        parallel=2,
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, d), q_tile),
+            pl.BlockSpec((1, 1, sk, d), kv_head),
+            pl.BlockSpec((1, 1, sk, d_v), kv_head),
+            pl.BlockSpec((1, 1, bq, d_v), q_tile),
+            pl.BlockSpec((1, 1, 1, bq), q_row),
+            pl.BlockSpec((1, 1, 1, bq), q_row),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, bq, d), q_tile),
+            pl.BlockSpec((1, 1, sk, d), kv_head),
+            pl.BlockSpec((1, 1, sk, d_v), kv_head),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((sk, d), jnp.float32), pltpu.VMEM((sk, d_v), jnp.float32)],
+        interpret=interpret,
+    )(*operands)
+
+
+def _bwd_split(operands, kernel, bq, bk, interpret):
+    """dq from one kernel, dk and dv from another: each walks the pairs."""
+    q, k, v = operands[:3]
+    b, h, sq, d = q.shape
+    h_kv, sk, d_v = k.shape[1], k.shape[2], v.shape[3]
+    group = _group_size(q, k)
+    kv = _kv_head(group)
     dq = _pallas_call(
-        functools.partial(
-            _dq_kernel, sm_scale=sm_scale, causal=causal, block_k=bk, shift=sk - sq, window=window
-        ),
+        functools.partial(_dq_kernel, block_k=bk, **kernel),
         grid=(b, h, sq // bq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda i, j, l: (i, j, l, 0)),
@@ -469,7 +607,7 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda i, j, l: (i, j, l, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(q, k, v, do, lse4, dmd4)
+    )(*operands)
 
     # one grid point a (k tile, query head): with a group, the heads of a
     # key-value head on a fourth axis, innermost, so that its dk and dv blocks
@@ -485,10 +623,7 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
         kv_tile = lambda i, j, l, m: (i, j, l, 0)  # noqa: E731
         scratch = [pltpu.VMEM((bk, d), jnp.float32), pltpu.VMEM((bk, d_v), jnp.float32)]
     dk, dv = _pallas_call(
-        functools.partial(
-            _dkv_kernel, sm_scale=sm_scale, causal=causal, block_q=bq, shift=sk - sq,
-            window=window, group=group,
-        ),
+        functools.partial(_dkv_kernel, block_q=bq, group=group, **kernel),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, sq, d), q_head),
@@ -508,7 +643,7 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
         ],
         scratch_shapes=scratch,
         interpret=interpret,
-    )(q, k, v, do, lse4, dmd4)
+    )(*operands)
     return dq, dk, dv
 
 

@@ -112,27 +112,40 @@ class TestFlashAttention:
             np.testing.assert_allclose(a, b, atol=2e-5)
 
 
-# (seq_q, seq_k, d_k, d_v, causal, block_q, block_k).  The causal square has
-# eight k tiles to a q tile: q tile 1 (rows 64-127) runs k tiles 0-3 (0 and 1
-# wholly visible, 2 and 3 crossed by the diagonal) and skips 4-7.
+# (seq_q, seq_k, d_k, d_v, causal, block_q, block_k[, key-value heads of the two
+# query heads, window]).  The causal square has eight k tiles to a q tile: q
+# tile 1 (rows 64-127) runs k tiles 0-3 (0 and 1 wholly visible, 2 and 3
+# crossed by the diagonal) and skips 4-7.
 KERNEL_CASES = {
     "causal-square": (256, 256, 16, 16, True, 64, 32),
     "q-shorter": (128, 256, 16, 16, True, 64, 32),
     "q-longer": (256, 128, 16, 16, True, 32, 64),
     "narrow-values": (128, 128, 48, 32, True, 32, 32),
     "full": (128, 128, 16, 16, False, 64, 32),
+    # both query heads read one key-value head: its dk and dv sum the two
+    "grouped": (128, 128, 16, 16, True, 32, 32, 1),
+    # the 40 newest keys: q tile 3 (rows 96-127) walks k tiles 1-3 and skips 0
+    "window": (128, 128, 16, 16, True, 32, 32, 2, 40),
+    "grouped-window-q-shorter": (64, 128, 16, 32, True, 32, 32, 1, 40),
 }
+
+
+def _case(name):
+    """A case of ``KERNEL_CASES`` whole: key-value heads 2 and no window
+    where it names none."""
+    case = KERNEL_CASES[name]
+    return case + (2, None)[len(case) - 7 :]
 
 
 def _f32(x):
     return x.astype(jnp.float32)
 
 
-def _kernel_inputs(sq, sk, d_k, d_v, dtype, seed=5):
+def _kernel_inputs(sq, sk, d_k, d_v, dtype, seed=5, h_kv=2):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     q = jax.random.normal(ks[0], (1, 2, sq, d_k), dtype)
-    k = jax.random.normal(ks[1], (1, 2, sk, d_k), dtype)
-    v = jax.random.normal(ks[2], (1, 2, sk, d_v), dtype)
+    k = jax.random.normal(ks[1], (1, h_kv, sk, d_k), dtype)
+    v = jax.random.normal(ks[2], (1, h_kv, sk, d_v), dtype)
     w_o = jax.random.normal(ks[3], (1, 2, sq, d_v), jnp.float32)
     w_lse = jax.random.normal(ks[4], (1, 2, sq), jnp.float32)
     return q, k, v, w_o, w_lse
@@ -166,24 +179,28 @@ def _kernel_tolerances(dtype, want):
 
 
 class TestKernelNumerics:
-    """Tier-1: the three kernels in interpret mode against the dense reference
-    computed in float32 from the same inputs."""
+    """Tier-1: the kernels in interpret mode against the dense reference
+    computed in float32 from the same inputs.  At these sizes the backward is
+    the single walk (``one_walk``); dq + dkv are called directly."""
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
     @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
     def test_matches_dense_in_float32(self, case, dtype):
-        sq, sk, d_k, d_v, causal, bq, bk = KERNEL_CASES[case]
-        q, k, v, w_o, w_lse = _kernel_inputs(sq, sk, d_k, d_v, dtype)
-        got = _outputs_and_grads(
-            lambda q, k, v: flash_attention_with_lse(q, k, v, causal, None, bq, bk, True),
+        sq, sk, d_k, d_v, causal, bq, bk, h_kv, window = _case(case)
+        q, k, v, w_o, w_lse = _kernel_inputs(sq, sk, d_k, d_v, dtype, h_kv=h_kv)
+        grad = lambda q, k, v: _outputs_and_grads(  # noqa: E731
+            lambda q, k, v: flash_attention_with_lse(q, k, v, causal, None, bq, bk, True, window),
             q, k, v, w_o, w_lse,
         )
+        # forward and the one walk, the latter over (batch, key-value head, its query heads, q tiles)
+        assert f"grid=(1, {h_kv}, {2 // h_kv}, {sq // bq})" in str(jax.make_jaxpr(grad)(q, k, v))
+        got = grad(q, k, v)
         want = _outputs_and_grads(
-            lambda q, k, v: reference_attention_with_lse(q, k, v, causal),
+            lambda q, k, v: reference_attention_with_lse(q, k, v, causal, None, window),
             _f32(q), _f32(k), _f32(v), w_o, w_lse,
         )
         assert got[0].dtype == got[2].dtype == dtype and got[1].dtype == jnp.float32
-        assert got[0].shape == (1, 2, sq, d_v) and got[4].shape == v.shape
+        assert got[0].shape == (1, 2, sq, d_v) and got[3].shape == k.shape and got[4].shape == v.shape
         seen = np.asarray(want[1]) > -1e20
         if sq > sk:  # rows before the diagonal: output 0, log-sum-exp the mask value
             assert not seen[..., : sq - sk].any() and seen[..., sq - sk :].all()
@@ -191,6 +208,31 @@ class TestKernelNumerics:
             np.testing.assert_array_equal(got[1][..., : sq - sk], want[1][..., : sq - sk])
         for name, g, w, tol in zip(("o", "lse", "dq", "dk", "dv"), got, want, _kernel_tolerances(dtype, want)):
             np.testing.assert_allclose(_f32(g), w, rtol=0, atol=tol, err_msg=name)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+    @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+    def test_one_walk_equals_dq_and_dkv(self, case, dtype):
+        """The two backwards on the same operands (a cotangent on the
+        log-sum-exp among them): both round the same ``p`` and ``dS``, so they
+        differ by the order of float32 sums, and by the last bit of a
+        bfloat16 result where that tips its rounding."""
+        from katib_tpu.ops import flash_attention as fa
+
+        sq, sk, d_k, d_v, causal, bq, bk, h_kv, window = _case(case)
+        q, k, v, w_o, w_lse = _kernel_inputs(sq, sk, d_k, d_v, dtype, h_kv=h_kv)
+        assert fa.one_walk(sq, sk, d_k, d_v, dtype, bq, bk)
+        o, lse = flash_attention_with_lse(q, k, v, causal, None, bq, bk, True, window)
+        do = w_o.astype(dtype)
+        dmd = jnp.sum(_f32(do) * _f32(o), axis=-1) - w_lse  # as _bwd: delta less the lse's cotangent
+        operands = (q, k, v, do, lse[:, :, None, :], dmd[:, :, None, :])
+        kernel = dict(sm_scale=d_k**-0.5, causal=causal, shift=sk - sq, window=window)
+        walk = fa._bwd_walk(operands, kernel, bq, bk, True)
+        split = fa._bwd_split(operands, kernel, bq, bk, True)
+        for name, g, w, like in zip(("dq", "dk", "dv"), walk, split, (q, k, v)):
+            assert g.shape == w.shape == like.shape and g.dtype == w.dtype == dtype
+            top = float(jnp.max(jnp.abs(_f32(w))))
+            tol = 1e-5 if dtype == jnp.float32 else 2.0**-8 * top
+            np.testing.assert_allclose(_f32(g), _f32(w), rtol=0, atol=tol, err_msg=name)
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
     def test_planned_tiles_equal_explicit_128(self, dtype):
@@ -238,9 +280,53 @@ class TestTilePlan:
         sublanes = 8 * 4 // jnp.dtype(dtype).itemsize
         assert bq == sq or (bq % 128 == 0 and bq % sublanes == 0)
         assert bk == sk or bk % sublanes == 0
-        assert fa.vmem_bytes(sq, sk, d_k, d_v, dtype, bq, bk) <= fa.VMEM_BUDGET_BYTES < fa.VMEM_LIMIT_BYTES
+        # the tiles are planned for the kernels that stream them; what runs
+        # (the single walk wherever its accumulators fit) is within the limit
+        assert fa.vmem_bytes(sq, sk, d_k, d_v, dtype, bq, bk, "dq+dkv") <= fa.VMEM_BUDGET_BYTES
+        runs = "walk" if fa.one_walk(sq, sk, d_k, d_v, dtype, bq, bk) else "dq+dkv"
+        assert fa.vmem_bytes(sq, sk, d_k, d_v, dtype, bq, bk, runs) <= fa.VMEM_LIMIT_BYTES == 2 * fa.VMEM_BUDGET_BYTES
         if min(sq, sk) >= 1024 and sq % 128 == 0:
             assert bq >= 256 and bk >= 128  # more rows streamed per operand loaded than the old 128
+
+    @pytest.mark.parametrize(
+        "shape,mib",
+        [
+            ((1024, 1024, 64, 64), 7.9), ((4096, 4096, 192, 128), 23.7), ((4096, 4096, 128, 128), 17.1),
+            ((16384, 16384, 128, 128), 53.1),
+        ],
+        ids=["gpt2-small", "kanana-2-30b-a3b-ep8", "ouro-2.6b-l6", "smallthinker-21b-a3b-ep8"],
+    )
+    def test_the_cells_take_the_single_walk(self, shape, mib):
+        """A head's K, V, dk, dv and the two float32 accumulators beside the
+        score tiles: inside the budget at three cells' shapes, inside the
+        limit at the fourth's 16384 keys."""
+        from katib_tpu.ops import flash_attention as fa
+
+        tiles = plan_tiles(*shape, jnp.bfloat16)
+        assert tiles == (512, 512) and fa.one_walk(*shape, jnp.bfloat16, *tiles)
+        walk = fa.vmem_bytes(*shape, jnp.bfloat16, *tiles, "walk")
+        assert walk / 2**20 == pytest.approx(mib, abs=0.05)
+        assert walk <= (fa.VMEM_BUDGET_BYTES if shape[0] <= 4096 else fa.VMEM_LIMIT_BYTES)
+        assert walk > fa.vmem_bytes(*shape, jnp.bfloat16, *tiles, "dq+dkv")
+
+    @pytest.mark.parametrize("seq,backward", [(16384, "one walk"), (32768, "dq+dkv")])
+    def test_the_shapes_decide_which_backward_runs(self, seq, backward):
+        """Twice the keys are twice the accumulators: over the limit, and
+        ``_bwd`` lowers to dq and dkv as it did (shapes only: nothing runs)."""
+        from katib_tpu.ops import flash_attention as fa
+
+        shape = (seq, seq, 128, 128, jnp.bfloat16)
+        tiles = plan_tiles(*shape)
+        walks = fa.one_walk(*shape, *tiles)
+        assert walks == (backward == "one walk")
+        assert (fa.vmem_bytes(*shape, *tiles, "walk") <= fa.VMEM_LIMIT_BYTES) == walks
+        qkv = [jax.ShapeDtypeStruct((1, h, seq, 128), jnp.bfloat16) for h in (4, 2, 2)]
+        grad = jax.grad(lambda q, k, v: flash_attention(q, k, v, interpret=True).astype(jnp.float32).sum(), (0, 1, 2))
+        jaxpr = str(jax.make_jaxpr(grad)(*qkv))
+        n_q, n_k = seq // tiles[0], seq // tiles[1]
+        grids = {"forward": f"grid=(1, 4, {n_q})", "walk": f"grid=(1, 2, 2, {n_q})", "dkv": f"grid=(1, 2, {n_k}, 2)"}
+        counts = {name: jaxpr.count(grid) for name, grid in grids.items()}
+        assert counts == ({"forward": 1, "walk": 1, "dkv": 0} if walks else {"forward": 2, "walk": 0, "dkv": 1})
 
     def test_explicit_tiles_pass_through(self):
         q, k, v, _, _ = _kernel_inputs(256, 256, 16, 16, jnp.float32)
@@ -432,7 +518,8 @@ class TestTrialPrograms:
         small = transformer.TransformerLM(
             vocab_size=50257, d_model=768, n_heads=12, attn_fn=transformer._flash_causal_attention
         )
-        assert transformer.attn_tiles(small, 1024) == "bfloat16 q%d k%d" % plan_tiles(1024, 1024, 64, 64, jnp.bfloat16)
+        assert transformer.attn_tiles(small, 1024) == "bfloat16 q512 k512, backward one walk"
+        assert plan_tiles(1024, 1024, 64, 64, jnp.bfloat16) == (512, 512)
         from katib_tpu.models.mla_moe import MlaMoeLM, MlaMoeSizes
 
         latent = MlaMoeLM(
@@ -440,7 +527,9 @@ class TestTrialPrograms:
             sizes=MlaMoeSizes(n_heads=32, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128),
             attn_fn=transformer._flash_causal_attention,
         )
-        assert transformer.attn_tiles(latent, 4096) == "bfloat16 q%d k%d" % plan_tiles(4096, 4096, 192, 128, jnp.bfloat16)
+        assert transformer.attn_tiles(latent, 4096) == "bfloat16 q%d k%d, backward one walk" % plan_tiles(4096, 4096, 192, 128, jnp.bfloat16)
+        # a head whose accumulators pass the kernel's VMEM limit keeps the two kernels, and says so
+        assert transformer.attn_tiles(latent, 32768) == "bfloat16 q%d k%d, backward dq+dkv" % plan_tiles(32768, 32768, 192, 128, jnp.bfloat16)
         assert transformer.attn_tiles(dataclasses.replace(small, attn_fn=lambda q, k, v: q), 1024) == "seq-parallel"
 
     def test_trial_init_names_how_the_loss_runs(self, tmp_path):

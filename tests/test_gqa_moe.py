@@ -133,15 +133,28 @@ class TestWindowedGroupedKernel:
             tol = 1e-5 * top if name == "lse" else 2.0**-6 * top
             np.testing.assert_allclose(_f32(g), x, rtol=0, atol=tol, err_msg=name)
 
-    def test_the_window_is_one_more_static_argument_of_the_same_kernels(self):
-        """No window and equal heads: a three-axis grid and no scratch, the
-        program the kernel lowered to before it learned either."""
+    @pytest.mark.parametrize("backward", ["one walk", "dq+dkv"])
+    def test_the_window_is_one_more_static_argument_of_the_same_kernels(self, backward, monkeypatch):
+        """No window and equal heads: the forward's three-axis grid and the
+        walk's four, a key-value head's query heads (one) and the q tiles in
+        order; where dq and dkv run (here: ``one_walk`` told to refuse), three
+        three-axis grids and no scratch, the program the kernel lowered to
+        before it learned of windows, groups or the walk."""
+        walk = backward == "one walk"
+        monkeypatch.setattr(fa, "one_walk", lambda *shape_and_tiles: walk)
         q, k, v, _, _ = _attention_inputs(2, 2, 16, 16)
         grad = jax.grad(lambda q, k, v: fa.flash_attention(q, k, v, block_q=32, block_k=32, interpret=True).sum(), (0, 1, 2))
         plain = str(jax.make_jaxpr(grad)(q, k, v))
-        assert plain.count("grid=(1, 2, 4)") == 3 and "arbitrary" not in plain  # nothing summed over a grid axis
         q7 = jnp.tile(q[:, :1], (1, 14, 1, 1))
         grouped = str(jax.make_jaxpr(grad)(q7, k, v))
+        if walk:
+            assert plain.count("grid=(1, 2, 4)") == 1 and plain.count("grid=(1, 2, 1, 4)") == 1
+            assert grouped.count("grid=(1, 14, 4)") == 1  # forward at the query heads
+            assert "grid=(1, 2, 7, 4)" in grouped  # the walk: a key-value head's 7 query heads, their q tiles innermost
+            # the heads of a group and the q tiles add into one pair of accumulators
+            assert plain.count("'arbitrary'") == grouped.count("'arbitrary'") == 2
+            return
+        assert plain.count("grid=(1, 2, 4)") == 3 and "arbitrary" not in plain  # nothing summed over a grid axis
         assert "grid=(1, 2, 4, 7)" in grouped  # dkv: a key-value head's 7 query heads innermost
         assert grouped.count("grid=(1, 14, 4)") == 2  # forward and dq at the query heads
         assert grouped.count("'arbitrary'") == 1  # that axis alone is summed over
@@ -193,16 +206,19 @@ class TestTileBounds:
                     assert (first[i], end[i]) == (live[0], live[-1] + 1) and live.size == end[i] - first[i]
                 else:
                     assert end[i] <= first[i]
-        run, needed = fa.tile_visits(sq, sk, bq, bk, True, window)
-        assert run == needed == 3 * int(holds.sum())
+        # forward and the one walk back; forward, dq and dkv where those run
+        assert fa.tile_visits(sq, sk, bq, bk, True, window, walk=True) == (2 * int(holds.sum()),) * 2
+        assert fa.tile_visits(sq, sk, bq, bk, True, window, walk=False) == (3 * int(holds.sum()),) * 2
 
     def test_counts_at_the_benchmark_cell(self):
         """16384 positions in 512 x 512 tiles: 528 tiles under the diagonal,
         252 of them inside a 4096-key window (9 a q tile from the ninth on)."""
         assert fa.plan_tiles(16384, 16384, 128, 128, jnp.bfloat16) == (512, 512)
-        assert fa.tile_visits(16384, 16384, 512, 512, True, None) == (3 * 528, 3 * 528)
-        assert fa.tile_visits(16384, 16384, 512, 512, True, 4096) == (3 * 252, 3 * 252)
-        assert fa.tile_visits(1024, 1024, 512, 512, False, None) == (12, 12)
+        assert fa.one_walk(16384, 16384, 128, 128, jnp.bfloat16, 512, 512)  # two walks a step: forward, backward
+        assert fa.tile_visits(16384, 16384, 512, 512, True, None, walk=True) == (2 * 528, 2 * 528)
+        assert fa.tile_visits(16384, 16384, 512, 512, True, 4096, walk=True) == (2 * 252, 2 * 252)
+        assert fa.tile_visits(16384, 16384, 512, 512, True, 4096, walk=False) == (3 * 252, 3 * 252)
+        assert fa.tile_visits(1024, 1024, 512, 512, False, None, walk=True) == (8, 8)
         # window layers that walked the whole triangle would read 1.64
         assert 4 * 528 / (528 + 3 * 252) == pytest.approx(1.645, abs=1e-3)
 
@@ -618,13 +634,13 @@ class TestNormalPath:
         )
         attrs, counters = transformer.attention_plan(cell, 1, 16384)
         assert attrs == {
-            "attn_layers": "full nope x1, window4096 rope x3", "attn_tiles": "bfloat16 q512 k512",
+            "attn_layers": "full nope x1, window4096 rope x3", "attn_tiles": "bfloat16 q512 k512, backward one walk",
             "remat": "blocks, keeps attn out+lse",
         }
-        assert counters == {"attn_tiles_run": 28 * 3 * (528 + 3 * 252), "attn_tiles_needed": 28 * 3 * (528 + 3 * 252)}
+        assert counters == {"attn_tiles_run": 28 * 2 * (528 + 3 * 252), "attn_tiles_needed": 28 * 2 * (528 + 3 * 252)}
         small = transformer.TransformerLM(vocab_size=50257, d_model=768, n_heads=12, n_layers=12, attn_fn=kernel(True))
         attrs, counters = transformer.attention_plan(small, 8, 1024)
-        assert attrs["attn_layers"] == "full learned x12" and counters["attn_tiles_run"] == counters["attn_tiles_needed"] == 8 * 12 * 12 * 9
+        assert attrs["attn_layers"] == "full learned x12" and counters["attn_tiles_run"] == counters["attn_tiles_needed"] == 8 * 12 * 12 * 6
         # and they reach the span as counters, the enclosing spans too
         path = str(tmp_path / "trace.jsonl")
         tracer = tracing.Tracer(path)
@@ -639,8 +655,8 @@ class TestNormalPath:
         tracer.close()
         records = {r["name"]: r["args"] for r in tracing.read_journal(path)}
         init = records["trial.init"]
-        assert init["attn_tiles"] == "float32 q256 k256" and init["attn_layers"] == "full nope x1, window128 rope x1"
-        assert init["attn_tiles_run"] == init["attn_tiles_needed"] == 2 * 2 * 3
+        assert init["attn_tiles"] == "float32 q256 k256, backward one walk" and init["attn_layers"] == "full nope x1, window128 rope x1"
+        assert init["attn_tiles_run"] == init["attn_tiles_needed"] == 2 * 2 * 2
         assert records["train_fn"]["attn_tiles_run"] == init["attn_tiles_run"]
 
     @pytest.mark.parametrize(

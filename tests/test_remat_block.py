@@ -73,20 +73,21 @@ def _traced_step(model, seq_len=256):
 
 class TestTheStepHoldsTheForwardKernelOnce:
     @pytest.mark.parametrize("family", ["mla_moe", "gqa_moe", "looped"])
-    def test_three_attention_kernels_a_layer_under_remat(self, family):
+    def test_two_attention_kernels_a_layer_under_remat(self, family):
         """The step lowered for a TPU (no chip and no compile: the text of
-        what the compiler would be handed): forward, dq and dkv of every layer
-        (the looped model's passes are a loop), no second forward."""
+        what the compiler would be handed): the forward and the backward's one
+        walk of every layer (the looped model's passes are a loop), no second
+        forward."""
         model = _models(_kernel)[family]
         traced = _traced_step(model)
         assert REMAT in str(traced.jaxpr)  # the blocks are rematerialised
-        assert traced.lower(lowering_platforms=("tpu",)).as_text().count("tpu_custom_call") == 3 * _layers(model)
+        assert traced.lower(lowering_platforms=("tpu",)).as_text().count("tpu_custom_call") == 2 * _layers(model)
 
     def test_gpt2_keeps_everything_and_is_not_rematerialised(self):
         model = _models(_kernel)["gpt2"]
         traced = _traced_step(model)
         jaxpr, text = str(traced.jaxpr), traced.lower(lowering_platforms=("tpu",)).as_text()
-        assert text.count("tpu_custom_call") == 3 * _layers(model)
+        assert text.count("tpu_custom_call") == 2 * _layers(model)
         assert REMAT not in jaxpr and "optimization_barrier" not in text
         # the names are in the program and lower to nothing
         assert all(name in jaxpr for name in KERNEL_RESULTS) and not any(name in text for name in KERNEL_RESULTS)
@@ -139,9 +140,9 @@ def test_block_under_the_helper_gives_the_plain_blocks_gradient(case):
     for want, got, again in zip(jax.tree.leaves(plain), jax.tree.leaves(kept), jax.tree.leaves(bare)):
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
         np.testing.assert_allclose(again, want, rtol=1e-6, atol=1e-6)
-    # forward, dq, dkv; a bare nn.remat runs the forward kernel again
-    assert plain_program.count("pallas_call") == kept_program.count("pallas_call") == 3
-    assert bare_program.count("pallas_call") == 4
+    # forward and the backward's one walk; a bare nn.remat runs the forward kernel again
+    assert plain_program.count("pallas_call") == kept_program.count("pallas_call") == 2
+    assert bare_program.count("pallas_call") == 3
     assert REMAT in kept_program and REMAT not in plain_program
 
 
@@ -176,9 +177,9 @@ def test_ring_attention_under_the_helper_gives_the_plain_gradient():
     kept, kept_program = _block_gradients(remat_block, _RingBlock, (attn,), x)
     assert all(name in plain_program for name in KERNEL_RESULTS)
     assert REMAT in kept_program and REMAT not in plain_program
-    # forward, dq, dkv in the branch of an earlier chunk and in the diagonal's: the
-    # policy reaches the names through the scan and the switch
-    assert plain_program.count("pallas_call") == kept_program.count("pallas_call") == 6
+    # forward and the one walk back in the branch of an earlier chunk and in the diagonal's:
+    # the policy reaches the names through the scan and the switch
+    assert plain_program.count("pallas_call") == kept_program.count("pallas_call") == 4
     for want, got in zip(jax.tree.leaves(plain), jax.tree.leaves(kept)):
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
@@ -195,10 +196,11 @@ def test_attention_plan_says_what_the_backward_pass_computes_again(family, kerne
     attrs, counters = transformer.attention_plan(model, 1, 1024)
     want = "none" if family == "gpt2" else "blocks, keeps attn out+lse" if kernel else "blocks"
     assert attrs["remat"] == want and model.BLOCK == family
-    # every tile the step walks is counted: forward, dq, dkv once each
+    # every tile the step walks is counted: forward and the backward's one walk, once each
     assert bool(counters) == kernel
     if kernel:
-        assert counters["attn_tiles_run"] % 3 == 0 and counters["attn_tiles_run"] >= counters["attn_tiles_needed"]
+        assert attrs["attn_tiles"].endswith(", backward one walk")
+        assert counters["attn_tiles_run"] % 2 == 0 and counters["attn_tiles_run"] >= counters["attn_tiles_needed"]
 
 
 def test_trial_init_carries_remat(tmp_path):
