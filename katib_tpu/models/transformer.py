@@ -12,7 +12,7 @@ training on a sharded mesh with the same trial API as the CNN workloads.
 Tunable parameters understood by ``transformer_trial``: lr, d_model,
 n_heads, n_layers, seq_len, vocab_size, batch_size, n_seq, data_seed, steps,
 warmup_frac, attn(ring|ulysses), dropout, and
-block(gpt2|mla_moe|gqa_moe|looped).
+block(gpt2|mla_moe|gqa_moe|looped|dsa_moe).
 With ``block: mla_moe`` (latent attention and sparse experts,
 ``katib_tpu.models.mla_moe``) also: first_dense_layers, qk_nope_dim,
 qk_rope_dim, v_head_dim, kv_lora_rank, dense_width, expert_width, n_experts,
@@ -27,8 +27,12 @@ positions), expert_width, n_experts, experts_per_token, rope_theta, eps,
 experts_held_first, experts_held.  With ``block: looped`` (a stack of layers
 run ``ut_steps`` times over the same weights, an exit after every pass, a
 learned exit gate, ``katib_tpu.models.looped``) also: head_dim, mlp_width,
-ut_steps, exit_beta, rope_theta, eps.  Every block but ``gpt2`` refuses
-dropout and a ``seq`` mesh axis.
+ut_steps, exit_beta, rope_theta, eps.  With ``block: dsa_moe`` (grouped-query
+attention over the keys a learned indexer selects, sparse experts,
+``katib_tpu.models.dsa_moe``) also: n_kv_heads, head_dim, index_heads,
+index_head_dim, index_topk, expert_width, n_experts, experts_per_token,
+rope_theta, eps, experts_held_first, experts_held.  Every block but ``gpt2``
+refuses dropout and a ``seq`` mesh axis.
 
 **The loss belongs to the model.**  A model answers ``training_loss(outputs,
 tokens)`` with the loss a step differentiates and what a report may read of
@@ -36,7 +40,9 @@ it (a tree; ``step_counters`` turns it into a span's attributes), and
 ``reported_loss(outputs, tokens)`` with the ``eval_loss`` a trial reports;
 ``outputs`` is what its ``apply`` returns.  For the first three blocks both
 are the next-token cross entropy (``lm_loss``); ``block: looped`` trains on
-its expected-exit objective and reports its last exit's cross entropy.
+its expected-exit objective and reports its last exit's cross entropy;
+``block: dsa_moe`` trains on the cross entropy plus its indexers' losses and
+reports the cross entropy.
 
 The training task is a synthetic first-order Markov language-modelling
 problem: next-token structure is learnable (entropy well below uniform) and
@@ -60,6 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from katib_tpu.models.dsa_moe import DsaMoeLM, DsaMoeSizes
 from katib_tpu.models.gqa_moe import GqaMoeLM, GqaMoeSizes
 from katib_tpu.models.lm_head import LMHead, chunk_rows, lm_loss
 from katib_tpu.models.looped import LoopedLM, LoopedSizes
@@ -71,6 +78,7 @@ from katib_tpu.ops.flash_attention import (
     reference_attention,
     tile_visits,
 )
+from katib_tpu.ops.sparse_attention import dense_sparse_attention, sparse_attention
 from katib_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS, replicated, shard_batch
 from katib_tpu.parallel.ring_attention import make_sequence_parallel_attention
 from katib_tpu.parallel.train import TrainState, clip_by_global_norm
@@ -180,6 +188,22 @@ _dense_causal_attention = _single_device_attention(False)
 _flash_causal_attention = _single_device_attention(True)
 
 
+@lru_cache(maxsize=16)
+def sparse_attention_fn(kernel: bool, topk: int):
+    """Attention over the ``topk`` keys an indexer selects (``block:
+    dsa_moe``): the selection pass and the selecting flash kernels, or the
+    dense reference; like ``_single_device_attention`` it says what it runs
+    (``kernel``, ``window`` None, ``topk``)."""
+
+    def attention(q, k, v, qi, ki, w):
+        if kernel:
+            return sparse_attention(q, k, v, qi, ki, w, topk)
+        return dense_sparse_attention(q, k, v, qi, ki, w, topk)
+
+    attention.kernel, attention.window, attention.topk = kernel, None, topk
+    return attention
+
+
 @lru_cache(maxsize=8)
 def _sequence_parallel_attention(mesh, strategy: str):
     return make_sequence_parallel_attention(mesh, strategy=strategy, causal=True)
@@ -210,7 +234,8 @@ def attn_tiles(model, seq_len: int) -> str:
     """What a trial's attention runs, for the ``trial.init`` span: the flash
     kernel's operand dtype, the tiles it plans for these shapes and the
     backward it takes there (``"bfloat16 q512 k512, backward one walk"``;
-    ``"..., backward dq+dkv"`` where a head's accumulators do not fit);
+    ``"..., backward dq+dkv"`` where a head's accumulators do not fit;
+    ``"..., selected top2048"`` where each query's keys are selected);
     ``dense`` where no kernel runs, ``seq-parallel`` over a mesh's ``seq``
     axis."""
     if model.attn_fn is None or getattr(model.attn_fn, "kernel", None) is False:
@@ -218,7 +243,9 @@ def attn_tiles(model, seq_len: int) -> str:
     if not hasattr(model.attn_fn, "kernel"):
         return "seq-parallel"
     bq, bk, walk = _planned_attention(model, seq_len)
-    return f"{jnp.dtype(model.dtype).name} q{bq} k{bk}, backward {'one walk' if walk else 'dq+dkv'}"
+    tiles = f"{jnp.dtype(model.dtype).name} q{bq} k{bk}, backward {'one walk' if walk else 'dq+dkv'}"
+    topk = getattr(model.attn_fn, "topk", None)
+    return tiles if topk is None else f"{tiles}, selected top{topk}"
 
 
 def attention_plan(model, batch: int, seq_len: int) -> tuple[dict, dict]:
@@ -234,7 +261,9 @@ def attention_plan(model, batch: int, seq_len: int) -> tuple[dict, dict]:
     forward and the backward's one walk, or forward, dq and dkv where those
     run; every application of a layer, head and batch row) and
     ``attn_tiles_needed`` (the least: the tiles of the planned size that hold
-    a visible pair, once a walk)."""
+    a visible pair, once a walk).  Where keys are selected the walks' bounds
+    come from the selection, so ``attn_tiles_run`` is their causal bound;
+    the step counts what they walk (``dsa_tiles_visited``)."""
     kinds = model.attn_kinds
     passes = getattr(model, "passes", 1)
     kernel = bool(getattr(model.attn_fn, "kernel", False))
@@ -330,7 +359,7 @@ class TrialPrograms(NamedTuple):
 
 
 def _build_programs(
-    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM, grad_clip: float, weight_decay: float, mesh
+    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM | DsaMoeLM, grad_clip: float, weight_decay: float, mesh
 ) -> TrialPrograms:
     # AdamW without its rate: ``step_fn`` scales the update by the schedule's
     # value, so lr, steps and warmup_frac are operands and not constants
@@ -394,7 +423,7 @@ _PROGRAMS_LOCK = threading.Lock()
 
 
 def _programs_for(
-    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM, grad_clip: float, mesh
+    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM | DsaMoeLM, grad_clip: float, mesh
 ) -> tuple[TrialPrograms, bool]:
     """The structure's programs, and whether the process had them already."""
     key = (model, float(grad_clip), WEIGHT_DECAY, mesh)
@@ -416,7 +445,7 @@ def _programs_for(
 
 
 def train_lm(
-    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM,
+    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM | DsaMoeLM,
     data: np.ndarray,
     *,
     lr: float,
@@ -431,7 +460,9 @@ def train_lm(
 ) -> float:
     """Train on ``data`` [N, S]; returns the final eval loss (the model's
     reported loss) on a held-out tail.  Calls ``report(step, loss, eval_loss)``
-    every ``report_every`` steps; ``loss`` is the model's training loss."""
+    every ``report_every`` steps; ``loss`` is the model's training loss; a
+    model that names ``REPORTED_COUNTERS`` has those of its step's counters
+    reported beside them, by name."""
     # everything up to the loop: the structure's programs, parameters and
     # optimizer state, the schedule's operands, placing the eval tokens
     with tracing.span("trial.init") as sp:
@@ -471,15 +502,17 @@ def train_lm(
         counters = None
         dkey = jax.random.PRNGKey(seed + 1)
 
-    def evaluate(step: int, first: bool) -> float:
+    def evaluate(step: int, first: bool) -> tuple[float, dict]:
         with tracing.span("trial.eval", step=step, first=first) as sp:
             value = float(programs.eval_fn(state.params, eval_tokens))
+            read = {}
             if counters:
                 # what the last step gave a report to read (an expert model's
                 # routing counts, a looped model's exits), as the model reads
                 # it; fetched where the loss is: no wait of its own
-                sp.set(**model.step_counters(jax.device_get(counters)))
-            return value
+                read = model.step_counters(jax.device_get(counters))
+                sp.set(**read)
+            return value, read
 
     for s in range(steps):
         idx = rng.integers(0, len(train), size=batch_size)
@@ -492,11 +525,13 @@ def train_lm(
             state, loss, counters = programs.step_fn(state, tokens, sub, *schedule)
         eval_loss = None  # stale after this step's update
         if report is not None and (s % report_every == 0 or s == steps - 1):
-            eval_loss = evaluate(s, first=s == 0)
-            if report(step=s, loss=float(loss), eval_loss=eval_loss) is False:
+            eval_loss, read = evaluate(s, first=s == 0)
+            # the counters a model names as metrics of its own (the store's series)
+            metrics = {name: read[name] for name in getattr(model, "REPORTED_COUNTERS", ())}
+            if report(step=s, loss=float(loss), eval_loss=eval_loss, **metrics) is False:
                 break
     if eval_loss is None:
-        eval_loss = evaluate(steps - 1, first=True)  # nothing was reported
+        eval_loss, _ = evaluate(steps - 1, first=True)  # nothing was reported
     return eval_loss
 
 
@@ -505,7 +540,7 @@ def train_lm(
 
 def _block_sizes(cls, block: str, p, mesh) -> dict:
     """The fields of a block's sizes (``MlaMoeSizes`` | ``GqaMoeSizes`` |
-    ``LoopedSizes``) from a trial's parameters: every field under its own
+    ``LoopedSizes`` | ``DsaMoeSizes``) from a trial's parameters: every field under its own
     name, a layout as a string of 0 and 1 (the experts held are two integers
     of their own: ``_expert_block_sizes``).  These blocks have no dropout and
     have not been run over a ``seq`` axis."""
@@ -575,6 +610,24 @@ def _gqa_moe_model(p, vocab: int, mesh) -> GqaMoeLM:
     )
 
 
+def _dsa_moe_model(p, vocab: int, mesh) -> DsaMoeLM:
+    """The ``block: dsa_moe`` model from a trial's parameters: one device (a
+    ``data`` axis shards its batch as it does every other operation's)."""
+    sizes = _expert_block_sizes(DsaMoeSizes, DsaMoeLM.BLOCK, p, mesh)
+    if sizes.n_heads % sizes.n_kv_heads:
+        raise ValueError(
+            f"transformer_trial: {sizes.n_heads} query heads are not a multiple of "
+            f"{sizes.n_kv_heads} key-value heads"
+        )
+    if sizes.index_topk < 1:
+        raise ValueError(f"transformer_trial: index_topk {sizes.index_topk}: a query selects at least one key")
+    return DsaMoeLM(
+        vocab_size=vocab,
+        sizes=sizes,
+        attn_fn=sparse_attention_fn(jax.default_backend() == "tpu", sizes.index_topk),
+    )
+
+
 def _looped_model(p, vocab: int, mesh) -> LoopedLM:
     """The ``block: looped`` model from a trial's parameters."""
     sizes = LoopedSizes(**_block_sizes(LoopedSizes, LoopedLM.BLOCK, p, mesh))
@@ -602,6 +655,7 @@ _BLOCKS = {
     MlaMoeLM.BLOCK: _mla_moe_model,
     GqaMoeLM.BLOCK: _gqa_moe_model,
     LoopedLM.BLOCK: _looped_model,
+    DsaMoeLM.BLOCK: _dsa_moe_model,
 }
 
 
@@ -624,8 +678,8 @@ def transformer_trial(ctx) -> None:
             vocab, int(p.get("n_seq", 512)), seq_len, seed=int(p.get("data_seed", 0))
         )
 
-    def report(step, loss, eval_loss):
-        return ctx.report(step=step, loss=loss, eval_loss=eval_loss)
+    def report(step, loss, eval_loss, **metrics):
+        return ctx.report(step=step, loss=loss, eval_loss=eval_loss, **metrics)
 
     train_lm(
         model,

@@ -270,6 +270,58 @@ def _visible(shape, k0, q0, window):
     return (k_pos <= q_pos) & (k_pos > q_pos - window)
 
 
+# ---------------------------------------------------------------------------
+# a selection of keys for every query, as the selecting kernels read it
+# ---------------------------------------------------------------------------
+#
+# A selection (``ops/sparse_attention.py`` makes it) is a bitmask and the loop
+# bounds it leaves.  ``bits`` [batch, seq_k / 32, seq_q] int32: a query's
+# column of words; within k tile ``j`` of ``bk`` keys, key ``j * bk + r`` is
+# bit ``r // (bk / 32)`` of word row ``j * bk / 32 + r % (bk / 32)``, so a
+# tile's words unpack to its ``[bk, bq]`` mask by a broadcast along a leading
+# axis, a shift and a merge of leading axes.  ``bounds`` [batch, q tiles, 2]
+# int32 (in SMEM): the k tiles ``[first, end)`` that hold a selected key of
+# each q tile; the tiles outside are skipped by the loop bounds.
+
+
+def pack_tile(selected):
+    """A ``[bk, bq]`` bool tile as its ``[bk / 32, bq]`` int32 words."""
+    bk, bq = selected.shape
+    shape = (32, bk // 32, bq)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return jnp.sum(jax.lax.shift_left(selected.astype(jnp.int32).reshape(shape), shifts), axis=0)
+
+
+def unpack_tile(words):
+    """``pack_tile``'s inverse: ``[bk / 32, bq]`` words to a ``[bk, bq]`` mask."""
+    shape = (32, *words.shape)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    bits = jax.lax.shift_right_logical(jnp.broadcast_to(words[None], shape), shifts) & 1
+    return bits.reshape(32 * words.shape[0], words.shape[1]) != 0
+
+
+def word_rows(start, block_k):
+    """The bitmask's rows of the k tile from key ``start``."""
+    words = block_k // 32
+    return pl.ds(pl.multiple_of(start // 32, words), words)
+
+
+class _Selection:
+    """The selection inside a kernel: its bitmask column (``[1, seq_k / 32,
+    bq]`` block) and the loop bounds (the whole array, in SMEM)."""
+
+    def __init__(self, bits_ref, bounds_ref, block_k):
+        self.bits_ref, self.bounds_ref, self.block_k = bits_ref, bounds_ref, block_k
+
+    def mask(self, start):
+        """The selected pairs of the k tile from key ``start``."""
+        return unpack_tile(self.bits_ref[0, word_rows(start, self.block_k), :])
+
+    def k_tile_range(self, qi):
+        b = pl.program_id(0)
+        return self.bounds_ref[b, qi, 0], self.bounds_ref[b, qi, 1]
+
+
 @functools.lru_cache(maxsize=64)
 def tile_visits(
     seq_q: int, seq_k: int, bq: int, bk: int, causal: bool = True, window: int | None = None, *, walk: bool
@@ -308,11 +360,15 @@ def tile_visits(
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_k, shift, window):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, sm_scale, causal, block_k, shift, window, selected=False):
     """One q tile against the k tiles it sees.  Scores are held transposed,
     [block_k, bq]: the running max and sum are then reductions along sublanes
     (elementwise across registers) and live in lane-dense [1, bq] rows, where
-    reductions along lanes cost more than the products at these widths."""
+    reductions along lanes cost more than the products at these widths.
+    ``selected``: two more inputs, a selection's bitmask and loop bounds; the
+    tile's mask is the causal mask AND the selection."""
+    *sel, o_ref, lse_ref = refs
+    sel = _Selection(*sel, block_k) if selected else None
     bq, d_v = q_ref.shape[-2], v_ref.shape[-1]
     n_kb = k_ref.shape[-2] // block_k
     qi = pl.program_id(2)
@@ -326,7 +382,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_
         # operands in the inputs' dtype, scores and everything after in f32
         s = sm_scale * jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
         if causal:
-            s = jnp.where(_visible(s.shape, start, qi * bq + shift, window), s, _MASK_VALUE)
+            mask = _visible(s.shape, start, qi * bq + shift, window)
+            s = jnp.where(mask if sel is None else mask & sel.mask(start), s, _MASK_VALUE)
         m_new = jnp.maximum(m_acc, jnp.max(s, axis=0, keepdims=True))
         # a row that has seen no key yet has m_new == _MASK_VALUE and p == 1
         # on masked keys: wiped by alpha == 0 at its first visible key, or at
@@ -340,7 +397,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal, block_
     o0 = jnp.zeros((d_v, bq), jnp.float32)
     m0 = jnp.full((1, bq), _MASK_VALUE, jnp.float32)
     l0 = jnp.zeros((1, bq), jnp.float32)
-    first, end = _k_tile_range(qi, bq, block_k, n_kb, shift, causal, window)
+    if sel is None:
+        first, end = _k_tile_range(qi, bq, block_k, n_kb, shift, causal, window)
+    else:
+        first, end = sel.k_tile_range(qi)
     o, m, l = jax.lax.fori_loop(first, end, body, (o0, m0, l0))
 
     # rows with every key masked: output 0, log-sum-exp the mask value
@@ -357,21 +417,34 @@ def _kv_head(group: int):
     return (lambda j: j) if group == 1 else (lambda j: j // group)
 
 
-def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret, window):
+def _selection_specs(sk, bq, column):
+    """The selection's two inputs of a kernel: a q tile's column of the
+    bitmask (``column`` maps the grid to its (batch, q tile)) and the loop
+    bounds, whole, in SMEM."""
+    return [
+        pl.BlockSpec((1, sk // 32, bq), lambda *g: (column(*g)[0], 0, column(*g)[1])),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+    ]
+
+
+def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret, window, selection=()):
+    """``selection``: ``(bits, bounds)`` of a selection made at these tiles,
+    or none."""
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
     bq, bk = _block_sizes(q, k, v, block_q, block_k)
     kv = _kv_head(_group_size(q, k))
     o, lse = _pallas_call(
         functools.partial(
-            _fwd_kernel, sm_scale=sm_scale, causal=causal, block_k=bk, shift=sk - sq, window=window
+            _fwd_kernel, sm_scale=sm_scale, causal=causal, block_k=bk, shift=sk - sq, window=window,
+            selected=bool(selection),
         ),
         grid=(b, h, sq // bq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda i, j, l: (i, j, l, 0)),
             pl.BlockSpec((1, 1, sk, d), lambda i, j, l: (i, kv(j), 0, 0)),
             pl.BlockSpec((1, 1, sk, d_v), lambda i, j, l: (i, kv(j), 0, 0)),
-        ],
+        ] + (_selection_specs(sk, bq, lambda i, j, l: (i, l)) if selection else []),
         out_specs=[
             pl.BlockSpec((1, 1, bq, d_v), lambda i, j, l: (i, j, l, 0)),
             pl.BlockSpec((1, 1, 1, bq), lambda i, j, l: (i, j, 0, l)),
@@ -381,7 +454,7 @@ def _fwd(q, k, v, *, sm_scale, causal, block_q, block_k, interpret, window):
             jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(q, k, v, *selection)
     return o, lse[:, :, 0, :]
 
 
@@ -484,8 +557,8 @@ def _dkv_kernel(
 
 
 def _walk_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, dq_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-    *, sm_scale, causal, block_k, shift, window,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, dmd_ref, *refs,
+    sm_scale, causal, block_k, shift, window, selected=False,
 ):
     """The whole backward in one walk: one q tile of one query head against
     the k tiles it sees, as the dq kernel (its scores, transposed, and its
@@ -493,7 +566,9 @@ def _walk_kernel(
     dq stays in registers over the k tiles; dk and dv add into two whole-head
     float32 accumulators (VMEM scratch, ``[S_k, d]``) that every q tile and
     every query head of the key-value head share: cleared at the head's first
-    grid step, written out at its last."""
+    grid step, written out at its last.  ``selected`` as in the forward."""
+    *sel, dq_ref, dk_ref, dv_ref, dk_acc, dv_acc = refs
+    sel = _Selection(*sel, block_k) if selected else None
     bq, d = q_ref.shape[-2], q_ref.shape[-1]
     n_kb = k_ref.shape[-2] // block_k
     member, qi = pl.program_id(2), pl.program_id(3)
@@ -516,7 +591,8 @@ def _walk_kernel(
         s = sm_scale * jax.lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
         e = s - lse
         if causal:
-            e = jnp.where(_visible(e.shape, start, qi * bq + shift, window), e, _MASK_VALUE)
+            mask = _visible(e.shape, start, qi * bq + shift, window)
+            e = jnp.where(mask if sel is None else mask & sel.mask(start), e, _MASK_VALUE)
         p = jnp.exp(e)  # [block_k, bq]
         # dv's product before dp's, as the dkv kernel has them: the other
         # order read 0.4-1.0% slower a step on the chip (PERF.md section 6, PR 40)
@@ -526,7 +602,10 @@ def _walk_kernel(
         dk_acc[rows, :] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
         return dq_acc + jax.lax.dot_general(k, ds, _TN, preferred_element_type=jnp.float32)
 
-    first, end = _k_tile_range(qi, bq, block_k, n_kb, shift, causal, window)
+    if sel is None:
+        first, end = _k_tile_range(qi, bq, block_k, n_kb, shift, causal, window)
+    else:
+        first, end = sel.k_tile_range(qi)
     dq = jax.lax.fori_loop(first, end, body, jnp.zeros((d, bq), jnp.float32))
     dq_ref[0, 0, :, :] = (sm_scale * dq).T.astype(dq_ref.dtype)
 
@@ -536,7 +615,7 @@ def _walk_kernel(
         dv_ref[0, 0, :, :] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, interpret, window):
+def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, interpret, window, selection=()):
     b, h, sq, d = q.shape
     sk, d_v = k.shape[2], v.shape[3]
     bq, bk = _block_sizes(q, k, v, block_q, block_k)
@@ -544,6 +623,11 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
     dmd = delta - dlse.astype(jnp.float32)  # [b, h, sq]
     operands = (q, k, v, do, lse[:, :, None, :], dmd[:, :, None, :])
     kernel = dict(sm_scale=sm_scale, causal=causal, shift=sk - sq, window=window)
+    if selection:
+        # the selecting path has the single walk alone
+        if not one_walk(sq, sk, d, d_v, q.dtype, bq, bk):
+            raise ValueError(f"a selection of keys needs the single walk: {sk} keys of {d} do not fit it")
+        return _bwd_walk(operands + tuple(selection), dict(kernel, selected=True), bq, bk, interpret)
     backward = _bwd_walk if one_walk(sq, sk, d, d_v, q.dtype, bq, bk) else _bwd_split
     return backward(operands, kernel, bq, bk, interpret)
 
@@ -551,7 +635,8 @@ def _bwd(q, k, v, o, lse, do, dlse, *, sm_scale, causal, block_q, block_k, inter
 def _bwd_walk(operands, kernel, bq, bk, interpret):
     """dq, dk, dv from one kernel.  Grid (batch, key-value head, query head of
     it, q tile), the last two in order: a key-value head's K, V, dk and dv
-    blocks and the accumulators stay while its query heads' q tiles pass."""
+    blocks and the accumulators stay while its query heads' q tiles pass.
+    Two more operands where ``kernel`` says ``selected``."""
     q, k, v = operands[:3]
     b, _, sq, d = q.shape
     h_kv, sk, d_v = k.shape[1], k.shape[2], v.shape[3]
@@ -570,7 +655,7 @@ def _bwd_walk(operands, kernel, bq, bk, interpret):
             pl.BlockSpec((1, 1, bq, d_v), q_tile),
             pl.BlockSpec((1, 1, 1, bq), q_row),
             pl.BlockSpec((1, 1, 1, bq), q_row),
-        ],
+        ] + (_selection_specs(sk, bq, lambda i, j, m, l: (i, l)) if kernel.get("selected") else []),
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), q_tile),
             pl.BlockSpec((1, 1, sk, d), kv_head),

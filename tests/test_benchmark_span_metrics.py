@@ -240,12 +240,15 @@ def test_every_new_metric_has_its_entry_and_reader():
         assert set(row["workloads"]) <= cells
         assert callable(reader(metric))
     # PR 39's seven: every cell reports them (no ``workloads``), a counter's
-    # source says so, and they stand at the end of the list in this order
+    # source says so, and they stand together in this order (later PRs append
+    # after them)
     order = [
         "trial_handover_s", "trial_tail_s", "trial_head_s", "orch_dispatch_wait_s",
         "orch_dispatch_s", "orch_settle_s", "orch_journal_ms",
     ]
-    assert [m["name"] for m in bench["per_layer"]][-7:] == order and set(order) == set(HANDOVER)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(order[0])
+    assert names[first : first + 7] == order and set(order) == set(HANDOVER)
     for metric in order:
         row = rows[metric]
         assert row["moves"] == "trials_per_hour" and row["better"] == "lower"

@@ -298,3 +298,59 @@ def test_mnist_cohort_step_trial_sharded_four_chips(topo):
     state_out, _metrics = compiled.output_shardings
     for sharding in jax.tree.leaves(state_out):
         assert sharding.spec[0] == TRIAL_AXIS, sharding
+
+
+def test_sparse_attention_step_fits_one_chip(one_chip):
+    """The ``keye2-ep8-lr4low-steps12`` train step (``block: dsa_moe`` at the
+    configuration's sizes: four layers of 32 query heads over 4 key-value
+    heads of 128 at 16384 positions, an indexer of 16 heads of 64 selecting
+    2048 keys a query, 16 of 128 experts held) compiled for the v5e: four
+    kernels a layer (the selection pass, the selecting forward, the indexer's
+    loss, the selecting backward's one walk; the rematerialised forward runs
+    none), and the whole step within the chip.  Deviceless, this sandbox
+    (PR 41): ``peak_memory_in_bytes`` + code 11.6 GB (ISSUE 41 reckoned 10-11)."""
+    import json
+
+    from katib_tpu.models import transformer
+    from katib_tpu.models.dsa_moe import DsaMoeLM, DsaMoeSizes
+    from katib_tpu.ops.sparse_attention import sparse_attention
+
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark", "configs", "keye-vl-2.0-30b-a3b-ep8.json")) as f:
+        cfg = json.load(f)
+    sa = cfg["sa_config"]
+
+    def kernel(q, k, v, qi, ki, w):  # what sparse_attention_fn gives on the chip
+        return sparse_attention(q, k, v, qi, ki, w, sa["topk"], None, None, None, None, False)
+
+    kernel.kernel, kernel.window, kernel.topk = True, None, sa["topk"]
+    sizes = DsaMoeSizes(
+        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"], n_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg["head_dim"], n_layers=cfg["num_hidden_layers"], index_heads=sa["indexer_num_heads"],
+        index_head_dim=sa["indexer_head_dim"], index_topk=sa["topk"], expert_width=cfg["moe_intermediate_size"],
+        n_experts=cfg["router_width"], experts_per_token=cfg["num_experts_per_tok"],
+        experts_held=(cfg["experts_held_first"], cfg["num_experts"]),
+        rope_theta=float(cfg["rope_theta"]), eps=cfg["rms_norm_eps"],
+    )
+    model = DsaMoeLM(vocab_size=cfg["vocab_size"], sizes=sizes, attn_fn=kernel)
+    programs = transformer._build_programs(model, 1.0, transformer.WEIGHT_DECAY, None)
+    seq_len = cfg["seq_len"]
+
+    def placed(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    state = jax.tree.map(placed, jax.eval_shape(programs.init, jax.random.PRNGKey(0), seq_len))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)  # noqa: E731
+    compiled = programs.step_fn.lower(
+        state, placed(jnp.zeros((cfg["batch_size"], seq_len), jnp.int32)), placed(jnp.zeros((2,), jnp.uint32)),
+        scalar(jnp.float32), scalar(jnp.int32), scalar(jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("operand_layout_constraints={bf16[") == 4 * sizes.n_layers
+    # the grouped products, as families/dsa_moe.py EXPERT_PRODUCT_MARK finds them
+    assert text.count("operand_layout_constraints={s32[") >= 2 * sizes.n_layers
+    assert "approx_top_k" not in text and "ApproxTopK" not in text
+    ma = compiled.memory_analysis()
+    held = ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    assert 5.5e9 < held < 5.8e9  # parameters and two moments, float32, updated in place
+    assert 4e9 < ma.peak_memory_in_bytes + ma.generated_code_size_in_bytes < 14.5e9, ma
+    assert held + ma.temp_size_in_bytes + ma.generated_code_size_in_bytes < 16 * 2**30

@@ -23,6 +23,7 @@ import pytest
 
 import full_buffer_experts as planted
 from katib_tpu.models import transformer
+from katib_tpu.models.dsa_moe import DsaMoeSizes
 from katib_tpu.models.gqa_moe import GqaMoeLM, GqaMoeSizes
 from katib_tpu.models.mla_moe import ROUTING, ExpertLayer, buffer_rungs, rotary
 from katib_tpu.ops import flash_attention as fa
@@ -421,10 +422,30 @@ def _expert_weights(key):
     }
 
 
-def _share(weights, first, count, h, logits):
-    layer = ExpertLayer(dataclasses.replace(LAYER_SIZES, experts_held=(first, count)), jnp.float32)
+#: the same expert layer as ``block: dsa_moe`` configures it (the router on
+#: the post-attention norm's output, a SiLU gate), for its family's reference
+DSA_LAYER_CONFIG = {
+    "hidden_size": 48, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "num_hidden_layers": 1, "moe_intermediate_size": 32, "router_width": 16, "num_experts_per_tok": 3,
+    "experts_held_first": 0, "num_experts": 16, "vocab_size": 96, "rope_theta": 10000000, "rms_norm_eps": 1e-06,
+    "sa_config": {"indexer_num_heads": 2, "indexer_head_dim": 16, "topk": 8},
+}
+
+
+def _dsa_family():
+    path = os.path.join(REPO, "benchmark", "families", "dsa_moe.py")
+    spec = importlib.util.spec_from_file_location("benchmark_families_dsa_moe", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _share(weights, first, count, h, logits, sizes=LAYER_SIZES):
+    layer = ExpertLayer(dataclasses.replace(sizes, experts_held=(first, count)), jnp.float32)
     held = slice(first, first + count)
     params = {k: weights[k][held] for k in ("experts_gate", "experts_up", "experts_down")}
+    if logits is None:  # the layer's own router: the same weights in every share
+        params["router"] = weights["router"]
     out, sown = layer.apply({"params": params}, h, logits, mutable=[ROUTING])
     return out, sown[ROUTING]
 
@@ -434,18 +455,28 @@ class TestSharedExpertLayer:
     def stream(self):
         return jax.random.normal(jax.random.PRNGKey(11), (2, 24, LAYER_SIZES.d_model), jnp.float32)
 
-    def test_eight_shares_add_up_to_the_uncut_reference_layer(self, family, stream):
-        """16 experts in 8 shares of 2, the router's logits handed to every
-        share alike: their parts, and the residual once, are the whole layer
-        as the reference computes it."""
+    @pytest.mark.parametrize("block", ["gqa_moe", "dsa_moe"])
+    def test_eight_shares_add_up_to_the_uncut_reference_layer(self, family, stream, block):
+        """16 experts in 8 shares of 2, the router's logits the same in every
+        share: their parts, and the residual once, are the whole layer as the
+        reference computes it.  ``gqa_moe`` hands every share the logits of
+        the layer's input; ``dsa_moe``'s layer routes from its own router on
+        ``RN_2``'s output (the same weights on every chip), SiLU-gated."""
         weights = _expert_weights(jax.random.PRNGKey(5))
-        f = family._layer_functions(family.shape_of(LAYER_CONFIG), "f32", None, *stream.shape[:2])
-        logits = f["router"](stream, weights)
-        want = f["moe"](stream, weights, logits)
+        sizes, logits = LAYER_SIZES, None
+        if block == "gqa_moe":
+            f = family._layer_functions(family.shape_of(LAYER_CONFIG), "f32", None, *stream.shape[:2])
+            logits = f["router"](stream, weights)
+            want = f["moe"](stream, weights, logits)
+        else:
+            dsa = _dsa_family()
+            f = dsa._layer_functions(dsa.shape_of(DSA_LAYER_CONFIG), "f32", None, *stream.shape[:2])
+            want = f["moe"](stream, weights)
+            sizes = DsaMoeSizes(d_model=48, n_experts=16, experts_per_token=3, expert_width=32)
         h = f["rms_norm"](stream, weights["norm2"])
         total, rows = stream, 0
         for i in range(8):
-            out, sown = _share(weights, 2 * i, 2, h, logits)
+            out, sown = _share(weights, 2 * i, 2, h, logits, sizes)
             total = total + out
             rows += int(sown["expert_tokens"][0].sum())
             assert sown["assignments"][0][1] == 48 * 3
