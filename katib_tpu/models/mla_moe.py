@@ -27,9 +27,10 @@ over the rows that are held.  **The sorted buffer is as long as the rows
 held**: how many are held is known only on the device, so the buffer's length
 is one of a short ladder fixed by the shapes (``buffer_rungs``: twice what the
 share expects, and all ``T x k`` assignments), and each layer
-picks its rung every step from the counted rows; gathers, clears, grouped
-products and the way back to the tokens all run at that length, forward and
-backward.  The last rung holds any routing, so there is no capacity factor,
+picks its rung every step from the counted rows; gathers, clears and grouped
+products run at that length, forward and backward, and the way back to the
+tokens reads the held rows alone (``ops/expert_combine.py``, one kernel).
+The last rung holds any routing, so there is no capacity factor,
 no dropped token, and no dense product over all experts.  What the absent
 experts would have added is left out; the weights' normalisation still runs
 over all chosen.  With all ``n_experts`` held it is the whole layer: one
@@ -54,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from katib_tpu.models.lm_head import LMHead, lm_loss, next_token_objective
+from katib_tpu.ops.expert_combine import Routes, combine, way_back
 from katib_tpu.ops.flash_attention import remat_block
 
 #: the collection an expert layer sows a step's routing counts into
@@ -179,6 +181,14 @@ def expert_buffer(sizes, tokens: int) -> str:
     return " / ".join(map(str, buffer_rungs(tokens, sizes)))
 
 
+def expert_combine(mesh) -> str:
+    """The way back to the tokens as the ``trial.init`` span carries it:
+    ``"held rows, kernel"`` on one device, ``"every assignment, gather"``
+    where GSPMD partitions the step over a mesh of several devices (the
+    kernel cannot be partitioned: ``ops/expert_combine.py``)."""
+    return "held rows, kernel" if mesh is None or mesh.size == 1 else "every assignment, gather"
+
+
 def _in_bounds(x, index):
     """``x[index]`` for indices that are in bounds by construction: no clamp
     and no fill pass beside the gather."""
@@ -186,64 +196,27 @@ def _in_bounds(x, index):
 
 
 # The two passes between the tokens [T, D] and the rows of the sorted buffer
-# [C, D] are each other's transpose, and both are gathers: ``tok`` [C] is the
-# token of every row, ``src`` [k, T] the row of every assignment (of the k-th
-# choice of every token), a cleared row where the assignment has none.
+# [C, D] are each other's transpose: the gather of every row's token ``tok``
+# [C], and the way back, which sums every token's rows where ``routes`` says
+# they lie (``ops/expert_combine.py``: a kernel that reads the rows held).
 
 
 @jax.custom_vjp
-def _tokens_to_rows(x, tok, src):
+def _tokens_to_rows(x, tok, routes):
     """Row ``tok[i]`` of ``x`` [T, D] for every row i of the buffer."""
     return _in_bounds(x, tok)
 
 
-def _tokens_to_rows_fwd(x, tok, src):
-    return _in_bounds(x, tok), (tok, src)
+def _tokens_to_rows_fwd(x, tok, routes):
+    return _in_bounds(x, tok), routes
 
 
-def _tokens_to_rows_bwd(res, g):
-    return _rows_to_tokens(g, *res), None, None
+def _tokens_to_rows_bwd(routes, g):
+    ones = jnp.ones(routes.pos.shape, jnp.float32)
+    return way_back(ones, g, routes, g.dtype)[0], None, None
 
 
 _tokens_to_rows.defvjp(_tokens_to_rows_fwd, _tokens_to_rows_bwd)
-
-
-@jax.custom_vjp
-def _rows_to_tokens(y, tok, src):
-    """For every token the sum of its assignments' rows of ``y`` [C, D], in
-    float32, returned in ``y``'s dtype.  Not the scatter-add of C rows that
-    autodiff would write for ``_tokens_to_rows``: on the TPU that is sorted
-    and applied a row at a time, and reads slower than this gather of T*k."""
-    return _in_bounds(y, src).astype(jnp.float32).sum(axis=0).astype(y.dtype)
-
-
-def _rows_to_tokens_fwd(y, tok, src):
-    return _rows_to_tokens(y, tok, src), (tok, src)
-
-
-def _rows_to_tokens_bwd(res, g):
-    return _tokens_to_rows(g, *res), None, None
-
-
-_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
-
-
-@jax.custom_vjp
-def _assignments_to_rows(w, index, src):
-    """``w[index]``: the weight of every row's assignment, from ``w`` [k*T];
-    the transpose is the gather by ``src``."""
-    return _in_bounds(w, index)
-
-
-def _assignments_to_rows_fwd(w, index, src):
-    return _in_bounds(w, index), src
-
-
-def _assignments_to_rows_bwd(src, g):
-    return _in_bounds(g, src.reshape(-1)), None, None
-
-
-_assignments_to_rows.defvjp(_assignments_to_rows_fwd, _assignments_to_rows_bwd)
 
 
 class _Routed(NamedTuple):
@@ -254,37 +227,37 @@ class _Routed(NamedTuple):
     dtype: jnp.dtype  # of the products' operands
 
 
-def _routed_rung(rows: int, cfg: _Routed, x, weights, w_gate, w_up, w_down, order, inverse, sizes):
+def _routed_rung(rows: int, cfg: _Routed, x, weights, w_gate, w_up, w_down, order, inverse, expert, sizes):
     """What the experts held add to every token of ``x`` [T, D], through a
-    sorted buffer of ``rows`` rows.  ``order`` sorts the assignments [k, T]
-    (flattened: assignment ``j * T + t`` is token t's j-th choice) by expert
-    held, the others last, and ``inverse`` is its inverse; ``sizes`` are the
-    rows of each expert held, ``weights`` [k, T] is zero on an assignment not
-    held.  The caller sees to it that a row past the last group exists
-    wherever an assignment lies outside the buffer (``rows`` is more than
-    ``sizes.sum()``, or every assignment)."""
-    tokens = x.shape[0]
+    sorted buffer of ``rows`` rows, and the rows its way back read.
+    ``order`` sorts the assignments (flattened token-major: assignment ``t *
+    k + j`` is token t's j-th choice) by expert held, the others last, so
+    that inside an expert the rows lie in token order; ``inverse`` is its
+    inverse; ``weights`` [k, T] is each choice's weight, ``expert`` [k, T]
+    its expert among the held (-1: none), ``sizes`` the rows of each expert
+    held that lie inside the buffer."""
+    k, tokens = weights.shape
     index = order[:rows]
-    tok = index % tokens
-    src = jnp.minimum(inverse, rows - 1).reshape(-1, tokens)
+    held = sizes.sum()
+    pos = jnp.where(inverse < held, inverse, -1).reshape(tokens, k).T
+    routes = Routes(pos, jnp.where(pos >= 0, expert, -1), index, sizes)
     # Rows past the last group belong to no expert held here.  The grouped
     # product's kernels (forward and transposes) leave such rows of their
-    # results unwritten, so every operand and result is cleared there: no
-    # stale memory reaches a sum, forward or backward.  An assignment outside
-    # the buffer reads the last of them.
-    held_row = (jnp.arange(rows) < sizes.sum())[:, None]
+    # results unwritten, so the operands and the gate's and up's results are
+    # cleared there: no stale memory reaches a product, forward or backward.
+    # The way back never reads them (``combine``), so the down product's
+    # result goes to it as it is.
+    held_row = (jnp.arange(rows) < held)[:, None]
 
     def grouped(lhs, w):
-        out = jax.lax.ragged_dot(
+        return jax.lax.ragged_dot(
             jnp.where(held_row, lhs, 0).astype(cfg.dtype), w.astype(cfg.dtype), sizes,
             preferred_element_type=jnp.float32,
         )
-        return jnp.where(held_row, out, 0.0)
 
-    buffer = _tokens_to_rows(x, tok, src)
-    out = grouped(cfg.act(grouped(buffer, w_gate)) * grouped(buffer, w_up), w_down)
-    weight = _assignments_to_rows(weights.reshape(-1), index, src)
-    return _rows_to_tokens((out * weight[:, None]).astype(cfg.dtype), tok, src)
+    buffer = _tokens_to_rows(x, index // k, routes)
+    gate, up = (jnp.where(held_row, grouped(buffer, w), 0.0) for w in (w_gate, w_up))
+    return combine(weights, grouped(cfg.act(gate) * up, w_down), routes, cfg.dtype)
 
 
 def _on_rung(rung, branches, *operands):
@@ -294,7 +267,7 @@ def _on_rung(rung, branches, *operands):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _routed(cfg: _Routed, rung, x, weights, w_gate, w_up, w_down, order, inverse, sizes):
+def _routed(cfg: _Routed, rung, x, weights, w_gate, w_up, w_down, order, inverse, expert, sizes):
     """``_routed_rung`` on rung ``rung`` of ``cfg.rungs``, chosen on the
     device.  One operation with its own backward: forward and backward each
     branch on the rung, so what a branch keeps for its backward is never
@@ -302,7 +275,7 @@ def _routed(cfg: _Routed, rung, x, weights, w_gate, w_up, w_down, order, inverse
     again from the operands (under ``nn.remat`` that is the one recomputation:
     nothing of the rematerialised forward is kept, so none of it runs)."""
     branches = [partial(_routed_rung, rows, cfg) for rows in cfg.rungs]
-    return _on_rung(rung, branches, x, weights, w_gate, w_up, w_down, order, inverse, sizes)
+    return _on_rung(rung, branches, x, weights, w_gate, w_up, w_down, order, inverse, expert, sizes)
 
 
 def _routed_fwd(cfg, rung, *operands):
@@ -311,18 +284,18 @@ def _routed_fwd(cfg, rung, *operands):
 
 def _routed_bwd(cfg, res, g):
     def backward(rows):
-        def run(g, x, weights, w_gate, w_up, w_down, order, inverse, sizes):
-            _, vjp = jax.vjp(
-                lambda *diff: _routed_rung(rows, cfg, *diff, order, inverse, sizes),
-                x, weights, w_gate, w_up, w_down,
+        def run(g, x, weights, w_gate, w_up, w_down, order, inverse, expert, sizes):
+            _, vjp, _ = jax.vjp(
+                lambda *diff: _routed_rung(rows, cfg, *diff, order, inverse, expert, sizes),
+                x, weights, w_gate, w_up, w_down, has_aux=True,
             )
             return vjp(g)
 
         return run
 
     rung, *operands = res
-    grads = _on_rung(rung, [backward(rows) for rows in cfg.rungs], g, *operands)
-    return (None, *grads, None, None, None)
+    grads = _on_rung(rung, [backward(rows) for rows in cfg.rungs], g[0], *operands)
+    return (None, *grads, None, None, None, None)
 
 
 _routed.defvjp(_routed_fwd, _routed_bwd)
@@ -349,9 +322,10 @@ def _experts(z, dtype, x, logits, w_gate, w_up, w_down):
     act = {"silu": nn.silu, "relu": nn.relu}[z.expert_act]
 
     # -- the assignments held here, sorted by expert; the others sort last.
-    # An assignment's index is ``j * T + t`` (token t's j-th choice): the
-    # way back to the tokens then sums over a leading axis
-    local = (top_experts - first).T  # [k, T]
+    # An assignment's index is ``t * k + j`` (token t's j-th choice): inside
+    # an expert the rows then lie in token order, and a tile of tokens' rows
+    # are one run, which the way back reads
+    local = top_experts - first  # [T, k]
     held = (local >= 0) & (local < count)
     key = jnp.where(held, local, count).reshape(-1)
     order = jnp.argsort(key, stable=True)
@@ -367,17 +341,18 @@ def _experts(z, dtype, x, logits, w_gate, w_up, w_down):
     given = jnp.diff(jnp.minimum(jnp.cumsum(group_sizes), buffer_rows), prepend=0)
 
     # -- the grouped product over the rows that are held
-    routed = _routed(
+    routed, combine_rows = _routed(
         _Routed(rungs, act, dtype), rung,
-        x, jnp.where(held, weights.T, 0.0), w_gate, w_up, w_down, order, inverse, given,
+        x, weights.T, w_gate, w_up, w_down, order, inverse, jnp.where(held, local, -1).T, given,
     )
     # the rows computed for each held expert, the assignments as counted
-    # before the sort (to an expert held here, to any expert), and the rows
-    # of the rung that ran
+    # before the sort (to an expert held here, to any expert), the rows of
+    # the rung that ran, and the rows the forward's way back read
     counts = {
         "expert_tokens": given,
         "assignments": jnp.stack([held.sum(), jnp.int32(held.size)]),
         "buffer_rows": buffer_rows,
+        "combine_rows": combine_rows,
     }
     return routed, counts
 
@@ -434,7 +409,9 @@ def routing_counters(routing) -> dict:
     as the attributes of a span: assignments to the experts held and to all,
     the busiest held expert's tokens in one layer against the mean, the
     assignments to a held expert that its product did not compute (0: the
-    rung that ran held every one), and the rows of the rungs that ran, summed
+    rung that ran held every one), the rows of the rungs that ran, and the
+    rows the way back to the tokens read (the held rows and the kernel's
+    granules' edges; every assignment's where the gather runs), each summed
     over the layers (``moe_assignments_total`` is their worst case)."""
     leaves: dict = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(routing):
@@ -449,6 +426,7 @@ def routing_counters(routing) -> dict:
         "moe_expert_tokens_mean": float(tokens.mean()),
         "moe_tokens_dropped": int(held - tokens.sum()),
         "moe_buffer_rows": int(np.sum(leaves["buffer_rows"])),
+        "moe_combine_rows": int(np.sum(leaves["combine_rows"])),
     }
 
 

@@ -70,7 +70,7 @@ from katib_tpu.models.dsa_moe import DsaMoeLM, DsaMoeSizes
 from katib_tpu.models.gqa_moe import GqaMoeLM, GqaMoeSizes
 from katib_tpu.models.lm_head import LMHead, chunk_rows, lm_loss
 from katib_tpu.models.looped import LoopedLM, LoopedSizes
-from katib_tpu.models.mla_moe import ROUTING, MlaMoeLM, MlaMoeSizes, expert_buffer
+from katib_tpu.models.mla_moe import ROUTING, MlaMoeLM, MlaMoeSizes, expert_buffer, expert_combine
 from katib_tpu.ops.flash_attention import (
     flash_attention,
     one_walk,
@@ -484,8 +484,12 @@ def train_lm(
             for name, tiles in tile_counters.items():
                 sp.add(name, tiles)
             if hasattr(getattr(model, "sizes", None), "experts_held"):
-                # a model with expert layers: the lengths their sorted buffer may take
-                sp.set(expert_buffer=expert_buffer(model.sizes, batch_size * data.shape[1]))
+                # a model with expert layers: the lengths their sorted buffer may
+                # take, and what their way back to the tokens reads
+                sp.set(
+                    expert_buffer=expert_buffer(model.sizes, batch_size * data.shape[1]),
+                    expert_combine=expert_combine(mesh),
+                )
         state = programs.init(jax.random.PRNGKey(seed), data.shape[1])
         schedule = (
             jnp.float32(lr),

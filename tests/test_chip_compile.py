@@ -183,6 +183,52 @@ def test_grouped_expert_product_is_a_kernel(one_chip):
     assert "bf16[16,49152" not in text  # no [experts, rows, ...] dense intermediate
 
 
+# (tokens, choices a token, experts held, width, the rungs' rows): the three
+# expert cells' layers, smallthinker, keye2, kanana2
+WAY_BACK_SHAPES = [
+    (16384, 6, 8, 2560, (24576, 98304)),
+    (16384, 8, 16, 2048, (32768, 131072)),
+    (8192, 6, 16, 2048, (12288, 49152)),
+]
+
+
+@pytest.mark.parametrize("rung", [0, 1], ids=["short-rung", "full-rung"])
+@pytest.mark.parametrize("shape", WAY_BACK_SHAPES, ids=["smallthinker", "keye2", "kanana2"])
+def test_expert_way_back_is_a_kernel_no_benchmark_mark_counts(one_chip, shape, rung):
+    """The way back from the sorted buffer to the tokens compiles to the
+    kernel, forward (float32 rows) and in the gradient (the transpose of the
+    gather into the buffer: bfloat16 rows), at the expert cells' shapes on
+    both rungs.  Every kernel's first operand is float32, so neither the
+    attention mark (``{bf16[``) nor the grouped products' (``{s32[``) counts
+    it; and on the short rung no program holds an array of every
+    assignment's row ``[k x T, D]`` (``bf16[98304,2560]`` at the first
+    cell), which the gather made (on the full rung the buffer is that
+    long)."""
+    from katib_tpu.models.mla_moe import _tokens_to_rows
+    from katib_tpu.ops.expert_combine import Routes, combine
+
+    tokens, k, held, width, rungs = shape
+    rows = rungs[rung]
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    choices = sds((k, tokens), jnp.int32)
+    routes = Routes(choices, choices, sds((rows,), jnp.int32), sds((held,), jnp.int32))
+    x, weights = sds((tokens, width), jnp.bfloat16), sds((k, tokens), jnp.float32)
+
+    def forward(x, weights, routes):
+        buffer = _tokens_to_rows(x, routes.index // k, routes).astype(jnp.float32)
+        return combine(weights, buffer * 1.5, routes, jnp.bfloat16)[0]
+
+    def loss(x, weights, routes):
+        return jnp.sum(forward(x, weights, routes).astype(jnp.float32))
+
+    for fn, kernels in ((forward, 1), (jax.grad(loss, argnums=(0, 1)), 1)):
+        text = jax.jit(fn).lower(x, weights, routes).compile().as_text()
+        calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(calls) == kernels
+        assert all("operand_layout_constraints={f32[" in call for call in calls)
+        assert rung == 1 or f"[{k * tokens},{width}]" not in text
+
+
 def test_looped_step_fits_one_chip_and_holds_the_stack_once(one_chip):
     """The whole train step of ``ouro-2.6b-l6`` (``block: looped``: six layers
     run four times, 510M parameters, 4096 positions) compiled for the
