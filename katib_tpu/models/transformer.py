@@ -12,7 +12,7 @@ training on a sharded mesh with the same trial API as the CNN workloads.
 Tunable parameters understood by ``transformer_trial``: lr, d_model,
 n_heads, n_layers, seq_len, vocab_size, batch_size, n_seq, data_seed, steps,
 warmup_frac, attn(ring|ulysses), dropout, and
-block(gpt2|mla_moe|gqa_moe|looped|dsa_moe).
+block(gpt2|mla_moe|gqa_moe|looped|dsa_moe|kda_moe).
 With ``block: mla_moe`` (latent attention and sparse experts,
 ``katib_tpu.models.mla_moe``) also: first_dense_layers, qk_nope_dim,
 qk_rope_dim, v_head_dim, kv_lora_rank, dense_width, expert_width, n_experts,
@@ -31,8 +31,13 @@ ut_steps, exit_beta, rope_theta, eps.  With ``block: dsa_moe`` (grouped-query
 attention over the keys a learned indexer selects, sparse experts,
 ``katib_tpu.models.dsa_moe``) also: n_kv_heads, head_dim, index_heads,
 index_head_dim, index_topk, expert_width, n_experts, experts_per_token,
-rope_theta, eps, experts_held_first, experts_held.  Every block but ``gpt2``
-refuses dropout and a ``seq`` mesh axis.
+rope_theta, eps, experts_held_first, experts_held.  With ``block: kda_moe``
+(Kimi delta attention in a period with gated grouped-query attention, sparse
+experts beside a shared one, ``katib_tpu.models.kda_moe``) also: n_kv_heads,
+head_dim, kda_heads, kda_head_dim, conv_size, gqa_period, expert_width,
+n_experts, experts_per_token, n_shared_experts, eps, experts_held_first,
+experts_held.  Every block but ``gpt2`` refuses dropout and a ``seq`` mesh
+axis.
 
 **The loss belongs to the model.**  A model answers ``training_loss(outputs,
 tokens)`` with the loss a step differentiates and what a report may read of
@@ -68,9 +73,11 @@ import optax
 
 from katib_tpu.models.dsa_moe import DsaMoeLM, DsaMoeSizes
 from katib_tpu.models.gqa_moe import GqaMoeLM, GqaMoeSizes
+from katib_tpu.models.kda_moe import KdaMoeLM, KdaMoeSizes
 from katib_tpu.models.lm_head import LMHead, chunk_rows, lm_loss
 from katib_tpu.models.looped import LoopedLM, LoopedSizes
 from katib_tpu.models.mla_moe import ROUTING, MlaMoeLM, MlaMoeSizes, expert_buffer, expert_combine
+from katib_tpu.ops.delta_rule import chunked_delta_rule, delta_rule
 from katib_tpu.ops.flash_attention import (
     flash_attention,
     one_walk,
@@ -202,6 +209,20 @@ def sparse_attention_fn(kernel: bool, topk: int):
 
     attention.kernel, attention.window, attention.topk = kernel, None, topk
     return attention
+
+
+@lru_cache(maxsize=4)
+def kda_scan_fn(kernel: bool):
+    """The gated delta rule of ``block: kda_moe``: the chunked scan's Pallas
+    kernels, or the same chunks in plain XLA off the TPU; it runs under the
+    named scope ``kda_scan`` and says what it runs (``kernel``)."""
+
+    def scan(q, k, v, g, beta):
+        with jax.named_scope("kda_scan"):
+            return (delta_rule if kernel else chunked_delta_rule)(q, k, v, g, beta)
+
+    scan.kernel = kernel
+    return scan
 
 
 @lru_cache(maxsize=8)
@@ -359,7 +380,7 @@ class TrialPrograms(NamedTuple):
 
 
 def _build_programs(
-    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM | DsaMoeLM, grad_clip: float, weight_decay: float, mesh
+    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM | DsaMoeLM | KdaMoeLM, grad_clip: float, weight_decay: float, mesh
 ) -> TrialPrograms:
     # AdamW without its rate: ``step_fn`` scales the update by the schedule's
     # value, so lr, steps and warmup_frac are operands and not constants
@@ -423,7 +444,7 @@ _PROGRAMS_LOCK = threading.Lock()
 
 
 def _programs_for(
-    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM | DsaMoeLM, grad_clip: float, mesh
+    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM | DsaMoeLM | KdaMoeLM, grad_clip: float, mesh
 ) -> tuple[TrialPrograms, bool]:
     """The structure's programs, and whether the process had them already."""
     key = (model, float(grad_clip), WEIGHT_DECAY, mesh)
@@ -445,7 +466,7 @@ def _programs_for(
 
 
 def train_lm(
-    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM | DsaMoeLM,
+    model: TransformerLM | MlaMoeLM | GqaMoeLM | LoopedLM | DsaMoeLM | KdaMoeLM,
     data: np.ndarray,
     *,
     lr: float,
@@ -544,7 +565,7 @@ def train_lm(
 
 def _block_sizes(cls, block: str, p, mesh) -> dict:
     """The fields of a block's sizes (``MlaMoeSizes`` | ``GqaMoeSizes`` |
-    ``LoopedSizes`` | ``DsaMoeSizes``) from a trial's parameters: every field under its own
+    ``LoopedSizes`` | ``DsaMoeSizes`` | ``KdaMoeSizes``) from a trial's parameters: every field under its own
     name, a layout as a string of 0 and 1 (the experts held are two integers
     of their own: ``_expert_block_sizes``).  These blocks have no dropout and
     have not been run over a ``seq`` axis."""
@@ -632,6 +653,26 @@ def _dsa_moe_model(p, vocab: int, mesh) -> DsaMoeLM:
     )
 
 
+def _kda_moe_model(p, vocab: int, mesh) -> KdaMoeLM:
+    """The ``block: kda_moe`` model from a trial's parameters."""
+    sizes = _expert_block_sizes(KdaMoeSizes, KdaMoeLM.BLOCK, p, mesh)
+    if sizes.n_heads % sizes.n_kv_heads:
+        raise ValueError(
+            f"transformer_trial: {sizes.n_heads} query heads are not a multiple of "
+            f"{sizes.n_kv_heads} key-value heads"
+        )
+    if sizes.gqa_period < 1 or sizes.conv_size < 1:
+        raise ValueError(
+            f"transformer_trial: gqa_period {sizes.gqa_period} and conv_size {sizes.conv_size} are at least 1"
+        )
+    return KdaMoeLM(
+        vocab_size=vocab,
+        sizes=sizes,
+        attn_fn=make_attention_fn(),
+        scan_fn=kda_scan_fn(jax.default_backend() == "tpu"),
+    )
+
+
 def _looped_model(p, vocab: int, mesh) -> LoopedLM:
     """The ``block: looped`` model from a trial's parameters."""
     sizes = LoopedSizes(**_block_sizes(LoopedSizes, LoopedLM.BLOCK, p, mesh))
@@ -660,6 +701,7 @@ _BLOCKS = {
     GqaMoeLM.BLOCK: _gqa_moe_model,
     LoopedLM.BLOCK: _looped_model,
     DsaMoeLM.BLOCK: _dsa_moe_model,
+    KdaMoeLM.BLOCK: _kda_moe_model,
 }
 
 

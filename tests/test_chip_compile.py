@@ -400,3 +400,83 @@ def test_sparse_attention_step_fits_one_chip(one_chip):
     assert 5.5e9 < held < 5.8e9  # parameters and two moments, float32, updated in place
     assert 4e9 < ma.peak_memory_in_bytes + ma.generated_code_size_in_bytes < 14.5e9, ma
     assert held + ma.temp_size_in_bytes + ma.generated_code_size_in_bytes < 16 * 2**30
+
+
+def _kda_cell_step(one_chip):
+    """The ``solar2-tp8ep40-lr4low-steps12`` train step (``block: kda_moe`` at
+    the configuration's sizes), the scan's and the attention's kernels in it,
+    compiled for the described chip."""
+    import json
+
+    from katib_tpu.models import transformer
+    from katib_tpu.models.kda_moe import KdaMoeLM, KdaMoeSizes
+
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark", "configs", "solar-open2-250b-tp8ep40.json")) as f:
+        cfg = json.load(f)
+    lin = cfg["linear_attn_config"]
+
+    def attention(q, k, v):  # what make_attention_fn gives on the chip
+        from katib_tpu.ops.flash_attention import flash_attention
+
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    def scan(q, k, v, g, beta):  # what kda_scan_fn gives on the chip
+        from katib_tpu.ops.delta_rule import delta_rule
+
+        with jax.named_scope("kda_scan"):
+            return delta_rule(q, k, v, g, beta, interpret=False)
+
+    attention.kernel, attention.window, scan.kernel = True, None, True
+    sizes = KdaMoeSizes(
+        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"], n_kv_heads=cfg["num_key_value_heads"],
+        head_dim=cfg["head_dim"], kda_heads=lin["num_heads"], kda_head_dim=lin["head_dim"],
+        conv_size=lin["short_conv_kernel_size"], n_layers=cfg["num_hidden_layers"], gqa_period=cfg["gqa_interval"] + 1,
+        expert_width=cfg["moe_intermediate_size"], n_experts=cfg["router_width"],
+        experts_per_token=cfg["num_experts_per_tok"], n_shared_experts=cfg["n_shared_experts"],
+        experts_held=(cfg["experts_held_first"], cfg["n_routed_experts"]), eps=cfg["rms_norm_eps"],
+    )
+    model = KdaMoeLM(
+        vocab_size=cfg["vocab_size"], sizes=sizes, attn_fn=attention, scan_fn=scan)
+    programs = transformer._build_programs(model, 1.0, transformer.WEIGHT_DECAY, None)
+    seq_len = cfg["seq_len"]
+
+    def placed(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    state = jax.tree.map(placed, jax.eval_shape(programs.init, jax.random.PRNGKey(0), seq_len))
+    scalar = lambda dtype: jax.ShapeDtypeStruct((), dtype, sharding=one_chip)  # noqa: E731
+    compiled = programs.step_fn.lower(
+        state, placed(jnp.zeros((cfg["batch_size"], seq_len), jnp.int32)), placed(jnp.zeros((2,), jnp.uint32)),
+        scalar(jnp.float32), scalar(jnp.int32), scalar(jnp.int32),
+    ).compile()
+    return compiled, state, sizes
+
+
+def test_delta_rule_step_fits_one_chip(one_chip):
+    """The whole train step of the ``kda_moe`` cell: three delta-rule layers
+    (8 heads of 128 held, 4096 positions: 64 chunks of 64), one gated
+    grouped-query layer (8 query heads over 1 key-value head), 8 of 320
+    experts held and a shared one in each, 840.9M parameters.  Each
+    delta-rule layer runs the scan's two kernels (forward, and the backward's
+    reverse walk; the rematerialised forward runs none), the grouped-query
+    layer the attention's two, and the step fits the chip: 13.45 GB of
+    parameters, gradients and moments and about 2 GB of working memory were
+    reckoned (the deviceless compile read 14.99 GB, peak and code)."""
+    import re
+
+    from katib_tpu.ops.delta_rule import KDA_MARK
+
+    compiled, state, sizes = _kda_cell_step(one_chip)
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    scans = [line for line in kernels if re.search(KDA_MARK, line)]
+    assert len(scans) == 2 * 3 and all(line.lstrip().startswith("%kda_scan") for line in scans)
+    assert sum("operand_layout_constraints={bf16[" in line for line in kernels) == 2
+    params = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(state.params))
+    assert params == 840_871_320, params
+    ma = compiled.memory_analysis()
+    held = ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    assert 10.0e9 < held < 10.3e9  # parameters and two moments, float32, updated in place
+    peak = ma.peak_memory_in_bytes + ma.generated_code_size_in_bytes
+    print("deviceless v5e compile:", ma, "peak + code", peak)
+    assert peak < 16 * 2**30, ma

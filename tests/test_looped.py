@@ -631,7 +631,7 @@ class TestNormalPath:
         [
             ({"dropout": 0.1}, "has no dropout"),
             ({"ut_steps": 0}, "runs at least once"),
-            ({"block": "loop"}, "is neither 'gpt2', 'mla_moe', 'gqa_moe', 'looped' nor 'dsa_moe'"),
+            ({"block": "loop"}, "is neither 'gpt2', 'mla_moe', 'gqa_moe', 'looped', 'dsa_moe' nor 'kda_moe'"),
         ],
     )
     def test_refusals_are_clear(self, bad, match):
